@@ -16,7 +16,8 @@ import numpy as np
 
 import repro
 from repro.core import sht, spectra
-from benchmarks.common import emit, smoke, time_call
+from benchmarks.common import (emit, enable_float64_oracle, smoke,
+                               time_call)
 
 KEY = jax.random.PRNGKey(0)  # explicit: random_alm no longer defaults
 
@@ -28,6 +29,7 @@ def _roundtrip(plan, alm, iters=0):
 
 
 def main():
+    enable_float64_oracle()
     gl_sizes = (32,) if smoke() else (32, 64, 128, 256)
     for l_max in gl_sizes:
         plan = repro.make_plan("gl", l_max=l_max, dtype="float64", mode="jnp")

@@ -22,11 +22,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
 import repro
-from repro import compat
 from repro.core import sht
-from benchmarks.common import time_multi
+from benchmarks.common import enable_float64_oracle, time_multi
 from jax.sharding import PartitionSpec as P
 
+enable_float64_oracle()
 SMOKE = bool(os.environ.get("REPRO_BENCH_SMOKE"))
 K = 2
 REPS = 1 if SMOKE else 3
@@ -42,19 +42,19 @@ def breakdown(tag, plan):
     a_re, a_im = jnp.real(packed), jnp.imag(packed)
     spec = P(d.axis_names)
 
-    stage1 = jax.jit(compat.shard_map(lambda ar, ai, m: jnp.concatenate(
+    stage1 = jax.jit(jax.shard_map(lambda ar, ai, m: jnp.concatenate(
         d._stage1_synth(ar, ai, m), -1), mesh=d.mesh,
-        in_specs=(spec, spec, spec), out_specs=spec))
+        in_specs=(spec, spec, spec), out_specs=spec, check_vma=False))
     delta = stage1(a_re, a_im, c["m_flat"])
 
-    exch = jax.jit(compat.shard_map(lambda x: d._exchange(x, to_rings=True),
-        mesh=d.mesh, in_specs=(spec,), out_specs=spec))
+    exch = jax.jit(jax.shard_map(lambda x: d._exchange(x, to_rings=True),
+        mesh=d.mesh, in_specs=(spec,), out_specs=spec, check_vma=False))
     exch_out = exch(delta)
 
     nops = len(c["synth_ops"])
-    fft = jax.jit(compat.shard_map(lambda x, ph, vl, *ops: d._synth_fft(
+    fft = jax.jit(jax.shard_map(lambda x, ph, vl, *ops: d._synth_fft(
         x[..., :K], x[..., K:], ph, vl, ops), mesh=d.mesh,
-        in_specs=(spec,) * (3 + nops), out_specs=spec))
+        in_specs=(spec,) * (3 + nops), out_specs=spec, check_vma=False))
 
     ts = time_multi({
         "full_s": lambda: plan.alm2map(alm),
@@ -89,7 +89,17 @@ breakdown("healpix", repro.make_plan("healpix", nside=nside, K=K,
 def run_helper(helper: str, timeout: int = 560):
     """Run a multi-device benchmark helper in a subprocess and re-emit its
     ``CSV name,us,derived`` lines through `common.emit` so they land in
-    the BENCH_<date>.json trajectory."""
+    the BENCH_<date>.json trajectory.
+
+    The helper simulates 8 host devices in a child process, which is
+    only possible on the CPU backend: on a TPU this process holds the
+    chip, and a child that needs it fails or hangs -- refused."""
+    import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "multi-device benchmark helpers simulate host devices in a "
+            "child process; run them with JAX_PLATFORMS=cpu, not on "
+            f"the {jax.default_backend()} backend this process holds")
     env = dict(os.environ)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     # src for repro, the repo root for benchmarks.common (time_multi)

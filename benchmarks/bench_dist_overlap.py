@@ -21,8 +21,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 import repro
 from repro.core import sht
-from benchmarks.common import time_multi
+from benchmarks.common import enable_float64_oracle, time_multi
 
+enable_float64_oracle()
 SMOKE = bool(os.environ.get("REPRO_BENCH_SMOKE"))
 LMAX = 64 if SMOKE else 256
 K = 4

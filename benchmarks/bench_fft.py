@@ -14,12 +14,14 @@ import jax
 
 import repro
 from repro.core import sht
-from benchmarks.common import emit, smoke, time_call
+from benchmarks.common import (emit, enable_float64_oracle, smoke,
+                               time_call)
 
 KEY = jax.random.PRNGKey(2)
 
 
 def main():
+    enable_float64_oracle()
     nsides = (16,) if smoke() else (32, 64, 128)
     for nside in nsides:
         l_max = 2 * nside

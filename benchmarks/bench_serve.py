@@ -43,7 +43,7 @@ import numpy as np
 import repro
 from repro.core import sht
 from repro.serve import ShtEngine
-from benchmarks.common import emit
+from benchmarks.common import emit, enable_float64_oracle
 
 
 def _cfg():
@@ -135,6 +135,7 @@ def _frontier(cfg):
 
 
 def main():
+    enable_float64_oracle()
     cfg = _cfg()
     l_max, nside = cfg["l_max"], cfg["nside"]
     n, max_k = cfg["n_requests"], cfg["max_k"]

@@ -17,12 +17,14 @@ import jax.numpy as jnp
 
 import repro
 from repro.core import sht
-from benchmarks.common import emit, smoke, time_pair
+from benchmarks.common import (emit, enable_float64_oracle, smoke,
+                               time_pair)
 
 KEY = jax.random.PRNGKey(3)
 
 
 def main():
+    enable_float64_oracle()
     for l_max in ((32,) if smoke() else (64, 128)):
         alm64 = sht.random_alm(KEY, l_max, l_max)
         base = repro.make_plan("gl", l_max=l_max, K=1, dtype="float64",

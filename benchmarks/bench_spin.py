@@ -13,10 +13,12 @@ import jax.numpy as jnp
 
 import repro
 from repro.core import sht
-from benchmarks.common import emit, smoke, time_call
+from benchmarks.common import (emit, enable_float64_oracle, smoke,
+                               time_call)
 
 
 def main():
+    enable_float64_oracle()
     sizes = ((32, 4),) if smoke() else ((64, 4), (128, 8))
     backends = (("jnp", "float64"), ("pallas_vpu", "float32"),
                 ("pallas_mxu", "float32"))

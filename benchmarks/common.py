@@ -7,6 +7,15 @@ import jax
 import numpy as np
 
 
+def enable_float64_oracle() -> None:
+    """Turn on JAX's 64-bit mode for the float64 oracle plans a benchmark
+    compares against (`import repro` leaves it off).  CPU only: on a TPU
+    float64 plans are refused, and 64-bit mode breaks the float32
+    programs' compiles."""
+    if jax.default_backend() == "cpu":
+        jax.config.update("jax_enable_x64", True)
+
+
 def smoke() -> bool:
     """True when REPRO_BENCH_SMOKE=1: one small size, one rep per bench
     (the scripts/check.sh CI gate)."""

@@ -64,6 +64,8 @@ def main(argv=None) -> None:
                     help="summary JSON path (default BENCH_<UTC-date>.json "
                          "in the current directory)")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.enable()
     if args.smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
         # bounded CI runtime: plans built by the benches reuse chardb

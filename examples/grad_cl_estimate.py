@@ -34,11 +34,16 @@ def main():
     ap.add_argument("--grid", default="gl", choices=["gl", "ecp", "healpix"])
     ap.add_argument("--steps", type=int, default=25)
     ap.add_argument("--noise", type=float, default=0.05)
-    ap.add_argument("--dtype", default="float64",
-                    choices=["float64", "float32"])
+    ap.add_argument("--dtype", default=None,
+                    choices=["float64", "float32"],
+                    help="default: float64 on a CPU, float32 on a TPU")
     ap.add_argument("--mode", default="auto",
                     help="auto | model | jnp | pallas_vpu | pallas_mxu | dist")
     a = ap.parse_args()
+    if a.dtype is None:
+        a.dtype = "float32" if jax.default_backend() == "tpu" else "float64"
+    if a.dtype == "float64":    # the float64 oracle needs JAX's 64-bit mode
+        jax.config.update("jax_enable_x64", True)
 
     nside = max(a.lmax // 2, 2) if a.grid == "healpix" else None
     plan = repro.make_plan(a.grid, l_max=a.lmax, nside=nside,

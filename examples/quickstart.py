@@ -21,12 +21,17 @@ def main():
     ap.add_argument("--lmax", type=int, default=128)
     ap.add_argument("--grid", default="gl", choices=["gl", "healpix_ring"])
     ap.add_argument("--K", type=int, default=2, help="simultaneous maps")
-    ap.add_argument("--dtype", default="float64",
+    ap.add_argument("--dtype", default=None,
                     choices=["float64", "float32"],
-                    help="float32 enables the Pallas kernel backends")
+                    help="float32 enables the Pallas kernel backends "
+                         "(default: float64 on a CPU, float32 on a TPU)")
     ap.add_argument("--mode", default="auto",
                     help="auto | model | jnp | pallas_vpu | pallas_mxu | dist")
     a = ap.parse_args()
+    if a.dtype is None:
+        a.dtype = "float32" if jax.default_backend() == "tpu" else "float64"
+    if a.dtype == "float64":    # the float64 oracle needs JAX's 64-bit mode
+        jax.config.update("jax_enable_x64", True)
 
     # One entry point: the plan owns precompute, layout and kernel choice.
     # A second make_plan with this signature returns the same (cached) plan.

@@ -18,6 +18,7 @@ predicted-vs-measured calibration show up in the stats table).
 
 import argparse
 
+import jax
 import numpy as np
 
 import repro
@@ -41,6 +42,8 @@ def main():
     a = ap.parse_args()
     if a.smoke:
         a.requests, a.lmax, a.nside = min(a.requests, 6), 12, 4
+    # the demo serves and checks float64 requests (CPU): 64-bit mode on
+    jax.config.update("jax_enable_x64", True)
 
     target_s = None if a.p99_target_ms is None else a.p99_target_ms * 1e-3
     eng = ShtEngine(max_k=a.max_k, mode="jnp", warm_after=2,

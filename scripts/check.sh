@@ -21,9 +21,10 @@ PYTHONPATH=src REPRO_BENCH_SMOKE=1 python -m benchmarks.bench_dispatch
 
 echo "== ragged-grid smoke (true-HEALPix plan roundtrip) =="
 PYTHONPATH=src python - <<'PY'
-import numpy as np
+import jax, numpy as np
 import repro
 from repro.core import sht, spectra
+jax.config.update("jax_enable_x64", True)   # float64 oracle plans
 plan = repro.make_plan("healpix", nside=8, dtype="float64", mode="auto")
 alm = sht.random_alm(seed=0, l_max=plan.l_max, m_max=plan.m_max)
 err = float(spectra.d_err(alm, plan.map2alm(plan.alm2map(alm), iters=1)))
@@ -34,9 +35,10 @@ PY
 
 echo "== spin-2 smoke (Q/U roundtrips through make_plan(..., spin=2)) =="
 PYTHONPATH=src python - <<'PY'
-import numpy as np
+import jax, numpy as np
 import repro
 from repro.core import sht, spectra
+jax.config.update("jax_enable_x64", True)   # float64 oracle plans
 # exact grid: machine precision; pure-E must synthesise with zero B leakage
 plan = repro.make_plan("gl", l_max=32, dtype="float64", mode="auto", spin=2)
 alm = sht.random_alm_spin(seed=0, l_max=32, m_max=32)

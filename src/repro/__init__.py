@@ -5,14 +5,11 @@ with intra-node acceleration -- adapted to TPU (shard_map + Pallas), together
 with the assigned 10-architecture LM model zoo, training/serving substrate,
 multi-pod dry-run and roofline tooling.  See DESIGN.md.
 
-float64 is enabled globally: the SHT reference engine is double precision
-(matching the paper); all model/kernel code passes explicit dtypes and is
-unaffected by the default-dtype change.
+Importing the package changes no JAX configuration.  Device code runs in
+float32 (a TPU has no float64); the float64 reference engine
+(``dtype="float64"``) needs ``jax_enable_x64``, which a caller turns on
+itself on the CPU -- plans and engine requests refuse float64 otherwise.
 """
-
-import jax
-
-jax.config.update("jax_enable_x64", True)
 
 __version__ = "1.1.0"
 

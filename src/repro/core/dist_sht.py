@@ -53,7 +53,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import legendre
 from repro.core import phase as phaselib
 from repro.core.plan import SHTPlan
@@ -416,18 +415,18 @@ class DistSHT:
             return self._stage1_anal(dw_re, dw_im, m_loc)
 
         spec = self._spec_sharded()
-        # The compat shim disables the replication/VMA tracker: the
+        # check_vma=False disables the replication/VMA tracker: the
         # Legendre loop carries are seeded from constants (unvarying) and
         # become shard-varying inside the loop; we opt out rather than
         # pcast-ing deep inside the shared recurrence code.
-        synth = jax.jit(compat.shard_map(
+        synth = jax.jit(jax.shard_map(
             synth_shard, mesh=self.mesh,
             in_specs=(spec,) * (5 + len(synth_ops)),
-            out_specs=spec))
-        anal = jax.jit(compat.shard_map(
+            out_specs=spec, check_vma=False))
+        anal = jax.jit(jax.shard_map(
             anal_shard, mesh=self.mesh,
             in_specs=(spec,) * (4 + len(anal_ops)),
-            out_specs=(spec, spec)))
+            out_specs=(spec, spec), check_vma=False))
         return synth, anal, consts
 
     def _build_spin_uncached(self, K: int):
@@ -512,14 +511,14 @@ class DistSHT:
             return _anal_one(maps_loc, K, m_loc, phi0_loc, w_loc, fft_ops)
 
         spec = self._spec_sharded()
-        synth = jax.jit(compat.shard_map(
+        synth = jax.jit(jax.shard_map(
             synth_shard, mesh=self.mesh,
             in_specs=(spec,) * (7 + len(synth_ops)),
-            out_specs=spec))
-        anal = jax.jit(compat.shard_map(
+            out_specs=spec, check_vma=False))
+        anal = jax.jit(jax.shard_map(
             anal_shard, mesh=self.mesh,
             in_specs=(spec,) * (4 + len(anal_ops)),
-            out_specs=(spec,) * 4))
+            out_specs=(spec,) * 4, check_vma=False))
         return synth, anal, consts
 
     def alm2map(self, alm_packed):

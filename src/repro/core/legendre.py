@@ -21,8 +21,10 @@ Implements the paper's §2.1 machinery in a vectorised, branch-free form:
   dtype's resolution by construction.  This is the SIMD-uniform TPU adaptation
   of the paper's scheme (DESIGN.md §2).
 
-Everything in this module is pure jnp and dtype-parametric: float64 for the
+The recurrence is pure jnp and dtype-parametric: float64 for the
 reference/validation engine, float32 matching the Pallas kernel numerics.
+The seeds are precomputed on the host in numpy float64 and cast at the end,
+so no float64 arithmetic runs on the device.
 """
 
 from __future__ import annotations
@@ -48,6 +50,8 @@ __all__ = [
     "alm_from_delta_folded",
     # spin-aware harmonic core (Wigner-d generalisation)
     "spin_seeds_scaled",
+    "spin_seed_rows",
+    "pmm_seed_rows",
     "recurrence_step_general",
     "delta_from_alm_general",
     "alm_from_delta_general",
@@ -61,6 +65,9 @@ __all__ = [
 ]
 
 _LN2 = float(np.log(2.0))
+#: The ring contractions are float32 (or float64) sums; a TPU's default
+#: matmul precision would round their float32 operands to bfloat16.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def scale_bits_for(dtype) -> int:
@@ -92,17 +99,62 @@ def pmm_scaled(log_mu_m, m, sin_theta, *, dtype, scale_bits: int):
 
     log P_mm = log mu_m + m * log(sin theta); split into scale * SCALE_BITS
     octaves + mantissa so the seed is representable for any m, theta.
-    All logs are evaluated in float64 on the *host-precision* path (inputs may
-    be numpy) and cast at the end, so the f32 engine seeds are as accurate as
-    f32 allows.
+    Host-side numpy float64 throughout, cast to ``dtype`` at the end: at
+    m ~ 4096, log P_mm ~ -3e4, so a float32 log would cost ~0.2% in the
+    seeds.  Returns numpy ``(mant dtype, scale int32)``.
     """
-    log_p = log_mu_m + m * jnp.log(sin_theta)  # f64 if inputs are f64
+    log_p = (np.asarray(log_mu_m, np.float64)
+             + np.asarray(m, np.float64) * np.log(np.asarray(sin_theta,
+                                                            np.float64)))
     denom = scale_bits * _LN2
     # round (not floor): keeps the mantissa within [2^-B/2, 2^B/2] and maps
     # any representable P (log_p near 0) to scale == 0 exactly.
-    scale = jnp.minimum(jnp.round(log_p / denom), 0.0)
-    mant = jnp.exp(log_p - scale * denom)
-    return mant.astype(dtype), scale.astype(jnp.int32)
+    scale = np.minimum(np.round(log_p / denom), 0.0)
+    mant = np.exp(log_p - scale * denom)
+    return mant.astype(dtype), scale.astype(np.int32)
+
+
+def _concrete(v):
+    """``v`` as a numpy array, or None when it is a tracer (the
+    distributed stage-1 path deals its m rows inside shard_map)."""
+    if isinstance(v, jax.core.Tracer):
+        return None
+    return np.asarray(v)
+
+
+def _seed_rows(m_vals, table, m_max: int):
+    """Per-row seeds ``(mant, scale)`` (M, R) from a host table builder.
+
+    ``table(m)`` maps concrete rows (numpy int, every m >= 0) to numpy
+    seeds.  Concrete ``m_vals`` are evaluated directly; traced ones gather
+    from the table over every m in [0, m_max], so the float64 precompute
+    never runs inside a trace.  Rows with m < 0 (padding) get zero seeds.
+    """
+    m = _concrete(m_vals)
+    if m is not None:
+        ok = (m >= 0)[:, None]
+        mant, scale = table(np.maximum(m, 0).astype(np.int64))
+        return np.where(ok, mant, 0).astype(mant.dtype), \
+            np.where(ok, scale, 0).astype(np.int32)
+    mant, scale = table(np.arange(m_max + 1))
+    m_vals = jnp.asarray(m_vals, jnp.int32)
+    idx = jnp.clip(m_vals, 0, m_max)
+    ok = (m_vals >= 0)[:, None]
+    return (jnp.where(ok, jnp.take(jnp.asarray(mant), idx, axis=0), 0),
+            jnp.where(ok, jnp.take(jnp.asarray(scale), idx, axis=0), 0))
+
+
+def pmm_seed_rows(m_vals, sin_theta, log_mu_all, *, dtype, scale_bits: int):
+    """Scaled P_mm seeds (M, R) for rows ``m_vals`` (concrete or traced),
+    computed on the host in float64 (see :func:`pmm_scaled`)."""
+    lm = np.asarray(log_mu_all, np.float64)
+    sin = np.asarray(sin_theta, np.float64)[None, :]
+
+    def table(m):
+        return pmm_scaled(lm[m][:, None], m[:, None], sin, dtype=dtype,
+                          scale_bits=scale_bits)
+
+    return _seed_rows(m_vals, table, lm.shape[0] - 1)
 
 
 def _beta(l, m, dtype):
@@ -166,28 +218,30 @@ def recurrence_step(l, m, x, mant_prev, mant_curr, scale, pmm_mant, pmm_scale,
     return new_prev2, new_curr2, new_scale2, value
 
 
-def _prep(m_vals, grid_x, log_mu_all, dtype):
+def _prep(m_vals, grid_x, grid_sin, log_mu_all, dtype, scale_bits):
     m = jnp.asarray(m_vals, jnp.int32)[:, None]                  # (M, 1)
     x = jnp.asarray(grid_x, dtype)[None, :]                      # (1, R)
-    lm = jnp.asarray(log_mu_all, jnp.float64)[jnp.asarray(m_vals, jnp.int32)]
-    return m, x, lm[:, None]
+    pmm_mant, pmm_scale = pmm_seed_rows(m_vals, grid_sin, log_mu_all,
+                                        dtype=dtype, scale_bits=scale_bits)
+    return m, x, pmm_mant, pmm_scale
 
 
 @functools.partial(jax.jit, static_argnames=("l_max", "scale_bits", "dtype_name"))
-def _delta_from_alm_impl(a_re, a_im, m, x, sin_theta, log_mu_m, *, l_max,
+def _delta_from_alm_impl(a_re, a_im, m, x, pmm_mant, pmm_scale, *, l_max,
                          scale_bits, dtype_name):
     dtype = jnp.dtype(dtype_name)
     M, R = m.shape[0], x.shape[1]
     K = a_re.shape[-1]
-    pmm_mant, pmm_scale = pmm_scaled(log_mu_m, m.astype(jnp.float64),
-                                     jnp.asarray(sin_theta, jnp.float64)[None, :],
-                                     dtype=dtype, scale_bits=scale_bits)
+    # K-major rows throughout the loop (see `_scan_to_mlk`): the a_lm
+    # stream as (L, K*M), the accumulators as (K*M, R).
+    rows_re = jnp.transpose(a_re, (1, 2, 0)).reshape(l_max + 1, K * M)
+    rows_im = jnp.transpose(a_im, (1, 2, 0)).reshape(l_max + 1, K * M)
     carry0 = (
         jnp.zeros((M, R), dtype),          # P_{l-2} mantissa
         jnp.zeros((M, R), dtype),          # P_{l-1} mantissa
         jnp.zeros((M, R), jnp.int32),      # scale
-        jnp.zeros((M, R, K), dtype),       # d_re accumulator
-        jnp.zeros((M, R, K), dtype),       # d_im accumulator
+        jnp.zeros((K * M, R), dtype),      # d_re accumulator
+        jnp.zeros((K * M, R), dtype),      # d_im accumulator
     )
 
     def body(l, carry):
@@ -196,14 +250,15 @@ def _delta_from_alm_impl(a_re, a_im, m, x, sin_theta, log_mu_m, *, l_max,
             l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
             scale_bits=scale_bits, dtype=dtype)
         # Delta_m(r) += a_{l,m} * P_{l,m}(r)   (paper eq. 12)
-        are = jax.lax.dynamic_index_in_dim(a_re, l, axis=1, keepdims=False)
-        aim = jax.lax.dynamic_index_in_dim(a_im, l, axis=1, keepdims=False)
-        dre = dre + val[..., None] * are[:, None, :]
-        dim = dim + val[..., None] * aim[:, None, :]
+        are = jax.lax.dynamic_index_in_dim(rows_re, l, axis=0, keepdims=False)
+        aim = jax.lax.dynamic_index_in_dim(rows_im, l, axis=0, keepdims=False)
+        dre = dre + (are.reshape(K, M)[:, :, None] * val).reshape(K * M, R)
+        dim = dim + (aim.reshape(K, M)[:, :, None] * val).reshape(K * M, R)
         return mp, mc, sc, dre, dim
 
     _, _, _, d_re, d_im = jax.lax.fori_loop(0, l_max + 1, body, carry0)
-    return d_re, d_im
+    unrow = lambda d: jnp.transpose(d.reshape(K, M, R), (1, 2, 0))
+    return unrow(d_re), unrow(d_im)
 
 
 def delta_from_alm(a_re, a_im, m_vals, grid_x, grid_sin, log_mu_all, *,
@@ -227,31 +282,38 @@ def delta_from_alm(a_re, a_im, m_vals, grid_x, grid_sin, log_mu_all, *,
     assert a_re.shape[1] == l_max + 1, (a_re.shape, l_max)
     R = gx.shape[0]
 
-    def fwd(m_vals_, ops):
+    def fwd(pre, ops):
         ar, ai = ops
-        m, x, log_mu_m = _prep(m_vals_, gx, lm_all, dtype)
-        return _delta_from_alm_impl(ar, ai, m, x, gs, log_mu_m, l_max=l_max,
+        m, x, pm, ps = pre
+        return _delta_from_alm_impl(ar, ai, m, x, pm, ps, l_max=l_max,
                                     scale_bits=sb, dtype_name=dtype.name)
 
-    def bwd(m_vals_, cts):
+    def bwd(pre, cts):
         gd_re, gd_im = cts
-        m, x, log_mu_m = _prep(m_vals_, gx, lm_all, dtype)
+        m, x, pm, ps = pre
         ones = jnp.ones((R,), dtype)
-        return _alm_from_delta_impl(gd_re, gd_im, m, x, gs, log_mu_m, ones,
+        return _alm_from_delta_impl(gd_re, gd_im, m, x, pm, ps, ones,
                                     l_max=l_max, scale_bits=sb,
                                     dtype_name=dtype.name)
 
-    return linear_pair(fwd, bwd, m_vals, (a_re, a_im))
+    return linear_pair(fwd, bwd, _prep(m_vals, gx, gs, lm_all, dtype, sb),
+                       (a_re, a_im))
+
+
+def _scan_to_mlk(a, K: int):
+    """Per-l scan outputs stacked as (L, K*M) -> (M, L, K).  Each step
+    emits its (K, M) row block flattened: a stack with K as its minor
+    dimension is padded to 128 lanes on a TPU (32x at K=4: 16 GB at
+    l_max=4096), and a rank-3 (L, K, M) stack still gets that layout from
+    XLA's layout assignment (it propagates the (M, L, K) result back)."""
+    return jnp.transpose(a.reshape(a.shape[0], K, -1), (2, 0, 1))
 
 
 @functools.partial(jax.jit, static_argnames=("l_max", "scale_bits", "dtype_name"))
-def _alm_from_delta_impl(d_re, d_im, m, x, sin_theta, log_mu_m, w, *, l_max,
+def _alm_from_delta_impl(d_re, d_im, m, x, pmm_mant, pmm_scale, w, *, l_max,
                          scale_bits, dtype_name):
     dtype = jnp.dtype(dtype_name)
-    M, R = m.shape[0], x.shape[1]
-    pmm_mant, pmm_scale = pmm_scaled(log_mu_m, m.astype(jnp.float64),
-                                     jnp.asarray(sin_theta, jnp.float64)[None, :],
-                                     dtype=dtype, scale_bits=scale_bits)
+    M, R, K = m.shape[0], x.shape[1], d_re.shape[-1]
     dw_re = d_re * w[None, :, None]
     dw_im = d_im * w[None, :, None]
     carry0 = (
@@ -266,13 +328,14 @@ def _alm_from_delta_impl(d_re, d_im, m, x, sin_theta, log_mu_m, w, *, l_max,
             l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
             scale_bits=scale_bits, dtype=dtype)
         # a_{l,m} = sum_r w_r Delta^S_m(r) P_lm(r)   (paper eq. 13)
-        a_re_l = jnp.einsum("mr,mrk->mk", val, dw_re)
-        a_im_l = jnp.einsum("mr,mrk->mk", val, dw_im)
+        a_re_l = jnp.einsum("mr,mrk->km", val, dw_re,
+                            precision=_HIGHEST).reshape(-1)
+        a_im_l = jnp.einsum("mr,mrk->km", val, dw_im,
+                            precision=_HIGHEST).reshape(-1)
         return (mp, mc, sc), (a_re_l, a_im_l)
 
     _, (a_re, a_im) = jax.lax.scan(step, carry0, jnp.arange(l_max + 1))
-    # scan stacks on axis 0 -> (L, M, K); reorder to (M, L, K).
-    return jnp.swapaxes(a_re, 0, 1), jnp.swapaxes(a_im, 0, 1)
+    return _scan_to_mlk(a_re, K), _scan_to_mlk(a_im, K)
 
 
 def alm_from_delta(d_re, d_im, m_vals, grid_x, grid_sin, weights, log_mu_all,
@@ -294,23 +357,24 @@ def alm_from_delta(d_re, d_im, m_vals, grid_x, grid_sin, weights, log_mu_all,
     d_im = jnp.asarray(d_im, dtype)
 
     def fwd(res, ops):
-        m_vals_, w = res
+        pre, w = res
         dr, di = ops
-        m, x, log_mu_m = _prep(m_vals_, gx, lm_all, dtype)
-        return _alm_from_delta_impl(dr, di, m, x, gs, log_mu_m, w,
+        m, x, pm, ps = pre
+        return _alm_from_delta_impl(dr, di, m, x, pm, ps, w,
                                     l_max=l_max, scale_bits=sb,
                                     dtype_name=dtype.name)
 
     def bwd(res, cts):
-        m_vals_, w = res
+        pre, w = res
         ga_re, ga_im = cts
-        m, x, log_mu_m = _prep(m_vals_, gx, lm_all, dtype)
-        gd_re, gd_im = _delta_from_alm_impl(ga_re, ga_im, m, x, gs, log_mu_m,
+        m, x, pm, ps = pre
+        gd_re, gd_im = _delta_from_alm_impl(ga_re, ga_im, m, x, pm, ps,
                                             l_max=l_max, scale_bits=sb,
                                             dtype_name=dtype.name)
         return gd_re * w[None, :, None], gd_im * w[None, :, None]
 
-    return linear_pair(fwd, bwd, (m_vals, jnp.asarray(weights, dtype)),
+    return linear_pair(fwd, bwd, (_prep(m_vals, gx, gs, lm_all, dtype, sb),
+                                  jnp.asarray(weights, dtype)),
                        (d_re, d_im))
 
 
@@ -327,14 +391,11 @@ def alm_from_delta(d_re, d_im, m_vals, grid_x, grid_sin, weights, log_mu_all,
 
 
 @functools.partial(jax.jit, static_argnames=("l_max", "scale_bits", "dtype_name"))
-def _delta_from_alm_folded_impl(a_re, a_im, m, x, sin_theta, log_mu_m, *,
+def _delta_from_alm_folded_impl(a_re, a_im, m, x, pmm_mant, pmm_scale, *,
                                 l_max, scale_bits, dtype_name):
     dtype = jnp.dtype(dtype_name)
     M, R = m.shape[0], x.shape[1]      # R = number of *northern* rings
     K = a_re.shape[-1]
-    pmm_mant, pmm_scale = pmm_scaled(log_mu_m, m.astype(jnp.float64),
-                                     jnp.asarray(sin_theta, jnp.float64)[None, :],
-                                     dtype=dtype, scale_bits=scale_bits)
     zeros = lambda *s: jnp.zeros(s, dtype)
     carry0 = (zeros(M, R), zeros(M, R), jnp.zeros((M, R), jnp.int32),
               zeros(M, R, K), zeros(M, R, K),   # even re/im
@@ -379,33 +440,31 @@ def delta_from_alm_folded(a_re, a_im, m_vals, north_x, north_sin, log_mu_all,
     a_im = jnp.asarray(a_im, dtype)
     assert a_re.shape[1] == l_max + 1, (a_re.shape, l_max)
 
-    def fwd(m_vals_, ops):
+    def fwd(pre, ops):
         ar, ai = ops
-        m, x, log_mu_m = _prep(m_vals_, gx, lm_all, dtype)
-        return _delta_from_alm_folded_impl(ar, ai, m, x, gs, log_mu_m,
+        m, x, pm, ps = pre
+        return _delta_from_alm_folded_impl(ar, ai, m, x, pm, ps,
                                            l_max=l_max, scale_bits=sb,
                                            dtype_name=dtype.name)
 
-    def bwd(m_vals_, cts):
+    def bwd(pre, cts):
         ge_re, ge_im, go_re, go_im = cts
-        m, x, log_mu_m = _prep(m_vals_, gx, lm_all, dtype)
+        m, x, pm, ps = pre
         return _alm_from_delta_folded_impl(ge_re, ge_im, go_re, go_im, m, x,
-                                           gs, log_mu_m, l_max=l_max,
+                                           pm, ps, l_max=l_max,
                                            scale_bits=sb,
                                            dtype_name=dtype.name)
 
-    return linear_pair(fwd, bwd, m_vals, (a_re, a_im))
+    return linear_pair(fwd, bwd, _prep(m_vals, gx, gs, lm_all, dtype, sb),
+                       (a_re, a_im))
 
 
 @functools.partial(jax.jit, static_argnames=("l_max", "scale_bits", "dtype_name"))
 def _alm_from_delta_folded_impl(s_e_re, s_e_im, s_o_re, s_o_im, m, x,
-                                sin_theta, log_mu_m, *, l_max, scale_bits,
+                                pmm_mant, pmm_scale, *, l_max, scale_bits,
                                 dtype_name):
     dtype = jnp.dtype(dtype_name)
-    M, R = m.shape[0], x.shape[1]
-    pmm_mant, pmm_scale = pmm_scaled(log_mu_m, m.astype(jnp.float64),
-                                     jnp.asarray(sin_theta, jnp.float64)[None, :],
-                                     dtype=dtype, scale_bits=scale_bits)
+    M, R, K = m.shape[0], x.shape[1], s_e_re.shape[-1]
     carry0 = (jnp.zeros((M, R), dtype), jnp.zeros((M, R), dtype),
               jnp.zeros((M, R), jnp.int32))
 
@@ -417,12 +476,14 @@ def _alm_from_delta_folded_impl(s_e_re, s_e_im, s_o_re, s_o_im, m, x,
         even = (((l + m) % 2) == 0)[..., None]     # (M, 1) -> (M, 1, 1) below
         sre = jnp.where(even, s_e_re, s_o_re)
         sim = jnp.where(even, s_e_im, s_o_im)
-        a_re_l = jnp.einsum("mr,mrk->mk", val, sre)
-        a_im_l = jnp.einsum("mr,mrk->mk", val, sim)
+        a_re_l = jnp.einsum("mr,mrk->km", val, sre,
+                            precision=_HIGHEST).reshape(-1)
+        a_im_l = jnp.einsum("mr,mrk->km", val, sim,
+                            precision=_HIGHEST).reshape(-1)
         return (mp, mc, sc), (a_re_l, a_im_l)
 
     _, (a_re, a_im) = jax.lax.scan(step, carry0, jnp.arange(l_max + 1))
-    return jnp.swapaxes(a_re, 0, 1), jnp.swapaxes(a_im, 0, 1)
+    return _scan_to_mlk(a_re, K), _scan_to_mlk(a_im, K)
 
 
 def alm_from_delta_folded(sum_e_re, sum_e_im, sum_o_re, sum_o_im, m_vals,
@@ -445,22 +506,23 @@ def alm_from_delta_folded(sum_e_re, sum_e_im, sum_o_re, sum_o_im, m_vals,
     ops = tuple(jnp.asarray(v, dtype)
                 for v in (sum_e_re, sum_e_im, sum_o_re, sum_o_im))
 
-    def fwd(m_vals_, ops_):
+    def fwd(pre, ops_):
         se_re, se_im, so_re, so_im = ops_
-        m, x, log_mu_m = _prep(m_vals_, gx, lm_all, dtype)
+        m, x, pm, ps = pre
         return _alm_from_delta_folded_impl(se_re, se_im, so_re, so_im, m, x,
-                                           gs, log_mu_m, l_max=l_max,
+                                           pm, ps, l_max=l_max,
                                            scale_bits=sb,
                                            dtype_name=dtype.name)
 
-    def bwd(m_vals_, cts):
+    def bwd(pre, cts):
         ga_re, ga_im = cts
-        m, x, log_mu_m = _prep(m_vals_, gx, lm_all, dtype)
-        return _delta_from_alm_folded_impl(ga_re, ga_im, m, x, gs, log_mu_m,
+        m, x, pm, ps = pre
+        return _delta_from_alm_folded_impl(ga_re, ga_im, m, x, pm, ps,
                                            l_max=l_max, scale_bits=sb,
                                            dtype_name=dtype.name)
 
-    return linear_pair(fwd, bwd, m_vals, ops)
+    return linear_pair(fwd, bwd, _prep(m_vals, gx, gs, lm_all, dtype, sb),
+                       ops)
 
 
 # ===========================================================================
@@ -518,49 +580,81 @@ def spin_seeds_scaled(m_vals, mprime_vals, grid_x, grid_sin, logfact, *,
                       dtype, scale_bits: int):
     """Scaled seeds lam^{(m')}_{l0,m} as (mantissa, scale), l0 = max(m,|m'|).
 
-    ``m_vals``/``mprime_vals``: (Ms,) int (m < 0 rows are padding -> zero
-    seeds); ``grid_x``/``grid_sin``: (R,) float64; ``logfact``: host table
-    from :func:`log_factorials`, length >= 2*max(m)+1.  Trace-friendly
-    (pure jnp), so the distributed path can pass sharded ``m_vals``.
-    Currently |m'| must be 0 or 2 (asserted host-side where possible).
+    ``m_vals``/``mprime_vals``: (Ms,) concrete int rows (m < 0 rows are
+    padding -> zero seeds); ``grid_x``/``grid_sin``: (R,) float64;
+    ``logfact``: host table from :func:`log_factorials`, length >=
+    2*max(m)+1.  Host-side numpy float64 throughout, cast to ``dtype`` at
+    the end (see :func:`pmm_scaled`); traced rows go through
+    :func:`spin_seed_rows`.  Currently |m'| must be 0 or 2.
     """
-    m = jnp.asarray(m_vals, jnp.int32)[:, None]                  # (Ms, 1)
-    mp = jnp.asarray(mprime_vals, jnp.int32)[:, None]
-    x = jnp.asarray(grid_x, jnp.float64)[None, :]                # (1, R)
-    sin_t = jnp.asarray(grid_sin, jnp.float64)[None, :]
-    lf = jnp.asarray(logfact, jnp.float64)
-    mf = m.astype(jnp.float64)
-    mpf = mp.astype(jnp.float64)
+    m = np.asarray(m_vals, np.int64)[:, None]                    # (Ms, 1)
+    mp = np.asarray(mprime_vals, np.int64)[:, None]
+    x = np.asarray(grid_x, np.float64)[None, :]                  # (1, R)
+    sin_t = np.asarray(grid_sin, np.float64)[None, :]
+    lf = np.asarray(logfact, np.float64)
+    mf = m.astype(np.float64)
+    mpf = mp.astype(np.float64)
 
     # log cos(t/2), log sin(t/2) from x = cos t (grids never hit the poles)
-    log_c = 0.5 * jnp.log(jnp.maximum((1.0 + x) / 2.0, 1e-300))
-    log_s = 0.5 * jnp.log(jnp.maximum((1.0 - x) / 2.0, 1e-300))
+    log_c = 0.5 * np.log(np.maximum((1.0 + x) / 2.0, 1e-300))
+    log_s = 0.5 * np.log(np.maximum((1.0 - x) / 2.0, 1e-300))
 
     # --- general m >= |m'| branch (log domain; also the scalar m' = 0 seed)
-    msafe = jnp.maximum(m, 0)
-    idx = lambda v: jnp.clip(v, 0, lf.shape[0] - 1)
-    log_norm = 0.5 * (jnp.log(2.0 * jnp.maximum(mf, 0.0) + 1.0)
-                      - jnp.log(4.0 * jnp.pi))
+    msafe = np.maximum(m, 0)
+    idx = lambda v: np.clip(v, 0, lf.shape[0] - 1)
+    log_norm = 0.5 * (np.log(2.0 * np.maximum(mf, 0.0) + 1.0)
+                      - np.log(4.0 * np.pi))
     log_ratio = 0.5 * (lf[idx(2 * msafe)] - lf[idx(msafe + mp)]
                        - lf[idx(msafe - mp)])
     log_p = (log_norm + log_ratio
              + (mf + mpf) * log_c + (mf - mpf) * log_s)
     denom = scale_bits * _LN2
-    scale_g = jnp.minimum(jnp.round(log_p / denom), 0.0)
-    mant_g = jnp.exp(log_p - scale_g * denom)
+    scale_g = np.minimum(np.round(log_p / denom), 0.0)
+    mant_g = np.exp(log_p - scale_g * denom)
 
     # --- |m'| = 2, m < 2 branches (O(1) values, unscaled)
     c5 = float(np.sqrt(5.0 / (4.0 * np.pi)))
     v_m0 = c5 * (np.sqrt(6.0) / 4.0) * sin_t * sin_t
-    v_m1 = jnp.where(mp < 0,
-                     c5 * 0.5 * sin_t * (1.0 - x),      # m' = -2
-                     -c5 * 0.5 * sin_t * (1.0 + x))     # m' = +2
-    low = (m < jnp.abs(mp)) & (m >= 0)
-    mant = jnp.where(low, jnp.where(m == 0, v_m0, v_m1), mant_g)
-    scale = jnp.where(low, 0.0, scale_g)
-    mant = jnp.where(m >= 0, mant, 0.0)
-    scale = jnp.where(m >= 0, scale, 0.0)
-    return mant.astype(dtype), scale.astype(jnp.int32)
+    v_m1 = np.where(mp < 0,
+                    c5 * 0.5 * sin_t * (1.0 - x),       # m' = -2
+                    -c5 * 0.5 * sin_t * (1.0 + x))      # m' = +2
+    low = (m < np.abs(mp)) & (m >= 0)
+    mant = np.where(low, np.where(m == 0, v_m0, v_m1), mant_g)
+    scale = np.where(low, 0.0, scale_g)
+    mant = np.where(m >= 0, mant, 0.0)
+    scale = np.where(m >= 0, scale, 0.0)
+    return mant.astype(dtype), scale.astype(np.int32)
+
+
+def spin_seed_rows(m_vals, mprime_vals, grid_x, grid_sin, *, m_max, dtype,
+                   scale_bits: int):
+    """Spin seeds (Ms, R) for rows (m, m'), with ``m_vals`` concrete or
+    traced (``m_max`` bounds the traced rows) and ``mprime_vals``
+    concrete.  Traced rows gather from host float64 tables over every m in
+    [0, m_max] for each distinct m' (see :func:`_seed_rows`)."""
+    mp = np.asarray(mprime_vals)
+    m = _concrete(m_vals)
+    if m_max is None:
+        m_max = int(np.max(m))
+    logfact = log_factorials(2 * max(int(m_max), 2) + 1)
+
+    def seeds(rows, mprime):
+        return spin_seeds_scaled(rows, mprime, grid_x, grid_sin, logfact,
+                                 dtype=dtype, scale_bits=scale_bits)
+
+    if m is not None:
+        return seeds(m, mp)
+    mps = np.unique(mp)
+    which = jnp.asarray(np.searchsorted(mps, mp), jnp.int32)
+    m_all = np.arange(m_max + 1)
+    tabs = [seeds(m_all, np.full_like(m_all, v)) for v in mps]
+    mant = jnp.asarray(np.stack([t[0] for t in tabs]))       # (n_mp, M, R)
+    scale = jnp.asarray(np.stack([t[1] for t in tabs]))
+    m_vals = jnp.asarray(m_vals, jnp.int32)
+    idx = jnp.clip(m_vals, 0, m_max)
+    ok = (m_vals >= 0)[:, None]
+    return (jnp.where(ok, mant[which, idx], 0),
+            jnp.where(ok, scale[which, idx], 0))
 
 
 def recurrence_step_general(l, m, mp, x, mant_prev, mant_curr, scale,
@@ -656,7 +750,7 @@ def _delta_from_alm_general_impl(a_re, a_im, m, mp, x, seed_mant, seed_scale,
 def _alm_from_delta_general_impl(d_re, d_im, m, mp, x, seed_mant, seed_scale,
                                  *, l_max, scale_bits, dtype_name):
     dtype = jnp.dtype(dtype_name)
-    M, R = m.shape[0], x.shape[1]
+    M, R, K = m.shape[0], x.shape[1], d_re.shape[-1]
     carry0 = (jnp.zeros((M, R), dtype), jnp.zeros((M, R), dtype),
               jnp.zeros((M, R), jnp.int32))
 
@@ -665,20 +759,14 @@ def _alm_from_delta_general_impl(d_re, d_im, m, mp, x, seed_mant, seed_scale,
         mprev, mcurr, sc, val = recurrence_step_general(
             l, m, mp, x, mprev, mcurr, sc, seed_mant, seed_scale,
             scale_bits=scale_bits, dtype=dtype)
-        a_re_l = jnp.einsum("mr,mrk->mk", val, d_re)
-        a_im_l = jnp.einsum("mr,mrk->mk", val, d_im)
+        a_re_l = jnp.einsum("mr,mrk->km", val, d_re,
+                            precision=_HIGHEST).reshape(-1)
+        a_im_l = jnp.einsum("mr,mrk->km", val, d_im,
+                            precision=_HIGHEST).reshape(-1)
         return (mprev, mcurr, sc), (a_re_l, a_im_l)
 
     _, (a_re, a_im) = jax.lax.scan(step, carry0, jnp.arange(l_max + 1))
-    return jnp.swapaxes(a_re, 0, 1), jnp.swapaxes(a_im, 0, 1)
-
-
-def _seed_tables(m_vals, mprime_vals, grid_x, grid_sin, m_max, dtype, sb):
-    if m_max is None:
-        m_max = int(np.max(np.asarray(m_vals)))
-    logfact = log_factorials(2 * max(int(m_max), 2) + 1)
-    return spin_seeds_scaled(m_vals, mprime_vals, grid_x, grid_sin, logfact,
-                             dtype=dtype, scale_bits=sb)
+    return _scan_to_mlk(a_re, K), _scan_to_mlk(a_im, K)
 
 
 def delta_from_alm_general(a_re, a_im, m_vals, mprime_vals, grid_x, grid_sin,
@@ -696,8 +784,9 @@ def delta_from_alm_general(a_re, a_im, m_vals, mprime_vals, grid_x, grid_sin,
     """
     dtype = jnp.dtype(dtype)
     sb = scale_bits_for(dtype)
-    seed_mant, seed_scale = _seed_tables(m_vals, mprime_vals, grid_x,
-                                         grid_sin, m_max, dtype, sb)
+    seed_mant, seed_scale = spin_seed_rows(m_vals, mprime_vals, grid_x,
+                                           grid_sin, m_max=m_max, dtype=dtype,
+                                           scale_bits=sb)
     a_re = jnp.asarray(a_re, dtype)
     a_im = jnp.asarray(a_im, dtype)
     assert a_re.shape[1] == l_max + 1, (a_re.shape, l_max)
@@ -734,8 +823,9 @@ def alm_from_delta_general(d_re, d_im, m_vals, mprime_vals, grid_x, grid_sin,
     """
     dtype = jnp.dtype(dtype)
     sb = scale_bits_for(dtype)
-    seed_mant, seed_scale = _seed_tables(m_vals, mprime_vals, grid_x,
-                                         grid_sin, m_max, dtype, sb)
+    seed_mant, seed_scale = spin_seed_rows(m_vals, mprime_vals, grid_x,
+                                           grid_sin, m_max=m_max, dtype=dtype,
+                                           scale_bits=sb)
     d_re = jnp.asarray(d_re, dtype)
     d_im = jnp.asarray(d_im, dtype)
 

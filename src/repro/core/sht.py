@@ -81,8 +81,10 @@ def random_alm(key=None, l_max: int = None, m_max: int = None, K: int = 1,
     Exactly one of ``key`` (a jax PRNG key) or ``seed=`` (an int, documented
     deterministic shorthand) must be given.  m = 0 entries are real
     (required for a real field); ``spin`` zeroes the l < spin rows.
+    ``dtype`` float64 gives float32 draws when JAX's 64-bit mode is off.
     """
     key = _resolve_key(key, seed, "random_alm")
+    dtype = jax.dtypes.canonicalize_dtype(dtype)
     kr, ki = jax.random.split(key)
     shape = (m_max + 1, l_max + 1, K)
     re = jax.random.uniform(kr, shape, dtype, -1.0, 1.0)

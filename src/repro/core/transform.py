@@ -38,8 +38,11 @@ Dispatch modes
 --------------
 ``mode="model"``  rank backends with the analytic roofline cost model
                   (`repro.roofline.predict_sht_time`) -- free, deterministic.
-``mode="auto"``   measure each candidate once per direction (one warm-up +
-                  one timed call) and pick the fastest; the decision is
+``mode="auto"``   measure each candidate once per direction (compiled
+                  ahead of time, then one timed call; a Legendre layout
+                  is skipped when its backend's first layout already runs
+                  `PRUNE_FACTOR` times slower than the best candidate so
+                  far) and pick the fastest; the decision is
                   cached by plan signature (memory + optional disk), so the
                   autotune pass runs once per signature, ever.  The raw
                   corner timings additionally land in the persistent
@@ -82,6 +85,7 @@ anything (asserted by tests/test_transform_plan.py).
 
 from __future__ import annotations
 
+import functools
 import os
 import time
 from typing import Optional, Union
@@ -89,6 +93,7 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.core import cache as plancache
 from repro.core import grids as gridlib
@@ -101,6 +106,19 @@ __all__ = ["Plan", "make_plan", "available_backends", "backend_eligibility",
            "clear_plan_cache", "drop_plan"]
 
 BACKENDS = ("jnp", "pallas_vpu", "pallas_mxu", "dist")
+
+#: ``mode="auto"`` times a pallas backend's further Legendre layouts only
+#: when its first runs within this factor of the best time measured so
+#: far in that direction.  The first is ``fused`` where eligible, the
+#: fastest layout of its backend wherever it has been measured
+#: (docs/performance.md).  At l_max=4096 on a TPU v5e every MXU layout
+#: takes ~70 s a call against ~6 s for the best candidate; timing all
+#: of them made one auto build take ~20 minutes.
+PRUNE_FACTOR = 4.0
+
+#: Order in which a pallas backend's layouts are timed (first = the one
+#: that decides whether the rest are timed at all).
+_LAYOUT_ORDER = ("fused", "packed", "plain")
 
 #: make_plan memoisation: signature key -> Plan.  This is the "second
 #: make_plan is free" tier; the payload caches underneath make a cold
@@ -137,10 +155,40 @@ def drop_plan(plan: "Plan") -> bool:
 
 
 def _pallas_ops():
-    """Import the kernel layer lazily (keeps `import repro` light and lets
-    non-Pallas builds still use the jnp/dist backends)."""
+    """Import the kernel layer lazily (keeps `import repro` light)."""
     from repro.kernels import ops as kops
     return kops
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def default_dtype() -> str:
+    """The plan dtype used when none is given: ``"float64"`` (the oracle
+    precision) where it can run -- JAX's 64-bit mode on, not a TPU --
+    else ``"float32"``."""
+    if jax.config.jax_enable_x64 and not _on_tpu():
+        return "float64"
+    return "float32"
+
+
+def check_dtype(dtype: str) -> None:
+    """Refuse a float64 transform where it cannot run as float64: on a TPU
+    (no float64 unit; it would run in float32 or in slow emulation) and
+    wherever JAX's 64-bit mode is off (it would silently run in float32)."""
+    if dtype not in ("float64", "float32"):
+        raise ValueError(f"unsupported dtype {dtype!r}: expected 'float32' "
+                         "or 'float64'")
+    if dtype != "float64":
+        return
+    if _on_tpu():
+        raise ValueError("dtype='float64' is not available on a TPU (no "
+                         "float64 hardware); use dtype='float32'")
+    if not jax.config.jax_enable_x64:
+        raise ValueError("dtype='float64' needs JAX's 64-bit mode: call "
+                         "jax.config.update('jax_enable_x64', True) first, "
+                         "or use dtype='float32'")
 
 
 def backend_eligibility(grid: RingGrid, dtype: str,
@@ -151,18 +199,18 @@ def backend_eligibility(grid: RingGrid, dtype: str,
     float64 restricts to the jnp oracle (the kernels compute in float32);
     dist needs >= 2 devices.  Grid raggedness is NOT a restriction: the
     phase stage (`repro.core.phase`) serves every backend on every grid.
+    On a TPU the staged VPU layouts, which do not compile there, are
+    reported as ineligible under ``"pallas_vpu[plain]"`` and
+    ``"pallas_vpu[packed]"`` (the VPU backend keeps its fused layout).
     """
     out: dict[str, Optional[str]] = {b: None for b in BACKENDS}
     if dtype != "float32":
         reason = (f"kernels compute in float32 (plan dtype {dtype!r}); "
                   "force mode='pallas_*' to accept the precision drop")
         out["pallas_vpu"] = out["pallas_mxu"] = reason
-    else:
-        try:
-            _pallas_ops()
-        except Exception as e:  # pallas not importable on this build
-            reason = f"pallas unavailable: {type(e).__name__}: {e}"
-            out["pallas_vpu"] = out["pallas_mxu"] = reason
+    if _on_tpu():
+        out["pallas_vpu[plain]"] = out["pallas_vpu[packed]"] = \
+            _pallas_ops().STAGED_VPU_TPU_ERROR
     n_dev = jax.device_count() if n_devices is None else n_devices
     if n_dev < 2:
         out["dist"] = f"needs >= 2 devices (visible: {n_dev})"
@@ -179,6 +227,33 @@ def available_backends(grid: RingGrid, dtype: str,
 
 def _complex_dtype(dtype: str):
     return jnp.complex128 if jnp.dtype(dtype) == jnp.float64 else jnp.complex64
+
+
+def _time_call_us(fn, arg) -> float:
+    """Microseconds of one call ``fn(arg)``, compile excluded.  A jitted
+    plan function (``jax.jit`` or `_bind`) is compiled ahead of time and
+    run once; anything else (the dist wrapper) gets one untimed warm-up
+    call first.  On a TPU one call at l_max=4096 can take over a minute,
+    so no execution is spent on warming up."""
+    jitted, kw = ((fn.func, fn.keywords) if isinstance(fn, functools.partial)
+                  else (fn, {}))
+    if hasattr(jitted, "lower"):
+        compiled = jitted.lower(arg, **kw).compile()
+        call = functools.partial(compiled, arg, **kw)
+    else:
+        jax.block_until_ready(fn(arg))
+        call = functools.partial(fn, arg)
+    t0 = time.perf_counter()
+    jax.block_until_ready(call())
+    return (time.perf_counter() - t0) * 1e6
+
+
+def _bind(fn, consts):
+    """``fn(x, consts)`` jitted, with the plan's precomputed tables (seeds,
+    ring nodes, rotation tables, masks) passed as arguments.  Closed over
+    instead, they would be embedded in each program as constants: hundreds
+    of MB of program per kernel at l_max=4096."""
+    return functools.partial(jax.jit(fn), consts=consts)
 
 
 class Plan:
@@ -313,7 +388,11 @@ class Plan:
             from repro.core.plan import SHTPlan
             n = self._n_shards or jax.device_count()
             if self._dist_splan is None:
-                self._dist_splan = (jax.make_mesh((n,), ("sht",)),
+                # Auto axes: jax.grad through shard_map needs them (the
+                # default Explicit axes reject the adjoint's device count)
+                mesh = jax.make_mesh((n,), ("sht",),
+                                     axis_types=(AxisType.Auto,))
+                self._dist_splan = (mesh,
                                     SHTPlan(self.grid, self.l_max,
                                             self.m_max, n))
             mesh, splan = self._dist_splan
@@ -374,7 +453,6 @@ class Plan:
                                                   layout=layout)
             else:
                 fn = self._make_pallas_synth(variant=variant, layout=layout)
-            fn = jax.jit(fn)
         elif backend == "dist":
             d = self._dist_engine(comm_chunks=int(layout or 1))
             splan = d.plan
@@ -422,7 +500,6 @@ class Plan:
                                                  layout=layout)
             else:
                 fn = self._make_pallas_anal(variant=variant, layout=layout)
-            fn = jax.jit(fn)
         elif backend == "dist":
             d = self._dist_engine(comm_chunks=int(layout or 1))
             splan = d.plan
@@ -448,9 +525,9 @@ class Plan:
         K, nh = self.K, (self.grid.n_rings + 1) // 2
         ns = nh - 1 if self.grid.n_rings % 2 == 1 else nh
         cdt = _complex_dtype(self.dtype)
-        pmm, pms, x32 = self._seeds()      # eager: built once, closed over
 
-        def fn(alm):
+        def fn(alm, consts):
+            pmm, pms, x32 = consts
             a32 = jnp.concatenate(
                 [jnp.real(alm), jnp.imag(alm)], axis=-1).astype(jnp.float32)
             out = kops.synth(a32, self._m_vals, x32, pmm, pms,
@@ -466,16 +543,17 @@ class Plan:
             delta = (flat[..., :K] + 1j * flat[..., K:]).astype(cdt)
             return self._sht.phase.synth(delta).astype(self.dtype)
 
-        return fn
+        return _bind(fn, self._seeds())
 
     def _make_pallas_anal(self, variant: str, layout=None):
         kops = _pallas_ops()
         K, R = self.K, self.grid.n_rings
         nh = (R + 1) // 2
         cdt = _complex_dtype(self.dtype)
-        pmm, pms, x32 = self._seeds()      # eager: built once, closed over
+        mask = jnp.asarray(alm_mask(self.l_max, self.m_max))[..., None]
 
-        def fn(maps):
+        def fn(maps, consts):
+            (pmm, pms, x32), mask = consts
             dwc = self._sht.phase.anal(maps)              # (M, R, K) complex
             dw = jnp.concatenate(
                 [jnp.real(dwc), jnp.imag(dwc)], axis=-1).astype(jnp.float32)
@@ -490,10 +568,9 @@ class Plan:
                             l_max=self.l_max, fold=self.fold, variant=variant,
                             layout=layout)
             alm = (out[..., :K] + 1j * out[..., K:]).astype(cdt)
-            mask = jnp.asarray(alm_mask(self.l_max, self.m_max))[..., None]
             return jnp.where(mask, alm, 0.0)
 
-        return fn
+        return _bind(fn, (self._seeds(), mask))
 
     def _make_pallas_synth_spin(self, variant: str, layout=None):
         """Spin-2 kernel synthesis: stacked lambda^{(m' = -+2)} rows through
@@ -504,7 +581,8 @@ class Plan:
         cdt = _complex_dtype(self.dtype)
         pmm, pms, x32, m2, mp2 = self._seeds_spin()
 
-        def fn(alm_eb):
+        def fn(alm_eb, consts):
+            pmm, pms, x32 = consts
             e, b = alm_eb[0], alm_eb[1]
             a2_re, a2_im = leg.spin_pack_alm(
                 jnp.real(e), jnp.imag(e), jnp.real(b), jnp.imag(b))
@@ -521,7 +599,7 @@ class Plan:
             s = self._sht.phase.synth(delta).astype(self.dtype)
             return jnp.stack([s[..., :K], s[..., K:]], axis=0)
 
-        return fn
+        return _bind(fn, (pmm, pms, x32))
 
     def _make_pallas_anal_spin(self, variant: str, layout=None):
         from repro.core import legendre as leg
@@ -529,8 +607,11 @@ class Plan:
         K = self.K
         cdt = _complex_dtype(self.dtype)
         pmm, pms, x32, m2, mp2 = self._seeds_spin()
+        mask = jnp.asarray(
+            alm_mask(self.l_max, self.m_max, spin=2))[..., None]
 
-        def fn(maps_qu):
+        def fn(maps_qu, consts):
+            (pmm, pms, x32), mask = consts
             m2d = jnp.concatenate([maps_qu[0], maps_qu[1]], axis=-1)
             dwc = self._sht.phase.anal(m2d)           # (M, R, 2K) complex
             d2_re, d2_im = leg.spin_pack_delta(
@@ -545,11 +626,9 @@ class Plan:
                 out[..., :K], out[..., K:])
             alm = jnp.stack([e_re + 1j * e_im, b_re + 1j * b_im],
                             axis=0).astype(cdt)
-            mask = jnp.asarray(
-                alm_mask(self.l_max, self.m_max, spin=2))[..., None]
             return jnp.where(mask[None], alm, 0.0)
 
-        return fn
+        return _bind(fn, ((pmm, pms, x32), mask))
 
     # -- fused pipeline (layout "fused") --------------------------------------
 
@@ -598,12 +677,8 @@ class Plan:
             for c in cands:
 
                 def measure(c=c):
-                    fn = jax.jit(self._make_fused_synth(
-                        variant="vpu", lp_size=int(c)))
-                    jax.block_until_ready(fn(arg))      # warm-up/compile
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(fn(arg))
-                    return (time.perf_counter() - t0) * 1e6
+                    return _time_call_us(self._make_fused_synth(
+                        variant="vpu", lp_size=int(c)), arg)
 
                 # base fields on the *staged* corner and override: the
                 # fused fields would recurse into this very chooser.
@@ -613,12 +688,13 @@ class Plan:
                 try:
                     us, _ = db.get_or_measure(measure, **fields)
                 except Exception:
+                    if _on_tpu():   # a kernel that fails to build is an
+                        raise       # error on the chip, not a slow entry
                     us = None
                 times[int(c)] = float("inf") if us is None else float(us)
         if not times or not np.isfinite(min(times.values())):
             g = self.grid
-            hw = (roofline.HW_HOST if jax.default_backend() == "cpu"
-                  else roofline.HW_V5E)
+            hw = roofline.hardware_for()
             times = {int(c): roofline.predict_sht_time(
                 "pallas_vpu", layout="packed", pipeline="fused",
                 lp_size=int(c), l_max=self.l_max, m_max=self.m_max,
@@ -647,8 +723,25 @@ class Plan:
                     self._m_vals, self.l_max, lp_size=lp)
         return self._fused_los[lp]
 
+    def _fused_tables(self, m_vals, x32):
+        """Host-built rotation tables of both fused directions, on the
+        device once per plan (`kernels.fused.rotation_tables`)."""
+        if getattr(self, "_fused_tabs", None) is None:
+            from repro.kernels import fused as kfused
+            g, ph = self.grid, self.phase
+            if ph.kind == "uniform":
+                geometry = dict(phase_kind="uniform", n=ph.n, phi0=g.phi0,
+                                fold_rings=(g.n_rings if self.fold else None),
+                                n_half=x32.shape[0])
+            else:
+                geometry = dict(phase_kind="bucket", phi0=g.phi0)
+            tabs, rot = kfused.rotation_tables(m_vals, **geometry)
+            self._fused_tabs = (tuple(jnp.asarray(t) for t in tabs), rot)
+        return self._fused_tabs
+
     def _fused_parts(self, variant: str, bf16: bool, lp_size):
-        """Shared fused-dispatch plumbing: seeds, layout, the phase-flavour
+        """Shared fused-dispatch plumbing: the row set, the device operands
+        (``consts`` = ring nodes, seeds, rotation tables), the static
         keyword block, and the (synth_fn, anal_fn) kernel-chain pair for
         this plan's shape (scalar/spin x uniform/fold/bucket)."""
         from repro.kernels import fused as kfused
@@ -661,8 +754,9 @@ class Plan:
         else:
             pmm, pms, x32, m2, mp2 = self._seeds_spin()
             m_vals = m2
+        tabs, rot = self._fused_tables(m_vals, x32)
         kw = dict(l_max=self.l_max, variant=variant, bf16=bf16, lo=lo,
-                  lp_size=lp, mp_vals=mp2)
+                  lp_size=lp, mp_vals=mp2, rot=rot)
         if ph.kind == "uniform":
             kw.update(n=ph.n, phi0=g.phi0,
                       fold_rings=(g.n_rings if self.fold else None))
@@ -671,70 +765,79 @@ class Plan:
             kw.update(layout=ph.layout, pos=ph._pos, neg=ph._neg,
                       n_phi=g.n_phi, phi0=g.phi0)
             pair = (kfused.fused_synth_bucket, kfused.fused_anal_bucket)
-        return m_vals, x32, pmm, pms, kw, pair
+        return m_vals, (x32, pmm, pms, tabs), kw, pair
 
     def _make_fused_synth(self, variant: str, bf16: bool = False,
                           lp_size: Optional[int] = None):
         from repro.core import legendre as leg
         K = self.K
-        m_vals, x32, pmm, pms, kw, (fsynth, _) = \
+        m_vals, consts, kw, (fsynth, _) = \
             self._fused_parts(variant, bf16, lp_size)
         if self.phase.kind == "bucket":
             kw = dict(kw, out_width=self.grid.max_n_phi)
 
+        def run(a32, consts):
+            x32, pmm, pms, tabs = consts
+            return fsynth(a32, m_vals, x32, pmm, pms, tabs=tabs, **kw)
+
         if self.spin == 0:
-            def fn(alm):
+            def fn(alm, consts):
                 a32 = jnp.concatenate(
                     [jnp.real(alm), jnp.imag(alm)],
                     axis=-1).astype(jnp.float32)
-                maps = fsynth(a32, m_vals, x32, pmm, pms, **kw)
-                return maps.astype(self.dtype)
+                return run(a32, consts).astype(self.dtype)
         else:
-            def fn(alm_eb):
+            def fn(alm_eb, consts):
                 e, b = alm_eb[0], alm_eb[1]
                 a2_re, a2_im = leg.spin_pack_alm(
                     jnp.real(e), jnp.imag(e), jnp.real(b), jnp.imag(b))
                 a32 = jnp.concatenate([a2_re, a2_im],
                                       axis=-1).astype(jnp.float32)
-                s = fsynth(a32, m_vals, x32, pmm, pms, **kw)
-                s = s.astype(self.dtype)
+                s = run(a32, consts).astype(self.dtype)
                 return jnp.stack([s[..., :K], s[..., K:]], axis=0)
 
-        return fn
+        return _bind(fn, consts)
 
     def _make_fused_anal(self, variant: str, bf16: bool = False,
                          lp_size: Optional[int] = None):
         from repro.core import legendre as leg
         K = self.K
         cdt = _complex_dtype(self.dtype)
-        m_vals, x32, pmm, pms, kw, (_, fanal) = \
+        m_vals, consts, kw, (_, fanal) = \
             self._fused_parts(variant, bf16, lp_size)
         w = jnp.asarray(self.grid.weights)
         mask = jnp.asarray(
             alm_mask(self.l_max, self.m_max, spin=self.spin))[..., None]
 
+        def run(maps, consts):
+            (x32, pmm, pms, tabs), w, _ = consts
+            return fanal(maps, w, m_vals, x32, pmm, pms, tabs=tabs, **kw)
+
         if self.spin == 0:
-            def fn(maps):
-                out = fanal(maps, w, m_vals, x32, pmm, pms, **kw)
+            def fn(maps, consts):
+                out = run(maps, consts)
                 alm = (out[..., :K] + 1j * out[..., K:]).astype(cdt)
-                return jnp.where(mask, alm, 0.0)
+                return jnp.where(consts[2], alm, 0.0)
         else:
-            def fn(maps_qu):
+            def fn(maps_qu, consts):
                 m2d = jnp.concatenate([maps_qu[0], maps_qu[1]], axis=-1)
-                out = fanal(m2d, w, m_vals, x32, pmm, pms, **kw)
+                out = run(m2d, consts)
                 e_re, e_im, b_re, b_im = leg.spin_unpack_alm(
                     out[..., :K], out[..., K:])
                 alm = jnp.stack([e_re + 1j * e_im, b_re + 1j * b_im],
                                 axis=0).astype(cdt)
-                return jnp.where(mask[None], alm, 0.0)
+                return jnp.where(consts[2][None], alm, 0.0)
 
-        return fn
+        return _bind(fn, (consts, w, mask))
 
     # -- dispatch -------------------------------------------------------------
 
-    def _pallas_layouts(self) -> tuple:
-        """Candidate Legendre layouts for the pallas backends."""
-        lays = ("packed", "plain")
+    def _pallas_layouts(self, backend: str = "pallas_vpu") -> tuple:
+        """Candidate Legendre layouts for a pallas backend: the staged
+        grids the platform compiles (see `backend_eligibility`) plus
+        ``fused`` where the plan shape allows it."""
+        lays = tuple(lay for lay in ("packed", "plain")
+                     if f"{backend}[{lay}]" not in self.skipped)
         if self._fusion_eligibility()[0]:
             lays = lays + ("fused",)
         return lays
@@ -748,8 +851,7 @@ class Plan:
         """
         g = self.grid
         if hw is None:
-            hw = (roofline.HW_HOST if jax.default_backend() == "cpu"
-                  else roofline.HW_V5E)
+            hw = roofline.hardware_for()
         n_dev = self._n_shards or jax.device_count()
         fl = self._sht.phase.fft_lengths        # per-bucket cost on ragged
         out = {}
@@ -766,7 +868,7 @@ class Plan:
                                b, layout="packed" if lay == "fused" else lay,
                                pipeline="fused" if lay == "fused"
                                else "staged", **kw)
-                           for lay in self._pallas_layouts()}
+                           for lay in self._pallas_layouts(b)}
                     lay = min(per, key=per.get)
                     out[b][d] = per[lay]
                     out[b][f"{d}_layout"] = lay
@@ -791,8 +893,7 @@ class Plan:
             return (max(1, int(self._comm_spec)),)
         g = self.grid
         n_dev = self._n_shards or jax.device_count()
-        hw = (roofline.HW_HOST if jax.default_backend() == "cpu"
-              else roofline.HW_V5E)
+        hw = roofline.hardware_for()
         c = roofline.predict_comm_chunks(
             l_max=self.l_max, m_max=self.m_max, n_rings=g.n_rings,
             n_phi=g.max_n_phi, K=self.K, direction=direction, hw=hw,
@@ -834,8 +935,11 @@ class Plan:
     def _measure_all(self) -> dict:
         """Corner timings per candidate per direction, through the chardb:
         already-characterized corners are reused without running anything;
-        missing/stale ones get one warm-up + one timed call (or are
-        skipped entirely under ``REPRO_CHARDB_SMOKE=1``)."""
+        missing/stale ones are compiled and timed over one call (or are
+        skipped entirely under ``REPRO_CHARDB_SMOKE=1``).  A pallas
+        backend's layouts after its first are pruned (listed under
+        ``"<dir>_pruned"``) when the first is `PRUNE_FACTOR` times slower
+        than the best time so far."""
         db = self._chardb()
         cdt = _complex_dtype(self.dtype)
         if self.spin == 0:
@@ -849,25 +953,28 @@ class Plan:
             maps = jnp.zeros((2, self.grid.n_rings, self.grid.max_n_phi,
                               self.K), jnp.dtype(self.dtype))
         out: dict = {}
+        fastest = {"synth": float("inf"), "anal": float("inf")}
         for b in self.candidates:
             out[b] = {}
             for direction, fn_of, arg in (("synth", self._synth_fn, alm),
                                           ("anal", self._anal_fn, maps)):
                 if b in ("pallas_vpu", "pallas_mxu"):
-                    layouts = self._pallas_layouts()
+                    layouts = sorted(self._pallas_layouts(b),
+                                     key=_LAYOUT_ORDER.index)
                 elif b == "dist":
                     layouts = self._dist_chunk_variants(direction)
                 else:
                     layouts = (None,)
                 best, best_lay, errs = float("inf"), None, {}
-                for lay in layouts:
+                for i, lay in enumerate(layouts):
+                    if (i == 1 and b != "dist" and np.isfinite(best)
+                            and best > PRUNE_FACTOR * fastest[direction]):
+                        out[b][f"{direction}_pruned"] = list(layouts[1:])
+                        break
 
                     def measure(b=b, lay=lay, fn_of=fn_of, arg=arg):
                         fn = fn_of(b, lay) if lay is not None else fn_of(b)
-                        jax.block_until_ready(fn(arg))      # warm-up/compile
-                        t0 = time.perf_counter()
-                        jax.block_until_ready(fn(arg))
-                        return (time.perf_counter() - t0) * 1e6
+                        return _time_call_us(fn, arg)
 
                     try:
                         us, status = db.get_or_measure(
@@ -876,6 +983,8 @@ class Plan:
                         if status == "skipped":
                             out[b][f"{direction}_skipped"] = True
                     except Exception as e:  # unusable here: rank last
+                        if _on_tpu():   # ... but on the chip a candidate
+                            raise       # that fails to build is an error
                         t = float("inf")
                         errs[lay] = f"{type(e).__name__}: {e}"
                         if lay is not None:
@@ -884,6 +993,7 @@ class Plan:
                         out[b][f"{direction}_{lay}"] = t
                     if t < best:
                         best, best_lay = t, lay
+                    fastest[direction] = min(fastest[direction], t)
                 out[b][direction] = best
                 if not np.isfinite(best):   # every layout failed: backend
                     out[b][f"{direction}_error"] = \
@@ -1239,7 +1349,7 @@ def _resolve_grid(grid, l_max, nside, cache_kind, cache_dir):
 
 def make_plan(grid: Union[str, RingGrid] = "gl", l_max: Optional[int] = None,
               *, nside: Optional[int] = None, m_max: Optional[int] = None,
-              K: int = 1, dtype: str = "float64", mode: str = "auto",
+              K: int = 1, dtype: Optional[str] = None, mode: str = "auto",
               fold: bool = False, spin: int = 0, cache: str = "auto",
               cache_dir: Optional[str] = None,
               n_shards: Optional[int] = None,
@@ -1254,8 +1364,10 @@ def make_plan(grid: Union[str, RingGrid] = "gl", l_max: Optional[int] = None,
     nside : HEALPix resolution (required for healpix-family string specs).
     K : number of simultaneous maps the plan is specialised for (the
         batched Monte-Carlo workload; drives the VPU/MXU choice).
-    dtype : ``"float64"`` (oracle precision, jnp backend only) or
-        ``"float32"`` (performance; enables the Pallas kernels).
+    dtype : ``"float64"`` (oracle precision, jnp backend only; needs
+        ``jax_enable_x64`` and is refused on a TPU) or ``"float32"``
+        (performance; enables the Pallas kernels).  None picks
+        :func:`default_dtype`.
     mode : ``"auto"`` (autotune, cached), ``"model"`` (cost model), or an
         explicit backend name (``"jnp"``, ``"pallas_vpu"``, ``"pallas_mxu"``,
         ``"dist"``).
@@ -1281,6 +1393,9 @@ def make_plan(grid: Union[str, RingGrid] = "gl", l_max: Optional[int] = None,
     """
     if isinstance(grid, str) and grid in ("gl", "ecp") and l_max is None:
         raise ValueError(f"make_plan({grid!r}, ...) requires l_max")
+    if dtype is None:
+        dtype = default_dtype()
+    check_dtype(dtype)
     if mode not in ("auto", "model") + BACKENDS:
         raise ValueError(f"unknown mode {mode!r}: expected 'auto', 'model' "
                          f"or a backend name {BACKENDS}")
@@ -1306,7 +1421,6 @@ def make_plan(grid: Union[str, RingGrid] = "gl", l_max: Optional[int] = None,
         l_max = 2 * g.nside if g.nside else g.n_rings - 1
     m_max = l_max if m_max is None else m_max
     assert m_max <= l_max, (m_max, l_max)
-    assert dtype in ("float64", "float32"), dtype
     if spin:
         assert l_max >= spin, (l_max, spin)
     if fold:
@@ -1338,8 +1452,18 @@ def make_plan(grid: Union[str, RingGrid] = "gl", l_max: Optional[int] = None,
             raise ValueError(
                 f"backend {mode!r} unavailable for this signature: "
                 f"{elig[mode]} (candidates: {cand})")
-    plan.candidates = cand
     plan.skipped = {b: r for b, r in elig.items() if r is not None}
+    for b in ("pallas_vpu", "pallas_mxu"):
+        if b in cand and not plan._pallas_layouts(b):
+            reason = (f"no layout compiles here: fused ineligible "
+                      f"({plan._fusion_eligibility()[1]}); staged: "
+                      f"{plan.skipped[f'{b}[plain]']}")
+            if mode == b:
+                raise ValueError(f"backend {b!r} unavailable for this "
+                                 f"signature: {reason}")
+            cand.remove(b)
+            plan.skipped[b] = reason
+    plan.candidates = cand
     plan._choose_backends()
     _PLANS[sig_key] = plan
     return plan

@@ -7,18 +7,12 @@ padding/layout wrappers and the ``stage1="pallas"`` adapters used by
 validated against.
 
 Callers normally do not import this package directly: ``repro.make_plan``
-dispatches into it when a plan selects a ``pallas_*`` backend.  The import
-is kept lazy/fallible so builds without Pallas can still use the jnp and
-dist backends (``PALLAS_AVAILABLE`` reports the outcome).
+dispatches into it when a plan selects a ``pallas_*`` backend.
 """
 
-try:
-    from repro.kernels import ops  # noqa: F401
-    from repro.kernels import pack  # noqa: F401
-    from repro.kernels.ops import (  # noqa: F401
-        alm_from_delta_auto, anal, delta_from_alm_auto, pick_layout,
-        pick_variant, should_interpret, synth,
-    )
-    PALLAS_AVAILABLE = True
-except Exception:  # pragma: no cover - non-Pallas builds raise Import-,
-    PALLAS_AVAILABLE = False  # Attribute- or jaxlib-mismatch RuntimeErrors
+from repro.kernels import ops  # noqa: F401
+from repro.kernels import pack  # noqa: F401
+from repro.kernels.ops import (  # noqa: F401
+    alm_from_delta_auto, anal, delta_from_alm_auto, pick_layout,
+    pick_variant, should_interpret, synth,
+)

@@ -82,13 +82,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.autodiff import linear_pair
-from repro.kernels.legendre_pallas import _CompilerParams, _pad_rows, _step
+from repro.kernels.legendre_pallas import (F32_DOT, _pad_rows, _ring_rows,
+                                           _step)
 
 __all__ = [
     "synth_fused_vpu", "synth_fused_mxu",
     "anal_fused_vpu", "anal_fused_mxu",
     "fused_synth", "fused_anal",
-    "fused_synth_bucket", "fused_anal_bucket",
+    "fused_synth_bucket", "fused_anal_bucket", "rotation_tables",
 ]
 
 def _fill_panel(panel_ref, x, m0, m1, mp0, mp1, jsw, base, lp_size, spin,
@@ -133,15 +134,16 @@ def _fill_panel(panel_ref, x, m0, m1, mp0, mp1, jsw, base, lp_size, spin,
 
 
 def _hi_row_mask(base, jsw, lp_size):
-    iot = jax.lax.broadcasted_iota(jnp.int32, (lp_size, 1), 0)
+    """(1, LP): stream positions of the panel that belong to segment 1."""
+    iot = jax.lax.broadcasted_iota(jnp.int32, (1, lp_size), 1)
     return (base + iot) >= jsw
 
 
 def _parity_masks(base, jsw, lp_size):
     """(l + m) even per packed stream position, per segment -- the fold
     plane split.  2m is even so only the panel-local l offset counts:
-    seg0 l = l0 + base + j, seg1 l = l0 + base + j - seam."""
-    iot = jax.lax.broadcasted_iota(jnp.int32, (lp_size, 1), 0)
+    seg0 l = l0 + base + j, seg1 l = l0 + base + j - seam.  (1, LP)."""
+    iot = jax.lax.broadcasted_iota(jnp.int32, (1, lp_size), 1)
     par0 = ((base + iot) % 2) == 0
     par1 = ((base + iot - jsw) % 2) == 0
     return par0, par1
@@ -185,7 +187,7 @@ def _synth_fused_vpu_kernel(m0_ref, m1_ref, mp0_ref, mp1_ref, seed_ref,
     pp_ref[...], pc_ref[...], sc_ref[...] = carry
 
     panel = panel_ref[...].reshape(lp_size, -1)       # (LP, 8*128)
-    a_blk = a_ref[0]                          # (LP, 2K)
+    a_blk = a_ref[0]                          # (2K, LP)
     hi_row = _hi_row_mask(base, jsw, lp_size)
     if n_pl == 2:
         par0, par1 = _parity_masks(base, jsw, lp_size)
@@ -195,8 +197,9 @@ def _synth_fused_vpu_kernel(m0_ref, m1_ref, mp0_ref, mp1_ref, seed_ref,
         if n_pl == 2:
             par = par1 if seg else par0
             a_seg = jnp.concatenate([jnp.where(par, a_seg, 0.0),
-                                     jnp.where(par, 0.0, a_seg)], axis=1)
-        d = jax.lax.dot_general(a_seg, panel, (((0,), (0,)), ((), ())),
+                                     jnp.where(par, 0.0, a_seg)], axis=0)
+        d = jax.lax.dot_general(a_seg, panel, (((1,), (0,)), ((), ())),
+                                precision=F32_DOT,
                                 preferred_element_type=jnp.float32)
         d = d.reshape(n_pl * 2 * n_k, 8, 128)
         if n_pl == 2:
@@ -223,7 +226,8 @@ def synth_fused_vpu(a_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max,
                     spin=0, lp_size=128, interpret=True):
     """VPU fused synthesis on the packed (slot, panel) grid.
 
-    a_pk   : (n_slots, S, 2K) f32 packed coefficient streams
+    a_pk   : (n_slots, 2K, S) f32 packed coefficient streams (the stream
+             axis minor: lane-dense for any K)
     maps   : (m0, m1, mp0, mp1, seed) i32 per-slot scalar-prefetch arrays
     x2d    : (R1, 128) f32;  pmm_pk/pms_pk: (n_slots, 2, R1, 128)
     tab_pk : (n_slots, 2, n_pl, 4, Rf1, 128) f32 per-(segment, plane) phase
@@ -231,7 +235,7 @@ def synth_fused_vpu(a_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max,
              multi-row grids); n_pl == 2 on the equator-fold path
     returns: (n_slots, 2, n_pl, 2K, R1, 128) f32 rotated spectrum rows
     """
-    n_slots, S, K2 = a_pk.shape
+    n_slots, K2, S = a_pk.shape
     n_pl = tab_pk.shape[2]
     R1 = x2d.shape[0]
     assert S % lp_size == 0 and R1 % 8 == 0 and K2 % 2 == 0
@@ -258,8 +262,8 @@ def synth_fused_vpu(a_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max,
                 pl.BlockSpec((1, 2, 8, 128),
                              lambda s, rb, sp, *_refs: (s, 0, rb, 0)),
                 tab_spec,
-                pl.BlockSpec((1, lp_size, K2),
-                             lambda s, rb, sp, *_refs: (s, sp, 0)),
+                pl.BlockSpec((1, K2, lp_size),
+                             lambda s, rb, sp, *_refs: (s, 0, sp)),
             ],
             out_specs=pl.BlockSpec((1, 2, n_pl, K2, 8, 128),
                                    lambda s, rb, sp, *_refs:
@@ -275,7 +279,7 @@ def synth_fused_vpu(a_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max,
         out_shape=jax.ShapeDtypeStruct((n_slots, 2, n_pl, K2, R1, 128),
                                        jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*maps, x2d, pmm_pk, pms_pk, tab_pk, a_pk)
 
@@ -337,7 +341,7 @@ def _synth_fused_mxu_kernel(*refs, lp_size, n_k, n_sp, l_max, bf16, spin,
     panel = panel_ref[...]                    # (LP, 128)
     if bf16:
         panel = panel.astype(jnp.bfloat16)
-    a_blk = a_ref[0]                          # (LP, 2K)
+    a_blk = a_ref[0]                          # (2K, LP)
     hi_row = _hi_row_mask(base, jsw, lp_size)
     if n_pl == 2:
         par0, par1 = _parity_masks(base, jsw, lp_size)
@@ -349,24 +353,25 @@ def _synth_fused_mxu_kernel(*refs, lp_size, n_k, n_sp, l_max, bf16, spin,
         if n_pl == 2:
             par = par1 if seg else par0
             a_seg = jnp.concatenate([jnp.where(par, a_seg, 0.0),
-                                     jnp.where(par, 0.0, a_seg)], axis=1)
-        return jax.lax.dot_general(panel, a_seg, (((0,), (0,)), ((), ())),
+                                     jnp.where(par, 0.0, a_seg)], axis=0)
+        return jax.lax.dot_general(a_seg, panel, (((1,), (0,)), ((), ())),
+                                   precision=None if bf16 else F32_DOT,
                                    preferred_element_type=jnp.float32)
 
-    def flush(seg, cs):                       # (128, n_pl*2K)
+    def flush(seg, cs):                       # (n_pl*2K, 128)
         if n_pl == 2:
-            e, o = cs[:, :K2], cs[:, K2:]
+            e, o = cs[:K2], cs[K2:]
             planes = (e + o, e - o)           # north | south
         else:
             planes = (cs,)
         for pi, cp in enumerate(planes):
             if rot:
-                c_re, c_im = cp[:, :n_k], cp[:, n_k:]
+                c_re, c_im = cp[:n_k], cp[n_k:]
                 t = tab_ref[0, seg, pi][:, 0, :]  # (4, 128)
                 cp = jnp.concatenate(
-                    [t[0][:, None] * c_re + t[1][:, None] * c_im,
-                     t[2][:, None] * c_re + t[3][:, None] * c_im],
-                    axis=1)
+                    [t[0][None, :] * c_re + t[1][None, :] * c_im,
+                     t[2][None, :] * c_re + t[3][None, :] * c_im],
+                    axis=0)
             out_ref[0, seg, pi] = cp
 
     if n_sp == 1:
@@ -389,12 +394,12 @@ def synth_fused_mxu(a_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max,
 
     Layouts as :func:`synth_fused_vpu` except rings advance 128 at a time;
     tab_pk is (n_slots, 2, n_pl, 4, R1, 128); returns
-    (n_slots, 2, n_pl, R, 2K) with R = R1 * 128.  ``bf16=True`` contracts
+    (n_slots, 2, n_pl, 2K, R) with R = R1 * 128.  ``bf16=True`` contracts
     the recurrence panel in bfloat16 with f32 accumulation.  ``rot=False``
     (identity tables, see :func:`_tables_identity`) drops the table
     operand and the rotate epilogue.
     """
-    n_slots, S, K2 = a_pk.shape
+    n_slots, K2, S = a_pk.shape
     n_pl = tab_pk.shape[2]
     R1 = x2d.shape[0]
     R = R1 * 128
@@ -407,21 +412,22 @@ def synth_fused_mxu(a_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max,
                                n_k=K2 // 2, n_sp=n_sp, l_max=l_max,
                                bf16=bf16, spin=spin, n_pl=n_pl, rot=rot)
     in_specs = [
-        pl.BlockSpec((1, 128), lambda s, rb, sp, *_refs: (rb, 0)),
-        pl.BlockSpec((1, 2, 1, 128),
-                     lambda s, rb, sp, *_refs: (s, 0, rb, 0)),
-        pl.BlockSpec((1, 2, 1, 128),
-                     lambda s, rb, sp, *_refs: (s, 0, rb, 0)),
+        pl.BlockSpec((None, 1, 128), lambda s, rb, sp, *_refs: (rb, 0, 0)),
+        pl.BlockSpec((1, 2, None, 1, 128),
+                     lambda s, rb, sp, *_refs: (s, 0, rb, 0, 0)),
+        pl.BlockSpec((1, 2, None, 1, 128),
+                     lambda s, rb, sp, *_refs: (s, 0, rb, 0, 0)),
     ]
-    operands = [x2d, pmm_pk.reshape(n_slots, 2, R1, 128),
-                pms_pk.reshape(n_slots, 2, R1, 128)]
+    operands = [_ring_rows(x2d),
+                _ring_rows(pmm_pk.reshape(n_slots, 2, R1, 128)),
+                _ring_rows(pms_pk.reshape(n_slots, 2, R1, 128))]
     if rot:
         in_specs.append(
-            pl.BlockSpec((1, 2, n_pl, 4, 1, 128),
-                         lambda s, rb, sp, *_refs: (s, 0, 0, 0, rb, 0)))
-        operands.append(tab_pk)
-    in_specs.append(pl.BlockSpec((1, lp_size, K2),
-                                 lambda s, rb, sp, *_refs: (s, sp, 0)))
+            pl.BlockSpec((1, 2, n_pl, 4, None, 1, 128),
+                         lambda s, rb, sp, *_refs: (s, 0, 0, 0, rb, 0, 0)))
+        operands.append(_ring_rows(tab_pk))
+    in_specs.append(pl.BlockSpec((1, K2, lp_size),
+                                 lambda s, rb, sp, *_refs: (s, 0, sp)))
     operands.append(a_pk)
     scratch = [
         pltpu.VMEM((1, 128), jnp.float32),
@@ -430,22 +436,22 @@ def synth_fused_mxu(a_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max,
         pltpu.VMEM((lp_size, 128), jnp.float32),
     ]
     if n_sp > 1:
-        scratch.append(pltpu.VMEM((2, 128, n_pl * K2), jnp.float32))
+        scratch.append(pltpu.VMEM((2, n_pl * K2, 128), jnp.float32))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 2, n_pl, 128, K2),
+            out_specs=pl.BlockSpec((1, 2, n_pl, K2, 128),
                                    lambda s, rb, sp, *_refs:
-                                   (s, 0, 0, rb, 0)),
+                                   (s, 0, 0, 0, rb)),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((n_slots, 2, n_pl, R, K2),
+        out_shape=jax.ShapeDtypeStruct((n_slots, 2, n_pl, K2, R),
                                        jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*maps, *operands)
 
@@ -474,7 +480,10 @@ def _anal_fused_vpu_kernel(m0_ref, m1_ref, mp0_ref, mp1_ref, seed_ref,
         pc_ref[...] = jnp.zeros_like(pc_ref)
         sc_ref[...] = jnp.zeros_like(sc_ref)
 
-    @pl.when(rb == 0)
+    # the whole slot's output stays resident across its (ring block,
+    # panel) steps: every ring block adds into every panel's rows, and
+    # an output block is only kept in VMEM between consecutive steps
+    @pl.when((rb == 0) & (sp == 0))
     def _init_out():
         out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -511,16 +520,18 @@ def _anal_fused_vpu_kernel(m0_ref, m1_ref, mp0_ref, mp1_ref, seed_ref,
 
     panel = panel_ref[...].reshape(lp_size, -1)       # (LP, 8*128)
     dims = (((1,), (1,)), ((), ()))           # NT gemm over the ring tile
-    c0 = jax.lax.dot_general(panel, dbuf_ref[0].reshape(n_pl * K2, -1),
-                             dims, preferred_element_type=jnp.float32)
-    c1 = jax.lax.dot_general(panel, dbuf_ref[1].reshape(n_pl * K2, -1),
-                             dims, preferred_element_type=jnp.float32)
+    c0 = jax.lax.dot_general(dbuf_ref[0].reshape(n_pl * K2, -1), panel,
+                             dims, precision=F32_DOT,
+                             preferred_element_type=jnp.float32)
+    c1 = jax.lax.dot_general(dbuf_ref[1].reshape(n_pl * K2, -1), panel,
+                             dims, precision=F32_DOT,
+                             preferred_element_type=jnp.float32)
     hi_row = _hi_row_mask(base, jsw, lp_size)
     if n_pl == 2:
         par0, par1 = _parity_masks(base, jsw, lp_size)
-        c0 = jnp.where(par0, c0[:, :K2], c0[:, K2:])
-        c1 = jnp.where(par1, c1[:, :K2], c1[:, K2:])
-    out_ref[0] += jnp.where(hi_row, c1, c0)   # (LP, 2K)
+        c0 = jnp.where(par0, c0[:K2], c0[K2:])
+        c1 = jnp.where(par1, c1[:K2], c1[K2:])
+    out_ref[0, sp] += jnp.where(hi_row, c1, c0)   # (2K, LP)
 
 
 def anal_fused_vpu(f_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max, s_len,
@@ -530,7 +541,8 @@ def anal_fused_vpu(f_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max, s_len,
     f_pk   : (n_slots, 2, n_pl, 2K, Rf1, 128) gathered per-plane FFT rows
              per segment, ring-shrunk like ``tab_pk``
     tab_pk : (n_slots, 2, n_pl, 4, Rf1, 128) f32 anal-direction tables
-    returns: (n_slots, S, 2K) f32 packed l-stream rows
+    returns: (n_slots, S // LP, 2K, LP) f32 packed l-stream rows, one
+             lane-dense (2K, LP) block per panel
     """
     n_slots, n_seg, n_pl, K2 = f_pk.shape[:4]
     R1 = x2d.shape[0]
@@ -558,8 +570,8 @@ def anal_fused_vpu(f_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max, s_len,
                 pl.BlockSpec((1, 2, n_pl, 4, rf, 128), idx),
                 pl.BlockSpec((1, 2, n_pl, K2, rf, 128), idx),
             ],
-            out_specs=pl.BlockSpec((1, lp_size, K2),
-                                   lambda s, rb, sp, *_refs: (s, sp, 0)),
+            out_specs=pl.BlockSpec((1, S // lp_size, K2, lp_size),
+                                   lambda s, rb, sp, *_refs: (s, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((8, 128), jnp.float32),
                 pltpu.VMEM((8, 128), jnp.float32),
@@ -568,9 +580,10 @@ def anal_fused_vpu(f_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max, s_len,
                 pltpu.VMEM((2, n_pl * K2, 8, 128), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_slots, S, K2), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_slots, S // lp_size, K2, lp_size),
+                                       jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
     )(*maps, x2d, pmm_pk, pms_pk, tab_pk, f_pk)
 
@@ -597,7 +610,8 @@ def _anal_fused_mxu_kernel(*refs, lp_size, n_k, l_max, bf16, spin, n_pl,
         pc_ref[...] = jnp.zeros_like(pc_ref)
         sc_ref[...] = jnp.zeros_like(sc_ref)
 
-    @pl.when(rb == 0)
+    # resident per-slot output, as in the VPU analysis kernel
+    @pl.when((rb == 0) & (sp == 0))
     def _init_out():
         out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -613,22 +627,22 @@ def _anal_fused_mxu_kernel(*refs, lp_size, n_k, l_max, bf16, spin, n_pl,
     # VMEM scratch instead of recomputed every grid step
     @pl.when(sp == 0)
     def _rotate():
-        f = f_ref[0]                          # (2, n_pl, 128, 2K)
+        f = f_ref[0]                          # (2, n_pl, 2K, 128)
         for seg in (0, 1):
             dp = []
             for pi in range(n_pl):
                 fs = f[seg, pi]
                 if rot:
-                    f_re, f_im = fs[:, :n_k], fs[:, n_k:]
+                    f_re, f_im = fs[:n_k], fs[n_k:]
                     t = tab_ref[0, seg, pi][:, 0, :]  # (4, 128)
                     fs = jnp.concatenate(
-                        [t[0][:, None] * f_re + t[1][:, None] * f_im,
-                         t[2][:, None] * f_re + t[3][:, None] * f_im],
-                        axis=1)
+                        [t[0][None, :] * f_re + t[1][None, :] * f_im,
+                         t[2][None, :] * f_re + t[3][None, :] * f_im],
+                        axis=0)
                 dp.append(fs)
             if n_pl == 2:
                 dbuf_ref[seg] = jnp.concatenate([dp[0] + dp[1],
-                                                 dp[0] - dp[1]], axis=1)
+                                                 dp[0] - dp[1]], axis=0)
             else:
                 dbuf_ref[seg] = dp[0]
 
@@ -645,22 +659,24 @@ def _anal_fused_mxu_kernel(*refs, lp_size, n_k, l_max, bf16, spin, n_pl,
     pp_ref[...], pc_ref[...], sc_ref[...] = carry
 
     panel = panel_ref[...]                    # (LP, 128)
-    d = dbuf_ref[...]                         # (2, 128, W)
+    d = dbuf_ref[...]                         # (2, W, 128)
     if bf16:
         panel = panel.astype(jnp.bfloat16)
         d = d.astype(jnp.bfloat16)
     # two narrow ring contractions (one per segment), as in the staged
     # kernel -- a single wide [seg0 | seg1] dot is measurably slower
-    c0 = jax.lax.dot_general(panel, d[0], (((1,), (0,)), ((), ())),
+    dims = (((1,), (1,)), ((), ()))           # NT gemm over the ring block
+    prec = None if bf16 else F32_DOT
+    c0 = jax.lax.dot_general(d[0], panel, dims, precision=prec,
                              preferred_element_type=jnp.float32)
-    c1 = jax.lax.dot_general(panel, d[1], (((1,), (0,)), ((), ())),
+    c1 = jax.lax.dot_general(d[1], panel, dims, precision=prec,
                              preferred_element_type=jnp.float32)
     hi_row = _hi_row_mask(base, jsw, lp_size)
     if n_pl == 2:
         par0, par1 = _parity_masks(base, jsw, lp_size)
-        c0 = jnp.where(par0, c0[:, :K2], c0[:, K2:])
-        c1 = jnp.where(par1, c1[:, :K2], c1[:, K2:])
-    out_ref[0] += jnp.where(hi_row, c1, c0)
+        c0 = jnp.where(par0, c0[:K2], c0[K2:])
+        c1 = jnp.where(par1, c1[:K2], c1[K2:])
+    out_ref[0, sp] += jnp.where(hi_row, c1, c0)   # (2K, LP)
 
 
 def anal_fused_mxu(f_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max, s_len,
@@ -668,12 +684,13 @@ def anal_fused_mxu(f_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max, s_len,
                    rot=True):
     """MXU fused analysis (ring-contraction matmul + hoisted rotation).
 
-    f_pk   : (n_slots, 2, n_pl, R, 2K) gathered per-plane FFT rows
-    returns: (n_slots, S, 2K) f32 packed l-stream rows
+    f_pk   : (n_slots, 2, n_pl, 2K, R) gathered per-plane FFT rows
+    returns: (n_slots, S // LP, 2K, LP) f32 packed l-stream rows (as
+             :func:`anal_fused_vpu`)
     ``rot=False`` (identity tables) drops the table operand and the
     rotate half of the per-ring-block prologue.
     """
-    n_slots, n_seg, n_pl, R, K2 = f_pk.shape
+    n_slots, n_seg, n_pl, K2, R = f_pk.shape
     R1 = R // 128
     assert n_seg == 2 and R % 128 == 0 and K2 % 2 == 0
     S = int(s_len)
@@ -683,21 +700,22 @@ def anal_fused_mxu(f_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max, s_len,
                                n_k=K2 // 2, l_max=l_max, bf16=bf16,
                                spin=spin, n_pl=n_pl, rot=rot)
     in_specs = [
-        pl.BlockSpec((1, 128), lambda s, rb, sp, *_refs: (rb, 0)),
-        pl.BlockSpec((1, 2, 1, 128),
-                     lambda s, rb, sp, *_refs: (s, 0, rb, 0)),
-        pl.BlockSpec((1, 2, 1, 128),
-                     lambda s, rb, sp, *_refs: (s, 0, rb, 0)),
+        pl.BlockSpec((None, 1, 128), lambda s, rb, sp, *_refs: (rb, 0, 0)),
+        pl.BlockSpec((1, 2, None, 1, 128),
+                     lambda s, rb, sp, *_refs: (s, 0, rb, 0, 0)),
+        pl.BlockSpec((1, 2, None, 1, 128),
+                     lambda s, rb, sp, *_refs: (s, 0, rb, 0, 0)),
     ]
-    operands = [x2d, pmm_pk.reshape(n_slots, 2, R1, 128),
-                pms_pk.reshape(n_slots, 2, R1, 128)]
+    operands = [_ring_rows(x2d),
+                _ring_rows(pmm_pk.reshape(n_slots, 2, R1, 128)),
+                _ring_rows(pms_pk.reshape(n_slots, 2, R1, 128))]
     if rot:
         in_specs.append(
-            pl.BlockSpec((1, 2, n_pl, 4, 1, 128),
-                         lambda s, rb, sp, *_refs: (s, 0, 0, 0, rb, 0)))
-        operands.append(tab_pk)
-    in_specs.append(pl.BlockSpec((1, 2, n_pl, 128, K2),
-                                 lambda s, rb, sp, *_refs: (s, 0, 0, rb, 0)))
+            pl.BlockSpec((1, 2, n_pl, 4, None, 1, 128),
+                         lambda s, rb, sp, *_refs: (s, 0, 0, 0, rb, 0, 0)))
+        operands.append(_ring_rows(tab_pk))
+    in_specs.append(pl.BlockSpec((1, 2, n_pl, K2, 128),
+                                 lambda s, rb, sp, *_refs: (s, 0, 0, 0, rb)))
     operands.append(f_pk)
     return pl.pallas_call(
         kernel,
@@ -705,19 +723,20 @@ def anal_fused_mxu(f_pk, maps, x2d, pmm_pk, pms_pk, tab_pk, *, l_max, s_len,
             num_scalar_prefetch=5,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, lp_size, K2),
-                                   lambda s, rb, sp, *_refs: (s, sp, 0)),
+            out_specs=pl.BlockSpec((1, S // lp_size, K2, lp_size),
+                                   lambda s, rb, sp, *_refs: (s, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((1, 128), jnp.float32),
                 pltpu.VMEM((1, 128), jnp.float32),
                 pltpu.VMEM((1, 128), jnp.int32),
                 pltpu.VMEM((lp_size, 128), jnp.float32),
-                pltpu.VMEM((2, 128, n_pl * K2), jnp.float32),
+                pltpu.VMEM((2, n_pl * K2, 128), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_slots, S, K2), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_slots, S // lp_size, K2, lp_size),
+                                       jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
     )(*maps, *operands)
 
@@ -749,13 +768,13 @@ def _prep(lo, x, pmm, pms, var):
 
 
 def _pack_tables(tabs, lo, Rf1):
-    """(M, n_pl, 4, R) f64 rotation tables ->
+    """(M, n_pl, 4, R) f32 rotation tables ->
     (n_slots, 2, n_pl, 4, Rf1, 128) f32, ring-shrunk to the kernels'
     data-operand row count."""
     from repro.kernels import ops as kops
     _, n_pl, _, R = tabs.shape
-    t = jnp.asarray(np.pad(tabs, ((0, 0), (0, 0), (0, 0),
-                                  (0, Rf1 * 128 - R))), jnp.float32)
+    t = jnp.pad(jnp.asarray(tabs, jnp.float32),
+                ((0, 0), (0, 0), (0, 0), (0, Rf1 * 128 - R)))
     return kops._pack_rows(t, lo).reshape(lo.n_slots, 2, n_pl, 4, Rf1, 128)
 
 
@@ -783,8 +802,27 @@ def _rotation_tables(m_vals, direction, *, phase_kind, n, phi0, fold_rings,
     return np.stack([north, south], axis=1)
 
 
+def rotation_tables(m_vals, *, phase_kind, phi0, n=None, fold_rings=None,
+                    n_half=None):
+    """Host-side rotation tables of both fused directions.
+
+    Returns ``((synth, anal), (rot_synth, rot_anal))``: float32 tables
+    (Mr, n_pl, 4, R_kernel) computed in float64 and cast at the end, and
+    per direction whether the table rotates at all (False: the exact
+    identity, see :func:`_tables_identity`).  Plans build these once and
+    pass them to the fused entry points as jit arguments (``tabs=``/
+    ``rot=``), so they are never baked into a program as constants."""
+    tabs, rot = [], []
+    for direction in ("synth", "anal"):
+        t = _rotation_tables(m_vals, direction, phase_kind=phase_kind, n=n,
+                             phi0=phi0, fold_rings=fold_rings, n_half=n_half)
+        tabs.append(t.astype(np.float32))
+        rot.append(not _tables_identity(t))
+    return tuple(tabs), tuple(rot)
+
+
 def _kernel_synth(a, tabs, x, pmm, pms, *, l_max, var, bf16, lo, lp_size,
-                  interpret, spin):
+                  interpret, spin, rot):
     """Packed fused kernel leg: a (Mr, L1, 2K) + (Mr, n_pl, 4, R) tables ->
     rotated per-plane rows h (Mr, n_pl, R, 2K)."""
     from repro.kernels import ops as kops
@@ -792,7 +830,7 @@ def _kernel_synth(a, tabs, x, pmm, pms, *, l_max, var, bf16, lo, lp_size,
     K2 = a.shape[-1]
     n_pl = tabs.shape[1]
     R = x.shape[0]
-    a_pk = kops._pack_a(a, lo)
+    a_pk = jnp.swapaxes(kops._pack_a(a, lo), 1, 2)     # (n_slots, 2K, S)
     Rp, R1, Rf1, x2d, pmm2, pms2 = _prep(lo, x, pmm, pms, var)
     tab_pk = _pack_tables(tabs, lo, Rf1)
     pmaps = kops._pack_maps(lo)
@@ -800,18 +838,18 @@ def _kernel_synth(a, tabs, x, pmm, pms, *, l_max, var, bf16, lo, lp_size,
         out = synth_fused_vpu(a_pk, pmaps, x2d, pmm2, pms2, tab_pk,
                               l_max=l_max, spin=spin, lp_size=lp_size,
                               interpret=interpret)
-        out = jnp.moveaxis(out, 3, -1).reshape(lo.n_slots, 2, n_pl, Rp, K2)
+        out = out.reshape(lo.n_slots, 2, n_pl, K2, Rp)
     else:
         out = synth_fused_mxu(a_pk, pmaps, x2d, pmm2, pms2, tab_pk,
                               l_max=l_max, spin=spin, bf16=bf16,
                               lp_size=lp_size, interpret=interpret,
-                              rot=not _tables_identity(tabs))
-    seg = out.reshape(lo.n_slots * 2, n_pl, Rp, K2)
+                              rot=rot)
+    seg = jnp.moveaxis(out, 3, -1).reshape(lo.n_slots * 2, n_pl, Rp, K2)
     return kops._unpack_rows(seg, lo, Mr)[:, :, :R, :]
 
 
 def _kernel_anal(fp, tabs, x, pmm, pms, *, l_max, var, bf16, lo, lp_size,
-                 interpret, spin):
+                 interpret, spin, rot):
     """Packed fused kernel leg: per-plane unrotated-input rows fp
     (Mr, n_pl, R, 2K) + anal tables -> packed a (Mr, L1, 2K)."""
     from repro.kernels import ops as kops
@@ -822,17 +860,18 @@ def _kernel_anal(fp, tabs, x, pmm, pms, *, l_max, var, bf16, lo, lp_size,
     f_pk = kops._pack_rows(
         jnp.pad(fp, ((0, 0), (0, 0), (0, Rf1 * 128 - R), (0, 0))), lo)
     f_pk = f_pk.reshape(lo.n_slots, 2, n_pl, Rf1, 128, K2)
+    fk = jnp.moveaxis(f_pk, -1, 3)            # (n_slots, 2, n_pl, 2K, Rf1, 128)
     if var == "vpu":
-        fk = jnp.moveaxis(f_pk, -1, 3)        # (n_slots, 2, n_pl, 2K, Rf1, 128)
         out = anal_fused_vpu(fk, pmaps, x2d, pmm2, pms2, tab_pk,
                              l_max=l_max, s_len=lo.S, spin=spin,
                              lp_size=lp_size, interpret=interpret)
     else:
-        out = anal_fused_mxu(f_pk.reshape(lo.n_slots, 2, n_pl, Rp, K2),
+        out = anal_fused_mxu(fk.reshape(lo.n_slots, 2, n_pl, K2, Rp),
                              pmaps, x2d, pmm2, pms2, tab_pk, l_max=l_max,
                              s_len=lo.S, spin=spin, bf16=bf16,
                              lp_size=lp_size, interpret=interpret,
-                             rot=not _tables_identity(tabs))
+                             rot=rot)
+    out = jnp.moveaxis(out, 2, -1).reshape(lo.n_slots, lo.S, K2)
     return kops._unpack_alm(out, lo)
 
 
@@ -896,22 +935,21 @@ def _bucket_gather(maps_w, m_vals, layout, pos, n_phi):
     return delta
 
 
-def _synth_chain(a, m_vals, x, pmm, pms, *, l_max, var, bf16, lo, lp_size,
-                 interpret, spin, phase_kind, n=None, phi0=None,
+def _synth_chain(a, m_vals, x, pmm, pms, tabs, *, l_max, var, bf16, lo,
+                 lp_size, interpret, spin, rot, phase_kind, n=None,
                  fold_rings=None, bucket=None):
     """Weight-free fused synthesis for every fused plan shape:
     a (Mr, L1, 2K) f32 -> maps (R_out, width, C) f32.  ``Mr`` is the
     kernel row count (2M lambda^{+-} rows on the spin path, C = 2K Q|U
-    channels out)."""
+    channels out); ``tabs``/``rot`` are the (synth, anal) pairs from
+    :func:`rotation_tables`."""
     from repro.core import legendre as leg
     from repro.core import phase
     K2 = a.shape[-1]
     n_k = K2 // 2
-    tabs = _rotation_tables(m_vals, "synth", phase_kind=phase_kind, n=n,
-                            phi0=phi0, fold_rings=fold_rings,
-                            n_half=x.shape[0])
-    h = _kernel_synth(a, tabs, x, pmm, pms, l_max=l_max, var=var, bf16=bf16,
-                      lo=lo, lp_size=lp_size, interpret=interpret, spin=spin)
+    h = _kernel_synth(a, tabs[0], x, pmm, pms, l_max=l_max, var=var,
+                      bf16=bf16, lo=lo, lp_size=lp_size, interpret=interpret,
+                      spin=spin, rot=rot[0])
     if fold_rings is not None:
         # in-kernel combine already produced (north | south) planes; the
         # south rows come out in fold order (equator-out), reverse + trim
@@ -940,8 +978,8 @@ def _synth_chain(a, m_vals, x, pmm, pms, *, l_max, var, bf16, lo, lp_size,
     return (jnp.fft.irfft(H, n=n, axis=1) * n).astype(jnp.float32)
 
 
-def _anal_chain(maps_w, m_vals, x, pmm, pms, *, l_max, var, bf16, lo,
-                lp_size, interpret, spin, phase_kind, n=None, phi0=None,
+def _anal_chain(maps_w, m_vals, x, pmm, pms, tabs, *, l_max, var, bf16, lo,
+                lp_size, interpret, spin, rot, phase_kind, n=None,
                 fold_rings=None, bucket=None):
     """Weight-free fused analysis core: (already ring-weighted) maps
     (R_full, W, C) f32 -> a (Mr, L1, 2K) f32."""
@@ -974,12 +1012,9 @@ def _anal_chain(maps_w, m_vals, x, pmm, pms, *, l_max, var, bf16, lo,
         fp = jnp.stack([f_n, f_s], axis=1)    # (Mr, 2, nh, 2K)
     else:
         fp = f[:, None]                       # (Mr, 1, R, 2K)
-    tabs = _rotation_tables(m_vals, "anal", phase_kind=phase_kind, n=n,
-                            phi0=phi0, fold_rings=fold_rings,
-                            n_half=x.shape[0])
-    return _kernel_anal(fp, tabs, x, pmm, pms, l_max=l_max, var=var,
+    return _kernel_anal(fp, tabs[1], x, pmm, pms, l_max=l_max, var=var,
                         bf16=bf16, lo=lo, lp_size=lp_size,
-                        interpret=interpret, spin=spin)
+                        interpret=interpret, spin=spin, rot=rot[1])
 
 
 def _resolve(m_vals, l_max, lp_size, lo, interpret, mp_vals=None):
@@ -992,6 +1027,14 @@ def _resolve(m_vals, l_max, lp_size, lo, interpret, mp_vals=None):
     if interpret is None:
         interpret = should_interpret()
     return lo, interpret
+
+
+def _tables(tabs, rot, m_vals, **geometry):
+    """The caller's precomputed ``(tabs, rot)`` pair, or one built here
+    (host numpy, so a traced caller embeds it as a program constant)."""
+    if tabs is None:
+        return rotation_tables(m_vals, **geometry)
+    return tabs, rot
 
 
 # The whole-chain adjoints below compose the staged pipeline's transposes:
@@ -1008,9 +1051,64 @@ def _resolve(m_vals, l_max, lp_size, lo, interpret, mp_vals=None):
 #         verified in tests/test_fused.py adjoint identities.
 
 
+def _linear_synth(a, m_vals, x, pmm, pms, tabs, kw):
+    """synth chain + its adjoint (``fac``-compensated anal chain)."""
+    from repro.core.phase import _fac_rows
+    fac = _fac_rows(m_vals, jnp.float32)
+    bsc = 0.5 if kw["spin"] else 1.0
+
+    def fwd(res, a_):
+        return _synth_chain(a_, m_vals, *res, **kw)
+
+    def bwd(res, t):
+        return bsc * fac * _anal_chain(t, m_vals, *res, **kw)
+
+    return linear_pair(fwd, bwd, (x, pmm, pms, tabs), a)
+
+
+def _linear_anal(maps, weights, m_vals, x, pmm, pms, tabs, kw):
+    """Ring weights outside the linear core, then anal chain + adjoint."""
+    from repro.core.phase import _fac_rows
+    fac = _fac_rows(m_vals, jnp.float32)
+    bsc = 0.5 if kw["spin"] else 1.0
+    w = jnp.asarray(weights, jnp.float32)
+    maps_w = jnp.asarray(maps, jnp.float32) * w[:, None, None]
+
+    def fwd(res, mw):
+        return _anal_chain(mw, m_vals, *res, **kw)
+
+    def bwd(res, g):
+        return _synth_chain(g / (bsc * fac), m_vals, *res, **kw)
+
+    return linear_pair(fwd, bwd, (x, pmm, pms, tabs), maps_w)
+
+
+def _uniform_kw(m_vals, x, *, l_max, n, phi0, variant, bf16, lo, lp_size,
+                interpret, mp_vals, fold_rings, tabs, rot):
+    lo, interpret = _resolve(m_vals, l_max, lp_size, lo, interpret, mp_vals)
+    tabs, rot = _tables(tabs, rot, m_vals, phase_kind="uniform", n=n,
+                        phi0=phi0, fold_rings=fold_rings, n_half=x.shape[0])
+    kw = dict(l_max=l_max, var=variant, bf16=bf16, lo=lo, lp_size=lp_size,
+              interpret=interpret, spin=2 if lo.spin else 0, rot=rot,
+              phase_kind="uniform", n=n, fold_rings=fold_rings)
+    return tabs, kw
+
+
+def _bucket_kw(m_vals, *, l_max, layout, pos, neg, n_phi, phi0, out_width,
+               variant, bf16, lo, lp_size, interpret, mp_vals, tabs, rot):
+    lo, interpret = _resolve(m_vals, l_max, lp_size, lo, interpret, mp_vals)
+    tabs, rot = _tables(tabs, rot, m_vals, phase_kind="bucket", phi0=phi0)
+    bucket = dict(layout=layout, pos=np.asarray(pos), neg=np.asarray(neg),
+                  n_phi=np.asarray(n_phi), out_width=int(out_width))
+    kw = dict(l_max=l_max, var=variant, bf16=bf16, lo=lo, lp_size=lp_size,
+              interpret=interpret, spin=2 if lo.spin else 0, rot=rot,
+              phase_kind="bucket", bucket=bucket)
+    return tabs, kw
+
+
 def fused_synth(a, m_vals, x, pmm, pms, *, l_max, n, phi0, variant="vpu",
                 bf16=False, lo=None, lp_size=128, interpret=None,
-                mp_vals=None, fold_rings=None):
+                mp_vals=None, fold_rings=None, tabs=None, rot=None):
     """Differentiable fused synthesis on a uniform grid:
     a (Mr, L1, 2K) f32 -> maps (R, n, C).
 
@@ -1019,61 +1117,38 @@ def fused_synth(a, m_vals, x, pmm, pms, *, l_max, n, phi0, variant="vpu",
     re|im); the epilogue unpacks Q/U through the channel axis (C = 2K).
     Equator fold: pass ``fold_rings`` = the full ring count; ``x``/
     ``pmm``/``pms`` cover the northern half only and the north/south
-    combine runs in-kernel."""
-    from repro.core.phase import _fac_rows
-    lo, interpret = _resolve(m_vals, l_max, lp_size, lo, interpret, mp_vals)
-    spin = 2 if lo.spin else 0
-    kw = dict(l_max=l_max, var=variant, bf16=bf16, lo=lo, lp_size=lp_size,
-              interpret=interpret, spin=spin, phase_kind="uniform", n=n,
-              phi0=phi0, fold_rings=fold_rings)
-    fac = _fac_rows(m_vals, jnp.float32)
-    bsc = 0.5 if spin else 1.0
-
-    def fwd(res, a_):
-        x_, pmm_, pms_ = res
-        return _synth_chain(a_, m_vals, x_, pmm_, pms_, **kw)
-
-    def bwd(res, t):
-        x_, pmm_, pms_ = res
-        return bsc * fac * _anal_chain(t, m_vals, x_, pmm_, pms_, **kw)
-
-    return linear_pair(fwd, bwd, (x, pmm, pms), a)
+    combine runs in-kernel.  ``tabs``/``rot``: the precomputed
+    :func:`rotation_tables` of this geometry (built here when omitted)."""
+    tabs, kw = _uniform_kw(m_vals, x, l_max=l_max, n=n, phi0=phi0,
+                           variant=variant, bf16=bf16, lo=lo,
+                           lp_size=lp_size, interpret=interpret,
+                           mp_vals=mp_vals, fold_rings=fold_rings,
+                           tabs=tabs, rot=rot)
+    return _linear_synth(a, m_vals, x, pmm, pms, tabs, kw)
 
 
 def fused_anal(maps, weights, m_vals, x, pmm, pms, *, l_max, n, phi0,
                variant="vpu", bf16=False, lo=None, lp_size=128,
-               interpret=None, mp_vals=None, fold_rings=None):
+               interpret=None, mp_vals=None, fold_rings=None, tabs=None,
+               rot=None):
     """Differentiable fused analysis on a uniform grid:
     maps (R, n, C) -> a (Mr, L1, 2K) f32.
 
     Ring quadrature weights are applied to the maps *outside* the linear
     core (they commute with the phi-axis FFT), keeping the core's adjoint
     the weight-free fused synthesis of the fac-normalised cotangent."""
-    from repro.core.phase import _fac_rows
-    lo, interpret = _resolve(m_vals, l_max, lp_size, lo, interpret, mp_vals)
-    spin = 2 if lo.spin else 0
-    kw = dict(l_max=l_max, var=variant, bf16=bf16, lo=lo, lp_size=lp_size,
-              interpret=interpret, spin=spin, phase_kind="uniform", n=n,
-              phi0=phi0, fold_rings=fold_rings)
-    fac = _fac_rows(m_vals, jnp.float32)
-    bsc = 0.5 if spin else 1.0
-    w = jnp.asarray(weights, jnp.float32)
-    maps_w = jnp.asarray(maps, jnp.float32) * w[:, None, None]
-
-    def fwd(res, mw):
-        x_, pmm_, pms_ = res
-        return _anal_chain(mw, m_vals, x_, pmm_, pms_, **kw)
-
-    def bwd(res, g):
-        x_, pmm_, pms_ = res
-        return _synth_chain(g / (bsc * fac), m_vals, x_, pmm_, pms_, **kw)
-
-    return linear_pair(fwd, bwd, (x, pmm, pms), maps_w)
+    tabs, kw = _uniform_kw(m_vals, x, l_max=l_max, n=n, phi0=phi0,
+                           variant=variant, bf16=bf16, lo=lo,
+                           lp_size=lp_size, interpret=interpret,
+                           mp_vals=mp_vals, fold_rings=fold_rings,
+                           tabs=tabs, rot=rot)
+    return _linear_anal(maps, weights, m_vals, x, pmm, pms, tabs, kw)
 
 
 def fused_synth_bucket(a, m_vals, x, pmm, pms, *, l_max, layout, pos, neg,
                        n_phi, phi0, out_width, variant="vpu", bf16=False,
-                       lo=None, lp_size=128, interpret=None, mp_vals=None):
+                       lo=None, lp_size=128, interpret=None, mp_vals=None,
+                       tabs=None, rot=None):
     """Differentiable fused synthesis on a ragged (bucketed) grid:
     a (Mr, L1, 2K) f32 -> maps (R, out_width, C) f32.
 
@@ -1083,54 +1158,26 @@ def fused_synth_bucket(a, m_vals, x, pmm, pms, *, l_max, layout, pos, neg,
     ``layout`` a BucketLayout) runs on the host around the one kernel, so
     the unrotated Delta never round-trips HBM.  Spin-2 rides exactly like
     :func:`fused_synth` (``mp_vals`` + stacked rows)."""
-    from repro.core.phase import _fac_rows
-    lo, interpret = _resolve(m_vals, l_max, lp_size, lo, interpret, mp_vals)
-    spin = 2 if lo.spin else 0
-    bucket = dict(layout=layout, pos=np.asarray(pos), neg=np.asarray(neg),
-                  n_phi=np.asarray(n_phi), out_width=int(out_width))
-    kw = dict(l_max=l_max, var=variant, bf16=bf16, lo=lo, lp_size=lp_size,
-              interpret=interpret, spin=spin, phase_kind="bucket",
-              phi0=phi0, bucket=bucket)
-    fac = _fac_rows(m_vals, jnp.float32)
-    bsc = 0.5 if spin else 1.0
-
-    def fwd(res, a_):
-        x_, pmm_, pms_ = res
-        return _synth_chain(a_, m_vals, x_, pmm_, pms_, **kw)
-
-    def bwd(res, t):
-        x_, pmm_, pms_ = res
-        return bsc * fac * _anal_chain(t, m_vals, x_, pmm_, pms_, **kw)
-
-    return linear_pair(fwd, bwd, (x, pmm, pms), a)
+    tabs, kw = _bucket_kw(m_vals, l_max=l_max, layout=layout, pos=pos,
+                          neg=neg, n_phi=n_phi, phi0=phi0,
+                          out_width=out_width, variant=variant, bf16=bf16,
+                          lo=lo, lp_size=lp_size, interpret=interpret,
+                          mp_vals=mp_vals, tabs=tabs, rot=rot)
+    return _linear_synth(a, m_vals, x, pmm, pms, tabs, kw)
 
 
 def fused_anal_bucket(maps, weights, m_vals, x, pmm, pms, *, l_max, layout,
                       pos, neg, n_phi, phi0, variant="vpu", bf16=False,
-                      lo=None, lp_size=128, interpret=None, mp_vals=None):
+                      lo=None, lp_size=128, interpret=None, mp_vals=None,
+                      tabs=None, rot=None):
     """Differentiable fused analysis on a ragged (bucketed) grid:
     maps (R, W, C) -> a (Mr, L1, 2K) f32.  The per-bucket gather feeds
     unrotated spectrum rows to the kernel; the e^{-i m phi0} rotation
     happens in-register via the anal-direction bucket tables."""
-    from repro.core.phase import _fac_rows
-    lo, interpret = _resolve(m_vals, l_max, lp_size, lo, interpret, mp_vals)
-    spin = 2 if lo.spin else 0
-    bucket = dict(layout=layout, pos=np.asarray(pos), neg=np.asarray(neg),
-                  n_phi=np.asarray(n_phi), out_width=int(maps.shape[1]))
-    kw = dict(l_max=l_max, var=variant, bf16=bf16, lo=lo, lp_size=lp_size,
-              interpret=interpret, spin=spin, phase_kind="bucket",
-              phi0=phi0, bucket=bucket)
-    fac = _fac_rows(m_vals, jnp.float32)
-    bsc = 0.5 if spin else 1.0
-    w = jnp.asarray(weights, jnp.float32)
-    maps_w = jnp.asarray(maps, jnp.float32) * w[:, None, None]
-
-    def fwd(res, mw):
-        x_, pmm_, pms_ = res
-        return _anal_chain(mw, m_vals, x_, pmm_, pms_, **kw)
-
-    def bwd(res, g):
-        x_, pmm_, pms_ = res
-        return _synth_chain(g / (bsc * fac), m_vals, x_, pmm_, pms_, **kw)
-
-    return linear_pair(fwd, bwd, (x, pmm, pms), maps_w)
+    tabs, kw = _bucket_kw(m_vals, l_max=l_max, layout=layout, pos=pos,
+                          neg=neg, n_phi=n_phi, phi0=phi0,
+                          out_width=int(maps.shape[1]), variant=variant,
+                          bf16=bf16, lo=lo, lp_size=lp_size,
+                          interpret=interpret, mp_vals=mp_vals, tabs=tabs,
+                          rot=rot)
+    return _linear_anal(maps, weights, m_vals, x, pmm, pms, tabs, kw)

@@ -56,9 +56,10 @@ _BIG = float(2.0 ** (SCALE_BITS_F32 // 2))        # 2^32
 _INV_BIG2 = float(2.0 ** (-SCALE_BITS_F32))       # 2^-64
 _BIG2 = float(2.0 ** SCALE_BITS_F32)              # 2^64
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+#: Contraction precision of the kernels' float32 dots.  Mosaic's default
+#: contracts float32 operands in bfloat16 passes; the transform is a
+#: float32 computation, so its dots ask for full float32.
+F32_DOT = jax.lax.Precision.HIGHEST
 
 
 def _pad_rows(blk, rf):
@@ -71,6 +72,16 @@ def _pad_rows(blk, rf):
         return blk
     pad = blk.shape[:-2] + (8 - rf, 128)
     return jnp.concatenate([blk, jnp.zeros(pad, blk.dtype)], axis=-2)
+
+
+def _ring_rows(arr):
+    """(..., R1, 128) -> (..., R1, 1, 128) for the MXU kernels, which step
+    one 128-ring row block per grid step.  Mosaic requires a block's last
+    two dims to be multiples of (8, 128) or the array's own; a (1, 128)
+    block of an (R1, 128) array is neither once R1 > 1.  With the row axis
+    moved out of the minor pair, the block is (squeezed, 1, 128) and the
+    kernels still see (1, 128) rows; XLA lays the unit dim out unpadded."""
+    return arr.reshape(arr.shape[:-1] + (1, 128))
 
 
 def _f32_step(l, m_f, x, pp, pc, sc, pmm, pms):
@@ -262,7 +273,7 @@ def synth_vpu(a, m_vals, x2d, pmm, pms, *, l_max, fold=False, mp_vals=None,
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, n_par, K2, R1, 128), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
     )(m_vals, mp, x2d, pmm, pms, a)
 
@@ -304,21 +315,24 @@ def _synth_mxu_kernel(m_vals_ref, mp_vals_ref, x_ref, pmm_ref, pms_ref,
         sc_ref[...] = sc
 
         panel = panel_ref[...]                # (LP, 128)
-        a_blk = a_ref[0]                      # (LP, 2K)
-        dims = (((0,), (0,)), ((), ()))       # contract over l
+        a_blk = a_ref[0]                      # (2K, LP)
+        dims = (((1,), (0,)), ((), ()))       # contract over l
         if fold:
-            ls = l0 + jax.lax.broadcasted_iota(jnp.int32, (lp_size, 1), 0)
+            ls = l0 + jax.lax.broadcasted_iota(jnp.int32, (1, lp_size), 1)
             even = ((ls + m) % 2) == 0
             a_e = jnp.where(even, a_blk, 0.0)
             a_o = jnp.where(even, 0.0, a_blk)
-            ce = jax.lax.dot_general(panel, a_e, dims,
+            ce = jax.lax.dot_general(a_e, panel, dims,
+                                     precision=F32_DOT,
                                      preferred_element_type=jnp.float32)
-            co = jax.lax.dot_general(panel, a_o, dims,
+            co = jax.lax.dot_general(a_o, panel, dims,
+                                     precision=F32_DOT,
                                      preferred_element_type=jnp.float32)
-            out_ref[0, 0] += ce               # (128, 2K)
+            out_ref[0, 0] += ce               # (2K, 128)
             out_ref[0, 1] += co
         else:
-            c = jax.lax.dot_general(panel, a_blk, dims,
+            c = jax.lax.dot_general(a_blk, panel, dims,
+                                    precision=F32_DOT,
                                     preferred_element_type=jnp.float32)
             out_ref[0, 0] += c
 
@@ -327,10 +341,10 @@ def synth_mxu(a, m_vals, x2d, pmm, pms, *, l_max, fold=False, mp_vals=None,
               lp_size=128, interpret=True):
     """MXU synthesis kernel (multi-map panel matmul).
 
-    Layouts as synth_vpu except rings advance 128 at a time and the output
-    is (Mp, P, R1*?, ...) -- concretely (Mp, P, R, 2K) with R = R1 * 128.
+    a: (Mp, 2K, L1p) f32 (the l axis minor: lane-dense for any K); rings
+    advance 128 at a time; returns (Mp, P, 2K, R) with R = R1 * 128.
     """
-    Mp, L1p, K2 = a.shape
+    Mp, K2, L1p = a.shape
     R1 = x2d.shape[0]
     R = R1 * 128
     assert L1p % lp_size == 0
@@ -340,9 +354,9 @@ def synth_mxu(a, m_vals, x2d, pmm, pms, *, l_max, fold=False, mp_vals=None,
         else jnp.asarray(mp_vals, jnp.int32)
     n_par = 2 if fold else 1
     grid = (Mp, R1, L1p // lp_size)
-    x_flat = x2d.reshape(R1, 128)
-    pmm_f = pmm.reshape(Mp, R1, 128)
-    pms_f = pms.reshape(Mp, R1, 128)
+    x_flat = _ring_rows(x2d.reshape(R1, 128))
+    pmm_f = _ring_rows(pmm.reshape(Mp, R1, 128))
+    pms_f = _ring_rows(pms.reshape(Mp, R1, 128))
     kernel = functools.partial(_synth_mxu_kernel, lp_size=lp_size, fold=fold,
                                spin=spin)
     return pl.pallas_call(
@@ -351,13 +365,17 @@ def synth_mxu(a, m_vals, x2d, pmm, pms, *, l_max, fold=False, mp_vals=None,
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 128), lambda m, rb, lp, *_refs: (rb, 0)),
-                pl.BlockSpec((1, 1, 128), lambda m, rb, lp, *_refs: (m, rb, 0)),
-                pl.BlockSpec((1, 1, 128), lambda m, rb, lp, *_refs: (m, rb, 0)),
-                pl.BlockSpec((1, lp_size, K2), lambda m, rb, lp, *_refs: (m, lp, 0)),
+                pl.BlockSpec((None, 1, 128),
+                             lambda m, rb, lp, *_refs: (rb, 0, 0)),
+                pl.BlockSpec((1, None, 1, 128),
+                             lambda m, rb, lp, *_refs: (m, rb, 0, 0)),
+                pl.BlockSpec((1, None, 1, 128),
+                             lambda m, rb, lp, *_refs: (m, rb, 0, 0)),
+                pl.BlockSpec((1, K2, lp_size),
+                             lambda m, rb, lp, *_refs: (m, 0, lp)),
             ],
-            out_specs=pl.BlockSpec((1, n_par, 128, K2),
-                                   lambda m, rb, lp, *_refs: (m, 0, rb, 0)),
+            out_specs=pl.BlockSpec((1, n_par, K2, 128),
+                                   lambda m, rb, lp, *_refs: (m, 0, 0, rb)),
             scratch_shapes=[
                 pltpu.VMEM((1, 128), jnp.float32),
                 pltpu.VMEM((1, 128), jnp.float32),
@@ -365,9 +383,9 @@ def synth_mxu(a, m_vals, x2d, pmm, pms, *, l_max, fold=False, mp_vals=None,
                 pltpu.VMEM((lp_size, 128), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((Mp, n_par, R, K2), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((Mp, n_par, K2, R), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
     )(m_vals, mp, x_flat, pmm_f, pms_f, a)
 
@@ -473,7 +491,7 @@ def anal_vpu(dw, m_vals, x2d, pmm, pms, *, l_max, l1p, fold=False,
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, l1p, K2), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
     )(m_vals, mp, x2d, pmm, pms, dw)
 
@@ -500,9 +518,10 @@ def anal_vpu(dw, m_vals, x2d, pmm, pms, *, l_max, l1p, fold=False,
 
 
 def _packed_row_masks(base, jsw, m0, m1, mp0, mp1, lp_size, n_par, fold):
-    """Per-panel-row (lp_size, 1) bool masks selecting each fused output
-    component q = segment * n_par + parity (the MXU kernels' row splits)."""
-    iot = jax.lax.broadcasted_iota(jnp.int32, (lp_size, 1), 0)
+    """Per-stream-position (1, lp_size) bool masks selecting each fused
+    output component q = segment * n_par + parity (the MXU kernels' l
+    splits; the l stream is the lane axis of their operands)."""
+    iot = jax.lax.broadcasted_iota(jnp.int32, (1, lp_size), 1)
     g_row = base + iot
     hi_row = g_row >= jsw
     masks = []
@@ -631,7 +650,7 @@ def synth_vpu_packed(a_pk, maps, x2d, pmm_pk, pms_pk, *, l_max, fold=False,
         out_shape=jax.ShapeDtypeStruct((n_slots, n_q, K2, R1, 128),
                                        jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*maps, x2d, pmm_pk, pms_pk, a_pk)
 
@@ -684,25 +703,27 @@ def _synth_mxu_packed_kernel(m0_ref, m1_ref, mp0_ref, mp1_ref, seed_ref,
     sc_ref[...] = sc
 
     panel = panel_ref[...]                   # (LP, 128)
-    a_blk = a_ref[0]                         # (LP, 2K)
-    dims = (((0,), (0,)), ((), ()))          # contract over the l stream
+    a_blk = a_ref[0]                         # (2K, LP)
+    dims = (((1,), (0,)), ((), ()))          # contract over the l stream
     masks = _packed_row_masks(base, jsw, m0, m1, mp0, mp1, lp_size, n_par,
                               fold)
     for q, mask in enumerate(masks):
         a_q = jnp.where(mask, a_blk, 0.0)
-        c = jax.lax.dot_general(panel, a_q, dims,
+        c = jax.lax.dot_general(a_q, panel, dims,
+                                precision=F32_DOT,
                                 preferred_element_type=jnp.float32)
-        out_ref[0, q] += c                   # (128, 2K)
+        out_ref[0, q] += c                   # (2K, 128)
 
 
 def synth_mxu_packed(a_pk, maps, x2d, pmm_pk, pms_pk, *, l_max, fold=False,
                      spin=False, lp_size=128, interpret=True):
     """MXU synthesis on the packed grid (multi-map panel matmul).
 
-    Layouts as :func:`synth_vpu_packed` except rings advance 128 at a
-    time; returns (n_slots, Q, R, 2K) with R = R1 * 128.
+    a_pk: (n_slots, 2K, S) (the stream axis minor: lane-dense for any K);
+    rings advance 128 at a time; returns (n_slots, Q, 2K, R) with
+    R = R1 * 128.
     """
-    n_slots, S, K2 = a_pk.shape
+    n_slots, K2, S = a_pk.shape
     R1 = x2d.shape[0]
     R = R1 * 128
     assert S % lp_size == 0
@@ -718,16 +739,17 @@ def synth_mxu_packed(a_pk, maps, x2d, pmm_pk, pms_pk, *, l_max, fold=False,
             num_scalar_prefetch=5,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 128), lambda s, rb, sp, *_refs: (rb, 0)),
-                pl.BlockSpec((1, 2, 1, 128),
-                             lambda s, rb, sp, *_refs: (s, 0, rb, 0)),
-                pl.BlockSpec((1, 2, 1, 128),
-                             lambda s, rb, sp, *_refs: (s, 0, rb, 0)),
-                pl.BlockSpec((1, lp_size, K2),
-                             lambda s, rb, sp, *_refs: (s, sp, 0)),
+                pl.BlockSpec((None, 1, 128),
+                             lambda s, rb, sp, *_refs: (rb, 0, 0)),
+                pl.BlockSpec((1, 2, None, 1, 128),
+                             lambda s, rb, sp, *_refs: (s, 0, rb, 0, 0)),
+                pl.BlockSpec((1, 2, None, 1, 128),
+                             lambda s, rb, sp, *_refs: (s, 0, rb, 0, 0)),
+                pl.BlockSpec((1, K2, lp_size),
+                             lambda s, rb, sp, *_refs: (s, 0, sp)),
             ],
-            out_specs=pl.BlockSpec((1, n_q, 128, K2),
-                                   lambda s, rb, sp, *_refs: (s, 0, rb, 0)),
+            out_specs=pl.BlockSpec((1, n_q, K2, 128),
+                                   lambda s, rb, sp, *_refs: (s, 0, 0, rb)),
             scratch_shapes=[
                 pltpu.VMEM((1, 128), jnp.float32),
                 pltpu.VMEM((1, 128), jnp.float32),
@@ -735,12 +757,13 @@ def synth_mxu_packed(a_pk, maps, x2d, pmm_pk, pms_pk, *, l_max, fold=False,
                 pltpu.VMEM((lp_size, 128), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_slots, n_q, R, K2), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_slots, n_q, K2, R), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(*maps, x2d, pmm_pk.reshape(n_slots, 2, R1, 128),
-      pms_pk.reshape(n_slots, 2, R1, 128), a_pk)
+    )(*maps, _ring_rows(x2d),
+      _ring_rows(pmm_pk.reshape(n_slots, 2, R1, 128)),
+      _ring_rows(pms_pk.reshape(n_slots, 2, R1, 128)), a_pk)
 
 
 def _anal_vpu_packed_kernel(m0_ref, m1_ref, mp0_ref, mp1_ref, seed_ref,
@@ -866,7 +889,7 @@ def anal_vpu_packed(dw_pk, maps, x2d, pmm_pk, pms_pk, *, l_max, s_len,
         ),
         out_shape=jax.ShapeDtypeStruct((n_slots, S, K2), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
     )(*maps, x2d, pmm_pk, pms_pk, dw_pk)
 
@@ -889,7 +912,10 @@ def _anal_mxu_packed_kernel(m0_ref, m1_ref, mp0_ref, mp1_ref, seed_ref,
         pc_ref[...] = jnp.zeros_like(pc_ref)
         sc_ref[...] = jnp.zeros_like(sc_ref)
 
-    @pl.when(rb == 0)
+    # the slot's whole output stays resident across its (ring block, panel)
+    # steps: every ring block adds into every panel's rows, and an output
+    # block is only kept in VMEM between consecutive grid steps
+    @pl.when((rb == 0) & (sp == 0))
     def _init_out():
         out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -923,26 +949,28 @@ def _anal_mxu_packed_kernel(m0_ref, m1_ref, mp0_ref, mp1_ref, seed_ref,
     sc_ref[...] = sc
 
     panel = panel_ref[...]                   # (LP, 128)
-    dims = (((1,), (0,)), ((), ()))          # contract over rings(128)
+    dims = (((1,), (1,)), ((), ()))          # contract over rings(128)
     masks = _packed_row_masks(base, jsw, m0, m1, mp0, mp1, lp_size, n_par,
                               fold)
-    acc = jnp.zeros_like(out_ref[0])
+    acc = jnp.zeros(out_ref.shape[2:], jnp.float32)
     for q, mask in enumerate(masks):
-        c = jax.lax.dot_general(panel, dw_ref[0, q], dims,
+        c = jax.lax.dot_general(dw_ref[0, q], panel, dims,
+                                precision=F32_DOT,
                                 preferred_element_type=jnp.float32)
-        acc = acc + jnp.where(mask, c, 0.0)  # (LP, 2K)
-    out_ref[0] += acc
+        acc = acc + jnp.where(mask, c, 0.0)  # (2K, LP)
+    out_ref[0, sp] += acc
 
 
 def anal_mxu_packed(dw_pk, maps, x2d, pmm_pk, pms_pk, *, l_max, s_len,
                     fold=False, spin=False, lp_size=128, interpret=True):
     """MXU analysis on the packed grid.
 
-    dw_pk  : (n_slots, Q, R, 2K) weighted Delta (ring-major), R = R1 * 128
+    dw_pk  : (n_slots, Q, 2K, R) weighted Delta, R = R1 * 128
     s_len  : packed l-stream length per slot (layout.S)
-    returns: (n_slots, S, 2K) f32 packed l-stream rows
+    returns: (n_slots, S // LP, 2K, LP) f32 packed l-stream rows, one
+             lane-dense (2K, LP) block per panel
     """
-    n_slots, n_q, R, K2 = dw_pk.shape
+    n_slots, n_q, K2, R = dw_pk.shape
     R1 = R // 128
     n_par = 2 if fold else 1
     assert n_q == 2 * n_par and R % 128 == 0
@@ -958,16 +986,17 @@ def anal_mxu_packed(dw_pk, maps, x2d, pmm_pk, pms_pk, *, l_max, s_len,
             num_scalar_prefetch=5,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 128), lambda s, rb, sp, *_refs: (rb, 0)),
-                pl.BlockSpec((1, 2, 1, 128),
-                             lambda s, rb, sp, *_refs: (s, 0, rb, 0)),
-                pl.BlockSpec((1, 2, 1, 128),
-                             lambda s, rb, sp, *_refs: (s, 0, rb, 0)),
-                pl.BlockSpec((1, n_q, 128, K2),
-                             lambda s, rb, sp, *_refs: (s, 0, rb, 0)),
+                pl.BlockSpec((None, 1, 128),
+                             lambda s, rb, sp, *_refs: (rb, 0, 0)),
+                pl.BlockSpec((1, 2, None, 1, 128),
+                             lambda s, rb, sp, *_refs: (s, 0, rb, 0, 0)),
+                pl.BlockSpec((1, 2, None, 1, 128),
+                             lambda s, rb, sp, *_refs: (s, 0, rb, 0, 0)),
+                pl.BlockSpec((1, n_q, K2, 128),
+                             lambda s, rb, sp, *_refs: (s, 0, 0, rb)),
             ],
-            out_specs=pl.BlockSpec((1, lp_size, K2),
-                                   lambda s, rb, sp, *_refs: (s, sp, 0)),
+            out_specs=pl.BlockSpec((1, S // lp_size, K2, lp_size),
+                                   lambda s, rb, sp, *_refs: (s, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((1, 128), jnp.float32),
                 pltpu.VMEM((1, 128), jnp.float32),
@@ -975,12 +1004,14 @@ def anal_mxu_packed(dw_pk, maps, x2d, pmm_pk, pms_pk, *, l_max, s_len,
                 pltpu.VMEM((lp_size, 128), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_slots, S, K2), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_slots, S // lp_size, K2, lp_size),
+                                       jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-    )(*maps, x2d, pmm_pk.reshape(n_slots, 2, R1, 128),
-      pms_pk.reshape(n_slots, 2, R1, 128), dw_pk)
+    )(*maps, _ring_rows(x2d),
+      _ring_rows(pmm_pk.reshape(n_slots, 2, R1, 128)),
+      _ring_rows(pms_pk.reshape(n_slots, 2, R1, 128)), dw_pk)
 
 
 def _anal_mxu_kernel(m_vals_ref, mp_vals_ref, x_ref, pmm_ref, pms_ref,
@@ -1000,7 +1031,9 @@ def _anal_mxu_kernel(m_vals_ref, mp_vals_ref, x_ref, pmm_ref, pms_ref,
         pc_ref[...] = jnp.zeros_like(pc_ref)
         sc_ref[...] = jnp.zeros_like(sc_ref)
 
-    @pl.when(rb == 0)
+    # the row's whole output stays resident across its (ring block, panel)
+    # steps (see the packed analysis kernel)
+    @pl.when((rb == 0) & (lp == 0))
     def _init_out():
         out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -1024,29 +1057,32 @@ def _anal_mxu_kernel(m_vals_ref, mp_vals_ref, x_ref, pmm_ref, pms_ref,
         sc_ref[...] = sc
 
         panel = panel_ref[...]                  # (LP, 128)
-        dims = (((1,), (0,)), ((), ()))         # contract over rings(128)
+        dims = (((1,), (1,)), ((), ()))         # contract over rings(128)
         if fold:
-            ls = l0 + jax.lax.broadcasted_iota(jnp.int32, (lp_size, 1), 0)
+            ls = l0 + jax.lax.broadcasted_iota(jnp.int32, (1, lp_size), 1)
             even = ((ls + m) % 2) == 0
-            ce = jax.lax.dot_general(panel, dw_ref[0, 0], dims,
+            ce = jax.lax.dot_general(dw_ref[0, 0], panel, dims,
+                                     precision=F32_DOT,
                                      preferred_element_type=jnp.float32)
-            co = jax.lax.dot_general(panel, dw_ref[0, 1], dims,
+            co = jax.lax.dot_general(dw_ref[0, 1], panel, dims,
+                                     precision=F32_DOT,
                                      preferred_element_type=jnp.float32)
-            out_ref[0] += jnp.where(even, ce, co)
+            out_ref[0, lp] += jnp.where(even, ce, co)     # (2K, LP)
         else:
-            c = jax.lax.dot_general(panel, dw_ref[0, 0], dims,
+            c = jax.lax.dot_general(dw_ref[0, 0], panel, dims,
+                                    precision=F32_DOT,
                                     preferred_element_type=jnp.float32)
-            out_ref[0] += c
+            out_ref[0, lp] += c
 
 
 def anal_mxu(dw, m_vals, x2d, pmm, pms, *, l_max, l1p, fold=False,
              mp_vals=None, lp_size=128, interpret=True):
     """MXU analysis kernel.
 
-    dw     : (Mp, P, R, 2K) weighted Delta (ring-major), R = R1 * 128
-    returns: (Mp, L1p, 2K) f32
+    dw     : (Mp, P, 2K, R) weighted Delta, R = R1 * 128
+    returns: (Mp, L1p // LP, 2K, LP) f32, one lane-dense block per panel
     """
-    Mp, n_par, R, K2 = dw.shape
+    Mp, n_par, K2, R = dw.shape
     R1 = R // 128
     assert l1p % lp_size == 0 and R % 128 == 0
     spin = mp_vals is not None
@@ -1062,14 +1098,17 @@ def anal_mxu(dw, m_vals, x2d, pmm, pms, *, l_max, l1p, fold=False,
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 128), lambda m, rb, lp, *_refs: (rb, 0)),
-                pl.BlockSpec((1, 1, 128), lambda m, rb, lp, *_refs: (m, rb, 0)),
-                pl.BlockSpec((1, 1, 128), lambda m, rb, lp, *_refs: (m, rb, 0)),
-                pl.BlockSpec((1, n_par, 128, K2),
-                             lambda m, rb, lp, *_refs: (m, 0, rb, 0)),
+                pl.BlockSpec((None, 1, 128),
+                             lambda m, rb, lp, *_refs: (rb, 0, 0)),
+                pl.BlockSpec((1, None, 1, 128),
+                             lambda m, rb, lp, *_refs: (m, rb, 0, 0)),
+                pl.BlockSpec((1, None, 1, 128),
+                             lambda m, rb, lp, *_refs: (m, rb, 0, 0)),
+                pl.BlockSpec((1, n_par, K2, 128),
+                             lambda m, rb, lp, *_refs: (m, 0, 0, rb)),
             ],
-            out_specs=pl.BlockSpec((1, lp_size, K2),
-                                   lambda m, rb, lp, *_refs: (m, lp, 0)),
+            out_specs=pl.BlockSpec((1, l1p // lp_size, K2, lp_size),
+                                   lambda m, rb, lp, *_refs: (m, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((1, 128), jnp.float32),
                 pltpu.VMEM((1, 128), jnp.float32),
@@ -1077,8 +1116,10 @@ def anal_mxu(dw, m_vals, x2d, pmm, pms, *, l_max, l1p, fold=False,
                 pltpu.VMEM((lp_size, 128), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((Mp, l1p, K2), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((Mp, l1p // lp_size, K2, lp_size),
+                                       jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-    )(m_vals, mp, x2d, pmm, pms, dw)
+    )(m_vals, mp, _ring_rows(x2d), _ring_rows(pmm.reshape(Mp, R1, 128)),
+      _ring_rows(pms.reshape(Mp, R1, 128)), dw)

@@ -6,8 +6,8 @@ Responsibilities:
   * seed precomputation (float64 -> scaled f32 mantissas);
   * variant selection (VPU broadcast-FMA for few maps, MXU panel matmul for
     many) with env/arg overrides;
-  * `interpret=True` execution on CPU (this container) vs. compiled Mosaic
-    on real TPU backends.
+  * `interpret=True` execution on a CPU backend vs. compiled Mosaic on a
+    TPU backend (never interpret mode there).
 
 These wrappers are the integration point used by core.dist_sht's
 ``stage1="pallas"`` mode and by the benchmarks.
@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import legendre
 from repro.core.autodiff import linear_pair
 from repro.kernels import legendre_pallas as lk
 from repro.kernels import pack as kpack
@@ -35,10 +36,19 @@ __all__ = ["synth", "anal", "delta_from_alm_auto", "alm_from_delta_auto",
 
 def should_interpret() -> bool:
     """Pallas interpret mode unless running on a real TPU backend."""
-    forced = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if forced is not None:
-        return forced not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
+
+
+#: Why the staged VPU kernels (plain and packed grids, both directions)
+#: are ineligible on a TPU: Mosaic refuses each of them (v5e, jax 0.9).
+#: The two analysis kernels abort the compiler -- and the process with it
+#: -- so they must never reach compilation, not even inside a measurement.
+STAGED_VPU_TPU_ERROR = (
+    "the staged VPU kernels do not compile for TPU: synth packed -- "
+    "'scatter-add' has no Pallas TPU lowering; synth plain -- Mosaic "
+    "infer-vector-layout 'unsupported shape cast'; anal plain and packed "
+    "-- Mosaic 'layout.h:320 Check failed: arr.size() >= "
+    "layout_rank(implicit_dim)' aborts the compiler")
 
 
 #: canonical problem size for the vpu/mxu autotune measurement
@@ -49,10 +59,9 @@ def _measure_variant(K2: int, var: str) -> float:
     """One warm-up + one timed synth call of ``var`` at the canonical size."""
     import time
     from repro.core import grids as _grids
-    from repro.core import legendre as _legendre
     l_max = _AUTOTUNE_LMAX
     g = _grids.make_grid("gl", l_max=l_max)
-    lm = _legendre.log_mu(l_max)
+    lm = legendre.log_mu(l_max)
     m_vals = np.arange(l_max + 1)
     pmm, pms = kref.prepare_seeds(m_vals, g.sin_theta, lm)
     a = jnp.ones((l_max + 1, l_max + 1, K2), jnp.float32)
@@ -68,22 +77,16 @@ def _measure_variant(K2: int, var: str) -> float:
 
 
 def _autotune_variant(K2: int):
-    """Measured vpu-vs-mxu decision, cached by (K2, interpret) signature."""
+    """Measured vpu-vs-mxu decision, cached by (K2, interpret) signature.
+    A measurement that fails raises and caches nothing."""
     from repro.core import cache as plancache
     kind = "disk" if os.environ.get("REPRO_CACHE_DIR") else "memory"
     key = plancache.signature_key("legendre_variant", K2=int(K2),
                                   interpret=should_interpret())
     dec = plancache.load_decision(key, cache=kind)
-    if dec is not None:
-        v = dec.get("variant")
-        return v if v in ("vpu", "mxu") else None   # cached failure: static
-    try:
-        meas = {v: _measure_variant(K2, v) for v in ("vpu", "mxu")}
-    except Exception as e:                 # measurement unavailable: cache
-        plancache.save_decision(           # the failure, fall back static
-            key, {"variant": "static-fallback",
-                  "error": f"{type(e).__name__}: {e}"}, cache=kind)
-        return None
+    if dec is not None and dec.get("variant") in ("vpu", "mxu"):
+        return dec["variant"]
+    meas = {v: _measure_variant(K2, v) for v in ("vpu", "mxu")}
     best = min(meas, key=meas.get)
     plancache.save_decision(key, {"variant": best, "measured": meas},
                             cache=kind)
@@ -91,14 +94,20 @@ def _autotune_variant(K2: int):
 
 
 def pick_variant(K2: int, variant: str | None = None) -> str:
-    """vpu-vs-mxu selection: explicit arg > $REPRO_LEGENDRE_VARIANT >
-    cached autotune measurement (when $REPRO_LEGENDRE_AUTOTUNE is set) >
-    the static ``K2 >= 16`` rule."""
+    """vpu-vs-mxu selection for the staged kernels: explicit arg >
+    $REPRO_LEGENDRE_VARIANT > cached autotune measurement (when
+    $REPRO_LEGENDRE_AUTOTUNE is set) > the static ``K2 >= 16`` rule.
+    On a TPU only ``mxu`` compiles (:data:`STAGED_VPU_TPU_ERROR`): the
+    rule picks it, and an explicit ``vpu`` raises."""
+    if variant not in ("vpu", "mxu"):
+        variant = os.environ.get("REPRO_LEGENDRE_VARIANT")
+    if jax.default_backend() == "tpu":
+        if variant == "vpu":
+            raise ValueError(f"staged variant 'vpu' requested on a TPU: "
+                             f"{STAGED_VPU_TPU_ERROR}")
+        return "mxu"
     if variant in ("vpu", "mxu"):
         return variant
-    env = os.environ.get("REPRO_LEGENDRE_VARIANT")
-    if env in ("vpu", "mxu"):
-        return env
     if os.environ.get("REPRO_LEGENDRE_AUTOTUNE", "0") \
             not in ("", "0", "false", "False"):
         tuned = _autotune_variant(K2)
@@ -109,14 +118,7 @@ def pick_variant(K2: int, variant: str | None = None) -> str:
 
 def _concrete_rows(v):
     """Static numpy view of a row array, or None when traced."""
-    if v is None or isinstance(v, jax.core.Tracer):
-        return None
-    if isinstance(v, np.ndarray):
-        return v
-    try:
-        return np.asarray(v)
-    except Exception:
-        return None
+    return None if v is None else legendre._concrete(v)
 
 
 #: one-time traced-row degradation warning (see pick_layout); benches that
@@ -245,9 +247,10 @@ def _synth_packed(a, lo, x, pmm, pms, *, l_max, fold, var, spin, lp_size,
         out = jnp.moveaxis(out, 2, -1)       # (n_slots, Q, R1, 128, 2K)
         out = out.reshape(lo.n_slots, 2 * n_par, Rp, K2)
     else:
-        out = lk.synth_mxu_packed(a_pk, maps, x2d, pmm2, pms2, l_max=l_max,
-                                  fold=fold, spin=spin, lp_size=lp_size,
-                                  interpret=interpret)
+        out = lk.synth_mxu_packed(jnp.swapaxes(a_pk, 1, 2), maps, x2d, pmm2,
+                                  pms2, l_max=l_max, fold=fold, spin=spin,
+                                  lp_size=lp_size, interpret=interpret)
+        out = jnp.moveaxis(out, 2, -1)       # (n_slots, Q, R, 2K)
     seg = out.reshape(lo.n_slots * 2, n_par, Rp, K2)
     return _unpack_rows(seg, lo, Mp)[:, :, :R, :]
 
@@ -285,9 +288,11 @@ def _anal_packed(dw, lo, x, pmm, pms, *, l_max, fold, var, spin, lp_size,
     else:
         dw_p = jnp.pad(dw, ((0, 0), (0, 0), (0, Rp - R), (0, 0)))
         dw_pk = _pack_rows(dw_p, lo).reshape(lo.n_slots, 2 * n_par, Rp, K2)
-        out = lk.anal_mxu_packed(dw_pk, maps, x2d, pmm2, pms2, l_max=l_max,
-                                 s_len=lo.S, fold=fold, spin=spin,
-                                 lp_size=lp_size, interpret=interpret)
+        out = lk.anal_mxu_packed(jnp.moveaxis(dw_pk, -1, 2), maps, x2d, pmm2,
+                                 pms2, l_max=l_max, s_len=lo.S, fold=fold,
+                                 spin=spin, lp_size=lp_size,
+                                 interpret=interpret)
+        out = jnp.moveaxis(out, 2, -1).reshape(lo.n_slots, lo.S, K2)
     return _unpack_alm(out, lo)
 
 
@@ -328,9 +333,11 @@ def _synth_exec(a, m_vals, x, pmm, pms, mp_vals, *, l_max, fold, var, lo,
         out = jnp.moveaxis(out, 2, -1)            # (Mp, P, R1, 128, 2K)
         out = out.reshape(Mp, n_par, Rp, K2)
     else:
-        out = lk.synth_mxu(a_p, jnp.asarray(m_vals, jnp.int32), x2d, pmm2,
-                           pms2, l_max=l_max, fold=fold, mp_vals=mp_vals,
+        out = lk.synth_mxu(jnp.swapaxes(a_p, 1, 2),
+                           jnp.asarray(m_vals, jnp.int32), x2d, pmm2, pms2,
+                           l_max=l_max, fold=fold, mp_vals=mp_vals,
                            lp_size=lp_size, interpret=interpret)
+        out = jnp.moveaxis(out, 2, -1)            # (Mp, P, R, 2K)
     return out[:, :, :R, :]
 
 
@@ -360,9 +367,10 @@ def _anal_exec(dw, m_vals, x, pmm, pms, mp_vals, *, l_max, l1p, fold, var,
                           fold=fold, mp_vals=mp_vals, lp_size=lp_size,
                           interpret=interpret)
     else:
-        out = lk.anal_mxu(dw_p, mv, x2d, pmm2, pms2, l_max=l_max, l1p=L1p,
-                          fold=fold, mp_vals=mp_vals, lp_size=lp_size,
-                          interpret=interpret)
+        out = lk.anal_mxu(jnp.moveaxis(dw_p, -1, 2), mv, x2d, pmm2, pms2,
+                          l_max=l_max, l1p=L1p, fold=fold, mp_vals=mp_vals,
+                          lp_size=lp_size, interpret=interpret)
+        out = jnp.moveaxis(out, 2, -1).reshape(Mp, L1p, K2)
     return out[:, :L1, :]
 
 
@@ -509,7 +517,6 @@ def alm_from_delta_auto(dw_re, dw_im, m_vals, geom, log_mu_all, *, l_max,
 
 def spin_rows(m_vals):
     """Stack the m rows for the two spin recurrences: (m2, mp2), (2M,)."""
-    from repro.core import legendre
     return legendre._spin_rows(m_vals)
 
 
@@ -522,7 +529,6 @@ def delta_from_alm_spin_auto(e_re, e_im, b_re, b_im, m_vals, geom, *, l_max,
     ``cos_theta``/``sin_theta``).  Returns (dq_re, dq_im, du_re, du_im),
     each (M, R, K) in the geometry's ring order.  Kernel math is float32.
     """
-    from repro.core import legendre
     from repro.kernels import ref as kref_
     M, L1, K = e_re.shape
     x = geom["cos_theta"]
@@ -548,7 +554,6 @@ def alm_from_delta_spin_auto(dq_re, dq_im, du_re, du_im, m_vals, geom, *,
     dq/du re/im: (M, R, K) weighted Delta_Q/Delta_U.  Returns
     (e_re, e_im, b_re, b_im), each (M, L1, K).
     """
-    from repro.core import legendre
     from repro.kernels import ref as kref_
     M, R, K = dq_re.shape
     x = geom["cos_theta"]

@@ -22,21 +22,15 @@ __all__ = ["synth_ref", "anal_ref", "synth_packed_ref", "anal_packed_ref",
 
 
 def prepare_seeds(m_vals, sin_theta, log_mu_all, scale_bits: int = 64):
-    """Scaled P_mm seeds for the f32 kernels, computed in float64.
+    """Scaled P_mm seeds for the f32 kernels, computed on the host in
+    float64 (`legendre.pmm_seed_rows`).
 
-    m_vals: (Mp,) int (may include -1 padding -> inert seeds of 0);
-    sin_theta: (R,) f64.  Returns (pmm (Mp, R) f32, pms (Mp, R) i32).
+    m_vals: (Mp,) int, concrete or traced (may include -1 padding -> inert
+    seeds of 0); sin_theta: (R,) f64.  Returns (pmm (Mp, R) f32,
+    pms (Mp, R) i32).
     """
-    m_vals = jnp.asarray(m_vals)
-    msafe = jnp.maximum(m_vals, 0)
-    lm = jnp.asarray(log_mu_all, jnp.float64)[msafe][:, None]
-    st = jnp.asarray(sin_theta, jnp.float64)[None, :]
-    log_p = lm + msafe.astype(jnp.float64)[:, None] * jnp.log(st)
-    denom = scale_bits * np.log(2.0)
-    scale = jnp.minimum(jnp.round(log_p / denom), 0.0)
-    mant = jnp.exp(log_p - scale * denom)
-    mant = jnp.where((m_vals >= 0)[:, None], mant, 0.0)
-    return mant.astype(jnp.float32), scale.astype(jnp.int32)
+    return _legendre.pmm_seed_rows(m_vals, sin_theta, log_mu_all,
+                                   dtype=np.float32, scale_bits=scale_bits)
 
 
 def prepare_seeds_spin(m_vals, mprime_vals, cos_theta, sin_theta,
@@ -47,13 +41,9 @@ def prepare_seeds_spin(m_vals, mprime_vals, cos_theta, sin_theta,
     cos_theta/sin_theta: (R,) f64.  ``m_max`` must be given when ``m_vals``
     is traced (the distributed path).  Returns (pmm f32, pms i32), (Ms, R).
     """
-    if m_max is None:
-        m_max = int(np.max(np.asarray(m_vals)))
-    logfact = _legendre.log_factorials(2 * max(int(m_max), 2) + 1)
-    mant, scale = _legendre.spin_seeds_scaled(
-        m_vals, mprime_vals, cos_theta, sin_theta, logfact,
-        dtype=jnp.float32, scale_bits=scale_bits)
-    return mant, scale
+    return _legendre.spin_seed_rows(m_vals, mprime_vals, cos_theta,
+                                    sin_theta, m_max=m_max,
+                                    dtype=np.float32, scale_bits=scale_bits)
 
 
 def _ref_step(spin, l, m_f, mp_f, xb, pp, pc, sc, pmm, pms):
@@ -129,7 +119,8 @@ def anal_ref(dw, m_vals, x, pmm, pms, *, l_max: int, l1p: int,
             d = jnp.where(par == 0, dw[:, 0], dw[:, 1])
         else:
             d = dw[:, 0]
-        row = jnp.einsum("mr,mrk->mk", val, d)
+        row = jnp.einsum("mr,mrk->mk", val, d,
+                         precision=jax.lax.Precision.HIGHEST)
         return (pp, pc, sc), row
 
     _, rows = jax.lax.scan(step, carry0, jnp.arange(l1p))
@@ -230,7 +221,8 @@ def anal_packed_ref(dw_pk, layout, x, pmm_pk, pms_pk, *, fold: bool = False):
         val = jnp.where(l <= layout.l_max, val, 0.0)
         q = hi * n_par + ((l + m) % 2 if fold else 0)  # (n_slots, 1)
         d = jnp.take_along_axis(dw_pk, q[:, :, None, None], axis=1)[:, 0]
-        row = jnp.einsum("sr,srk->sk", val, d)
+        row = jnp.einsum("sr,srk->sk", val, d,
+                         precision=jax.lax.Precision.HIGHEST)
         return (pp, pc, sc), row
 
     _, rows = jax.lax.scan(step, carry0, jnp.arange(layout.S))

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import repro  # noqa: F401  (enables x64; models pass explicit dtypes)
+import repro  # noqa: F401
 from repro.configs import registry
 from repro.configs.base import SHAPES
 from repro.launch.mesh import make_production_mesh, sht_axis_names

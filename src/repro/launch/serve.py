@@ -15,7 +15,7 @@ import argparse
 
 import numpy as np
 
-import repro  # noqa: F401
+from repro import compile_cache
 from repro.core import sht
 from repro.serve import ShtEngine
 
@@ -35,6 +35,7 @@ def main():
     a = ap.parse_args()
     if a.smoke:
         a.lmax = min(a.lmax, 16)
+    compile_cache.enable()
 
     target_s = None if a.p99_target_ms is None else a.p99_target_ms * 1e-3
     eng = ShtEngine(max_k=a.max_k, mode=a.mode, warm_after=2,

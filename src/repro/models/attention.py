@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.models import layers as L
 
 __all__ = ["init_attention", "spec_attention", "attention_train",
@@ -131,8 +130,8 @@ def ulysses_attention(q, k, v, qpos, kpos, mesh, axis="model", *, window=None,
                                   tiled=True)
 
     spec = P(None, axis, None, None)
-    return compat.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                            out_specs=spec)(q, k, v)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 # =============================================================================

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.models import layers as L
 
 __all__ = ["init_moe", "spec_moe", "moe_apply"]
@@ -121,7 +120,7 @@ def moe_apply(p, x_loc, cfg, axis_name="model", *, cdt=jnp.bfloat16):
     """
     T, d = x_loc.shape
     E, k = cfg.n_experts, cfg.top_k
-    S = compat.axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     e_per_shard = E // S
     cap = int(np.ceil(T * k / S * cfg.capacity_factor))
 
@@ -173,7 +172,7 @@ def moe_apply_replicated(p_loc, x_loc, cfg, axis_name="model", *,
     """
     T, d = x_loc.shape
     E, k = cfg.n_experts, cfg.top_k
-    S = compat.axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     e_loc = E // S
     off = jax.lax.axis_index(axis_name) * e_loc
 

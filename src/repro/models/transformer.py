@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import moe as M
@@ -201,9 +200,9 @@ def _moe_block(p, x, rt: Runtime):
     if rt.mesh is not None:
         x = jax.lax.with_sharding_constraint(
             x, jax.NamedSharding(rt.mesh, in_spec))
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         body, mesh=rt.mesh, in_specs=(pspec_routed, in_spec),
-        out_specs=(in_spec, P()))(p_routed, x)
+        out_specs=(in_spec, P()), check_vma=False)(p_routed, x)
     if rt.mesh is not None:
         # ...and bring the output BACK to batch-only sharding: letting the
         # seq-sharding leak into the next layer's attention makes GSPMD

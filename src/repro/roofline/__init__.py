@@ -4,7 +4,8 @@
 # serving engine's latency-target admission control.
 from repro.roofline import admission, chardb  # noqa: F401
 from repro.roofline.analysis import (  # noqa: F401
-    BACKEND_MODELS, BackendModel, HW_HOST, HW_V5E, Hardware, Roofline,
-    analyze_compiled, collective_bytes, parse_hlo_collectives,
+    BACKEND_MODELS, BackendModel, HW_HOST, HW_V5E, PEAKS, Hardware,
+    Roofline, analyze_compiled, collective_bytes, hardware_for,
+    parse_hlo_collectives,
     predict_sht_time, sht_work,
 )

@@ -31,19 +31,17 @@ from __future__ import annotations
 
 from typing import Optional
 
-from repro.roofline.analysis import (HW_HOST, HW_V5E, Hardware,
-                                     predict_sht_time)
+from repro.roofline.analysis import Hardware, hardware_for, predict_sht_time
 
 __all__ = ["default_model", "k_caps_for_target"]
 
 
 def default_model() -> tuple:
     """(backend, Hardware) the admission model should price against on
-    this host: the f64 jnp oracle on CPU, the MXU pipeline on devices."""
-    import jax
-    if jax.default_backend() == "cpu":
-        return "jnp", HW_HOST
-    return "pallas_mxu", HW_V5E
+    this host: the f64 jnp oracle on CPU, the MXU pipeline on devices
+    (peaks from `analysis.PEAKS`; an unlisted device raises)."""
+    hw = hardware_for()
+    return ("jnp" if hw.name == "host-cpu" else "pallas_mxu"), hw
 
 
 def k_caps_for_target(*, l_max: int, n_rings: int, n_phi: int, max_k: int,

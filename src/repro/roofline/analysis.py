@@ -23,8 +23,8 @@ from typing import Optional
 
 import numpy as np
 
-__all__ = ["HW_V5E", "HW_HOST", "Roofline", "collective_bytes",
-           "analyze_compiled", "parse_hlo_collectives",
+__all__ = ["HW_V5E", "HW_HOST", "PEAKS", "hardware_for", "Roofline",
+           "collective_bytes", "analyze_compiled", "parse_hlo_collectives",
            "sht_work", "legendre_panel_counts", "predict_sht_time",
            "predict_comm_chunks", "BACKEND_MODELS", "BackendModel"]
 
@@ -46,6 +46,30 @@ HW_V5E = Hardware("tpu-v5e", 197e12, 819e9, 50e9)
 #: host "collectives" are memcpys behind a dispatch, so the per-collective
 #: launch latency is an order worse than real ICI.
 HW_HOST = Hardware("host-cpu", 2e11, 5e10, 1e10, coll_latency=1e-5)
+
+#: Per-chip peaks keyed by JAX's ``device_kind``.  Source: Google Cloud
+#: documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+#: 1,600 Gbit/s chip-to-chip interconnect = 4 links x 50 GB/s).
+PEAKS = {"TPU v5 lite": HW_V5E}
+
+
+def hardware_for(device=None) -> Hardware:
+    """The cost-model peaks of ``device`` (default: the first JAX device).
+
+    A CPU backend gets the crude host model; an accelerator must be listed
+    in :data:`PEAKS` -- an unknown device kind raises instead of being
+    priced as some other chip."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return HW_HOST
+    try:
+        return PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks for device kind {device.device_kind!r} "
+            f"({device.platform}); known: {sorted(PEAKS)}") from None
 
 
 # ---------------------------------------------------------------------------

@@ -45,8 +45,15 @@ _DBS: dict[str, "CharDB"] = {}
 
 
 def smoke_mode() -> bool:
-    """True when CI asked for bounded runtime: never measure, only reuse."""
-    return os.environ.get(_SMOKE_ENV, "") not in ("", "0")
+    """True when CI asked for bounded runtime: never measure, only reuse.
+    Refused on a TPU, where it would swap measurement for the model."""
+    if os.environ.get(_SMOKE_ENV, "") in ("", "0"):
+        return False
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(f"${_SMOKE_ENV} replaces measurement with the "
+                           "cost model; it is for CPU CI runs, not a TPU")
+    return True
 
 
 def hardware_fingerprint(*, interpret: Optional[bool] = None) -> tuple:
@@ -60,7 +67,7 @@ def hardware_fingerprint(*, interpret: Optional[bool] = None) -> tuple:
         interpret = should_interpret()
     desc = "|".join([
         jax.default_backend(),
-        getattr(dev, "device_kind", "?"),
+        dev.device_kind,
         str(jax.device_count()),
         jax.__version__,
         f"interpret={int(bool(interpret))}",

@@ -36,7 +36,12 @@ class PlanSig:
     nside: Optional[int] = None
     m_max: Optional[int] = None
     spin: int = 0
-    dtype: str = "float64"
+    dtype: Optional[str] = None     # None: transform.default_dtype()
+
+    def __post_init__(self):
+        if self.dtype is None:
+            from repro.core import transform
+            object.__setattr__(self, "dtype", transform.default_dtype())
 
     def label(self) -> str:
         geo = f"nside{self.nside}" if self.nside else f"lmax{self.l_max}"

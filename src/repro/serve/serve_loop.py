@@ -74,6 +74,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.core import transform
 from repro.serve.metrics import Calibration, LatencyWindow
 from repro.serve.pool import PlanPool, PlanSig
 
@@ -167,10 +168,14 @@ class ShtRequest:
     nside: Optional[int] = None
     m_max: Optional[int] = None
     spin: int = 0
-    dtype: str = "float64"
+    dtype: Optional[str] = None       # None: transform.default_dtype()
     iters: int = 0                    # map2alm Jacobi refinement passes
     timeout: Optional[float] = None   # seconds in queue before eviction
     tag: Optional[str] = None         # caller-side label (not interpreted)
+
+    def __post_init__(self):
+        if self.dtype is None:
+            self.dtype = transform.default_dtype()
 
     def signature(self) -> PlanSig:
         return PlanSig(grid=self.grid, l_max=self.l_max, nside=self.nside,
@@ -256,8 +261,7 @@ def _normalize_payload(req: ShtRequest) -> tuple[np.ndarray, int, bool]:
         raise ValueError(f"unknown direction {req.direction!r}")
     if req.spin not in (0, 2):
         raise ValueError(f"unsupported spin {req.spin!r}")
-    if req.dtype not in ("float64", "float32"):
-        raise ValueError(f"unsupported dtype {req.dtype!r}")
+    transform.check_dtype(req.dtype)  # float64 is refused on a TPU
     if not isinstance(req.grid, str):
         raise ValueError("serving requests take string grid specs "
                          f"(got {type(req.grid).__name__})")

@@ -14,12 +14,15 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import sys
 import numpy as np, jax, jax.numpy as jnp
 import repro  # noqa
+
+jax.config.update("jax_enable_x64", True)   # float64 reference engine
 from repro.core import grids, sht, plan as planlib, dist_sht
 
 key = jax.random.PRNGKey(11)
 lmax = 24
 g = grids.make_grid("gl", l_max=lmax)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 p = planlib.SHTPlan(g, lmax, lmax, 4)
 ok = True
 

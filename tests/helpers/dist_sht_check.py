@@ -5,6 +5,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys
 import numpy as np, jax, jax.numpy as jnp
 import repro  # noqa
+
+jax.config.update("jax_enable_x64", True)   # float64 reference engine
 from repro.core import grids, sht, plan as planlib, dist_sht
 
 key = jax.random.PRNGKey(3)
@@ -14,7 +16,8 @@ t = sht.SHT(g, l_max=lmax, m_max=lmax)
 alm = sht.random_alm(key, lmax, lmax, K=2)
 maps_ref = np.asarray(t.alm2map(alm))
 alm_ref = np.asarray(t.map2alm(jnp.asarray(maps_ref)))
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 p = planlib.SHTPlan(g, lmax, lmax, 8)
 
 def check(name, fold, comm_dtype, stage1, dtype, tol_s, tol_a):

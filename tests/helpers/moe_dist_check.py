@@ -5,7 +5,8 @@ import sys, dataclasses
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 import repro  # noqa
-from repro import compat
+
+jax.config.update("jax_enable_x64", True)   # float64 reference engine
 from repro.configs import registry
 from repro.configs.base import reduced
 from repro.models import moe as M
@@ -22,20 +23,21 @@ x = jax.random.normal(jax.random.PRNGKey(1), (T, d), jnp.float32)
 
 y_ref, aux_ref = M.moe_apply_local(p, x, cfg, cdt=jnp.float32)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 pspec = M.spec_moe(cfg, make_rules(cfg, mesh), layer_stacked=False)
 def body(p_loc, x_loc):
     return M.moe_apply(p_loc, x_loc, cfg, axis_name="model", cdt=jnp.float32)
-y, aux = jax.jit(compat.shard_map(body, mesh=mesh,
+y, aux = jax.jit(jax.shard_map(body, mesh=mesh,
                 in_specs=(pspec, P("model", None)),
-                out_specs=(P("model", None), P())))(p, x)
+                out_specs=(P("model", None), P()), check_vma=False))(p, x)
 err = float(jnp.max(jnp.abs(y - y_ref))) / float(jnp.max(jnp.abs(y_ref)))
 
 def body2(p_loc, x_loc):
     return M.moe_apply_replicated(p_loc, x_loc, cfg, axis_name="model", cdt=jnp.float32)
-y2, _ = jax.jit(compat.shard_map(body2, mesh=mesh,
+y2, _ = jax.jit(jax.shard_map(body2, mesh=mesh,
                 in_specs=(pspec, P(None, None)),
-                out_specs=(P(None, None), P())))(p, x)
+                out_specs=(P(None, None), P()), check_vma=False))(p, x)
 err2 = float(jnp.max(jnp.abs(y2 - y_ref))) / float(jnp.max(jnp.abs(y_ref)))
 # full-block equivalence incl. shared expert, through _moe_block
 import dataclasses

@@ -4,6 +4,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import sys
 import numpy as np, jax, jax.numpy as jnp
 import repro  # noqa
+
+jax.config.update("jax_enable_x64", True)   # float64 reference engine
 from repro.models.attention import mea, ulysses_attention
 
 key = jax.random.PRNGKey(0)

@@ -509,3 +509,38 @@ def test_chardb_lp_corners_remeasured_zero(monkeypatch):
     again = dict(chardb.get_db().counters)
     assert again["measured"] == 0, again
     assert plan2.describe()["fusion"]["lp_size"] == lp1
+
+
+@pytest.mark.parametrize("mxu_us,pruned", [(100.0, True), (30.0, False)])
+def test_auto_prunes_the_layouts_of_a_slow_backend(monkeypatch, mxu_us,
+                                                   pruned):
+    """mode='auto' times a pallas backend's packed/plain layouts only when
+    its fused layout (timed first) is within PRUNE_FACTOR of the best so
+    far; pruned layouts are listed, not timed, and are no error."""
+    timed = []
+    orig = {d: getattr(transform.Plan, f"_{d}_fn") for d in ("synth", "anal")}
+
+    def spy(d):
+        def fn_of(self, backend, layout=None):
+            timed.append((d, backend, layout))
+            return orig[d](self, backend, layout)
+        return fn_of
+
+    for d in ("synth", "anal"):
+        monkeypatch.setattr(transform.Plan, f"_{d}_fn", spy(d))
+    speed = {"jnp": 10.0, "pallas_vpu": 20.0, "pallas_mxu": mxu_us}
+    monkeypatch.setattr(transform, "_time_call_us",
+                        lambda fn, arg: speed[timed[-1][1]])
+    plan = repro.make_plan("gl", l_max=8, K=1, dtype="float32", mode="auto",
+                           cache="memory")
+    table = plan.describe()["measured_s"]
+    assert not [k for row in table.values() for k in row
+                if k.endswith("_error")]
+    for d in ("synth", "anal"):
+        mxu_layouts = [lay for dd, b, lay in timed
+                       if dd == d and b == "pallas_mxu"]
+        assert mxu_layouts[0] == "fused"
+        assert (table["pallas_mxu"].get(f"{d}_pruned")
+                == (["packed", "plain"] if pruned else None))
+        assert len(mxu_layouts) == (1 if pruned else 3)
+        assert plan.backends[d] == "jnp"

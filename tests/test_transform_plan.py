@@ -327,3 +327,54 @@ def test_map2alm_iters_monotone_on_approximate_grids(kind):
     errs = [spectra.d_err(alm, p.map2alm(maps, iters=i)) for i in range(3)]
     assert errs[1] < errs[0] / 3         # first pass bites hard
     assert errs[2] < errs[1]             # and keeps shrinking
+
+
+# ---------------------------------------------------------------------------
+# device policy: float64, peaks table, staged VPU eligibility on a TPU
+# ---------------------------------------------------------------------------
+
+
+def test_float64_refused_on_tpu(monkeypatch):
+    """A TPU has no float64: plans and engine requests fail at once with
+    the reason, never running in float32 or emulation."""
+    from repro.serve import ShtEngine
+    monkeypatch.setattr(transform, "_on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="not available on a TPU"):
+        repro.make_plan("gl", l_max=8, dtype="float64", mode="jnp")
+    alm = np.zeros((9, 9), np.complex128)
+    with pytest.raises(ValueError, match="not available on a TPU"):
+        ShtEngine().submit(direction="alm2map", payload=alm, grid="gl",
+                           l_max=8, dtype="float64")
+    assert transform.default_dtype() == "float32"
+
+
+def test_float64_needs_x64_mode():
+    with jax.enable_x64(False):
+        with pytest.raises(ValueError, match="64-bit mode"):
+            repro.make_plan("gl", l_max=8, dtype="float64", mode="jnp")
+        assert transform.default_dtype() == "float32"
+    assert transform.default_dtype() == "float64"
+
+
+def test_staged_vpu_ineligible_on_tpu(monkeypatch):
+    """On a TPU the staged VPU kernels are skipped with Mosaic's reason;
+    the VPU backend keeps its fused layout."""
+    monkeypatch.setattr(transform, "_on_tpu", lambda: True)
+    elig = transform.backend_eligibility(grids.make_grid("gl", l_max=8),
+                                         "float32")
+    for lay in ("plain", "packed"):
+        assert "do not compile for TPU" in elig[f"pallas_vpu[{lay}]"]
+    assert elig["pallas_vpu"] is None
+
+
+def test_peaks_table_rejects_unknown_device():
+    from repro.roofline import analysis
+
+    class Dev:
+        platform, device_kind = "tpu", "TPU v9 imaginary"
+
+    with pytest.raises(ValueError, match="no peaks for device kind"):
+        analysis.hardware_for(Dev())
+    Dev.device_kind = "TPU v5 lite"
+    assert analysis.hardware_for(Dev()) is analysis.HW_V5E
+    assert analysis.hardware_for(jax.devices("cpu")[0]) is analysis.HW_HOST
