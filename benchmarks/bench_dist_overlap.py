@@ -13,7 +13,7 @@ Columns: name, us_per_call (speedup ratio for the ``overlap_speedup``
 rows), derived = chosen C and raw per-C times.
 """
 
-from benchmarks.bench_breakdown import run_helper
+from benchmarks.common import run_helper
 
 _HELPER = r'''
 import os

@@ -1,6 +1,8 @@
 """Shared benchmark utilities: timing, CSV emission, smoke-mode gating."""
 
 import os
+import subprocess
+import sys
 import time
 
 import jax
@@ -88,3 +90,29 @@ ROWS: list = []
 def emit(name, us_per_call, derived=""):
     ROWS.append((str(name), float(us_per_call), str(derived)))
     print(f"{name},{us_per_call:.1f},{derived}")
+
+
+def run_helper(helper: str, timeout: int = 560):
+    """Run a multi-device benchmark helper in a subprocess and re-emit its
+    ``CSV name,us,derived`` lines through :func:`emit` so they land in
+    the BENCH_<date>.json trajectory.
+
+    The helper simulates 8 host devices in a child process, which is
+    only possible on the CPU backend: on a TPU this process holds the
+    chip, and a child that needs it fails or hangs -- refused."""
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "multi-device benchmark helpers simulate host devices in a "
+            "child process; run them with JAX_PLATFORMS=cpu, not on "
+            f"the {jax.default_backend()} backend this process holds")
+    env = dict(os.environ)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    # src for repro, the repo root for benchmarks.common (time_multi)
+    env["PYTHONPATH"] = os.pathsep.join([os.path.join(root, "src"), root])
+    r = subprocess.run([sys.executable, "-c", helper], capture_output=True,
+                       text=True, timeout=timeout, env=env)
+    for line in r.stdout.splitlines():
+        if line.startswith("CSV "):
+            name, us, derived = line[4:].split(",", 2)
+            emit(name, float(us), derived)
+    return r

@@ -74,13 +74,13 @@ def main(argv=None) -> None:
         os.environ["REPRO_CHARDB_SMOKE"] = "1"
     from benchmarks import (bench_accuracy, bench_recurrence,
                             bench_scaling_model, bench_fft, bench_speedup,
-                            bench_breakdown, bench_dist_overlap,
-                            bench_dispatch, bench_spin, bench_serve)
+                            bench_dist_overlap, bench_dispatch, bench_spin,
+                            bench_serve)
     print("name,us_per_call,derived")
     errors = {}
     for mod in (bench_accuracy, bench_recurrence, bench_scaling_model,
-                bench_fft, bench_speedup, bench_breakdown,
-                bench_dist_overlap, bench_dispatch, bench_spin, bench_serve):
+                bench_fft, bench_speedup, bench_dist_overlap,
+                bench_dispatch, bench_spin, bench_serve):
         try:
             mod.main()
         except Exception as e:  # keep the harness going

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.autodiff import linear_pair
+from repro.tracing import ACCUMULATE, FOLD, LEGENDRE, RECURRENCE, scoped
 
 __all__ = [
     "log_mu",
@@ -246,14 +247,18 @@ def _delta_from_alm_impl(a_re, a_im, m, x, pmm_mant, pmm_scale, *, l_max,
 
     def body(l, carry):
         mp, mc, sc, dre, dim = carry
-        mp, mc, sc, val = recurrence_step(
-            l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
-            scale_bits=scale_bits, dtype=dtype)
+        with jax.named_scope(RECURRENCE):
+            mp, mc, sc, val = recurrence_step(
+                l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
+                scale_bits=scale_bits, dtype=dtype)
         # Delta_m(r) += a_{l,m} * P_{l,m}(r)   (paper eq. 12)
-        are = jax.lax.dynamic_index_in_dim(rows_re, l, axis=0, keepdims=False)
-        aim = jax.lax.dynamic_index_in_dim(rows_im, l, axis=0, keepdims=False)
-        dre = dre + (are.reshape(K, M)[:, :, None] * val).reshape(K * M, R)
-        dim = dim + (aim.reshape(K, M)[:, :, None] * val).reshape(K * M, R)
+        with jax.named_scope(ACCUMULATE):
+            are = jax.lax.dynamic_index_in_dim(rows_re, l, axis=0,
+                                               keepdims=False)
+            aim = jax.lax.dynamic_index_in_dim(rows_im, l, axis=0,
+                                               keepdims=False)
+            dre = dre + (are.reshape(K, M)[:, :, None] * val).reshape(K * M, R)
+            dim = dim + (aim.reshape(K, M)[:, :, None] * val).reshape(K * M, R)
         return mp, mc, sc, dre, dim
 
     _, _, _, d_re, d_im = jax.lax.fori_loop(0, l_max + 1, body, carry0)
@@ -261,6 +266,7 @@ def _delta_from_alm_impl(a_re, a_im, m, x, pmm_mant, pmm_scale, *, l_max,
     return unrow(d_re), unrow(d_im)
 
 
+@scoped(LEGENDRE)
 def delta_from_alm(a_re, a_im, m_vals, grid_x, grid_sin, log_mu_all, *,
                    l_max: int, dtype=jnp.float64):
     """Synthesis inner step: Delta^A_m(r) = sum_l a_lm P_lm(cos theta_r).
@@ -324,20 +330,23 @@ def _alm_from_delta_impl(d_re, d_im, m, x, pmm_mant, pmm_scale, w, *, l_max,
 
     def step(carry, l):
         mp, mc, sc = carry
-        mp, mc, sc, val = recurrence_step(
-            l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
-            scale_bits=scale_bits, dtype=dtype)
+        with jax.named_scope(RECURRENCE):
+            mp, mc, sc, val = recurrence_step(
+                l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
+                scale_bits=scale_bits, dtype=dtype)
         # a_{l,m} = sum_r w_r Delta^S_m(r) P_lm(r)   (paper eq. 13)
-        a_re_l = jnp.einsum("mr,mrk->km", val, dw_re,
-                            precision=_HIGHEST).reshape(-1)
-        a_im_l = jnp.einsum("mr,mrk->km", val, dw_im,
-                            precision=_HIGHEST).reshape(-1)
+        with jax.named_scope(ACCUMULATE):
+            a_re_l = jnp.einsum("mr,mrk->km", val, dw_re,
+                                precision=_HIGHEST).reshape(-1)
+            a_im_l = jnp.einsum("mr,mrk->km", val, dw_im,
+                                precision=_HIGHEST).reshape(-1)
         return (mp, mc, sc), (a_re_l, a_im_l)
 
     _, (a_re, a_im) = jax.lax.scan(step, carry0, jnp.arange(l_max + 1))
     return _scan_to_mlk(a_re, K), _scan_to_mlk(a_im, K)
 
 
+@scoped(LEGENDRE)
 def alm_from_delta(d_re, d_im, m_vals, grid_x, grid_sin, weights, log_mu_all,
                    *, l_max: int, dtype=jnp.float64):
     """Analysis inner step: a_lm = sum_r w_r Delta^S_m(r) P_lm(cos theta_r).
@@ -403,24 +412,29 @@ def _delta_from_alm_folded_impl(a_re, a_im, m, x, pmm_mant, pmm_scale, *,
 
     def body(l, carry):
         mp, mc, sc, ere, eim, ore_, oim = carry
-        mp, mc, sc, val = recurrence_step(
-            l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
-            scale_bits=scale_bits, dtype=dtype)
-        are = jax.lax.dynamic_index_in_dim(a_re, l, axis=1, keepdims=False)
-        aim = jax.lax.dynamic_index_in_dim(a_im, l, axis=1, keepdims=False)
-        cre = val[..., None] * are[:, None, :]
-        cim = val[..., None] * aim[:, None, :]
-        even = (((l + m) % 2) == 0)[..., None]     # (M, 1, 1)
-        ere = ere + jnp.where(even, cre, 0.0)
-        eim = eim + jnp.where(even, cim, 0.0)
-        ore_ = ore_ + jnp.where(even, 0.0, cre)
-        oim = oim + jnp.where(even, 0.0, cim)
+        with jax.named_scope(RECURRENCE):
+            mp, mc, sc, val = recurrence_step(
+                l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
+                scale_bits=scale_bits, dtype=dtype)
+        with jax.named_scope(ACCUMULATE):
+            are = jax.lax.dynamic_index_in_dim(a_re, l, axis=1,
+                                               keepdims=False)
+            aim = jax.lax.dynamic_index_in_dim(a_im, l, axis=1,
+                                               keepdims=False)
+            cre = val[..., None] * are[:, None, :]
+            cim = val[..., None] * aim[:, None, :]
+            even = (((l + m) % 2) == 0)[..., None]     # (M, 1, 1)
+            ere = ere + jnp.where(even, cre, 0.0)
+            eim = eim + jnp.where(even, cim, 0.0)
+            ore_ = ore_ + jnp.where(even, 0.0, cre)
+            oim = oim + jnp.where(even, 0.0, cim)
         return mp, mc, sc, ere, eim, ore_, oim
 
     _, _, _, ere, eim, ore_, oim = jax.lax.fori_loop(0, l_max + 1, body, carry0)
     return ere, eim, ore_, oim
 
 
+@scoped(LEGENDRE)
 def delta_from_alm_folded(a_re, a_im, m_vals, north_x, north_sin, log_mu_all,
                           *, l_max: int, dtype=jnp.float64):
     """Folded synthesis: returns even/odd partials over the northern rings.
@@ -470,22 +484,25 @@ def _alm_from_delta_folded_impl(s_e_re, s_e_im, s_o_re, s_o_im, m, x,
 
     def step(carry, l):
         mp, mc, sc = carry
-        mp, mc, sc, val = recurrence_step(
-            l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
-            scale_bits=scale_bits, dtype=dtype)
-        even = (((l + m) % 2) == 0)[..., None]     # (M, 1) -> (M, 1, 1) below
-        sre = jnp.where(even, s_e_re, s_o_re)
-        sim = jnp.where(even, s_e_im, s_o_im)
-        a_re_l = jnp.einsum("mr,mrk->km", val, sre,
-                            precision=_HIGHEST).reshape(-1)
-        a_im_l = jnp.einsum("mr,mrk->km", val, sim,
-                            precision=_HIGHEST).reshape(-1)
+        with jax.named_scope(RECURRENCE):
+            mp, mc, sc, val = recurrence_step(
+                l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
+                scale_bits=scale_bits, dtype=dtype)
+        with jax.named_scope(ACCUMULATE):
+            even = (((l + m) % 2) == 0)[..., None]  # (M, 1) -> (M, 1, 1)
+            sre = jnp.where(even, s_e_re, s_o_re)
+            sim = jnp.where(even, s_e_im, s_o_im)
+            a_re_l = jnp.einsum("mr,mrk->km", val, sre,
+                                precision=_HIGHEST).reshape(-1)
+            a_im_l = jnp.einsum("mr,mrk->km", val, sim,
+                                precision=_HIGHEST).reshape(-1)
         return (mp, mc, sc), (a_re_l, a_im_l)
 
     _, (a_re, a_im) = jax.lax.scan(step, carry0, jnp.arange(l_max + 1))
     return _scan_to_mlk(a_re, K), _scan_to_mlk(a_im, K)
 
 
+@scoped(LEGENDRE)
 def alm_from_delta_folded(sum_e_re, sum_e_im, sum_o_re, sum_o_im, m_vals,
                           north_x, north_sin, log_mu_all, *, l_max: int,
                           dtype=jnp.float64):
@@ -732,13 +749,17 @@ def _delta_from_alm_general_impl(a_re, a_im, m, mp, x, seed_mant, seed_scale,
 
     def body(l, carry):
         mprev, mcurr, sc, dre, dim = carry
-        mprev, mcurr, sc, val = recurrence_step_general(
-            l, m, mp, x, mprev, mcurr, sc, seed_mant, seed_scale,
-            scale_bits=scale_bits, dtype=dtype)
-        are = jax.lax.dynamic_index_in_dim(a_re, l, axis=1, keepdims=False)
-        aim = jax.lax.dynamic_index_in_dim(a_im, l, axis=1, keepdims=False)
-        dre = dre + val[..., None] * are[:, None, :]
-        dim = dim + val[..., None] * aim[:, None, :]
+        with jax.named_scope(RECURRENCE):
+            mprev, mcurr, sc, val = recurrence_step_general(
+                l, m, mp, x, mprev, mcurr, sc, seed_mant, seed_scale,
+                scale_bits=scale_bits, dtype=dtype)
+        with jax.named_scope(ACCUMULATE):
+            are = jax.lax.dynamic_index_in_dim(a_re, l, axis=1,
+                                               keepdims=False)
+            aim = jax.lax.dynamic_index_in_dim(a_im, l, axis=1,
+                                               keepdims=False)
+            dre = dre + val[..., None] * are[:, None, :]
+            dim = dim + val[..., None] * aim[:, None, :]
         return mprev, mcurr, sc, dre, dim
 
     _, _, _, d_re, d_im = jax.lax.fori_loop(0, l_max + 1, body, carry0)
@@ -756,19 +777,22 @@ def _alm_from_delta_general_impl(d_re, d_im, m, mp, x, seed_mant, seed_scale,
 
     def step(carry, l):
         mprev, mcurr, sc = carry
-        mprev, mcurr, sc, val = recurrence_step_general(
-            l, m, mp, x, mprev, mcurr, sc, seed_mant, seed_scale,
-            scale_bits=scale_bits, dtype=dtype)
-        a_re_l = jnp.einsum("mr,mrk->km", val, d_re,
-                            precision=_HIGHEST).reshape(-1)
-        a_im_l = jnp.einsum("mr,mrk->km", val, d_im,
-                            precision=_HIGHEST).reshape(-1)
+        with jax.named_scope(RECURRENCE):
+            mprev, mcurr, sc, val = recurrence_step_general(
+                l, m, mp, x, mprev, mcurr, sc, seed_mant, seed_scale,
+                scale_bits=scale_bits, dtype=dtype)
+        with jax.named_scope(ACCUMULATE):
+            a_re_l = jnp.einsum("mr,mrk->km", val, d_re,
+                                precision=_HIGHEST).reshape(-1)
+            a_im_l = jnp.einsum("mr,mrk->km", val, d_im,
+                                precision=_HIGHEST).reshape(-1)
         return (mprev, mcurr, sc), (a_re_l, a_im_l)
 
     _, (a_re, a_im) = jax.lax.scan(step, carry0, jnp.arange(l_max + 1))
     return _scan_to_mlk(a_re, K), _scan_to_mlk(a_im, K)
 
 
+@scoped(LEGENDRE)
 def delta_from_alm_general(a_re, a_im, m_vals, mprime_vals, grid_x, grid_sin,
                            *, l_max: int, m_max: Optional[int] = None,
                            dtype=jnp.float64):
@@ -810,6 +834,7 @@ def delta_from_alm_general(a_re, a_im, m_vals, mprime_vals, grid_x, grid_sin,
                        (a_re, a_im))
 
 
+@scoped(LEGENDRE)
 def alm_from_delta_general(d_re, d_im, m_vals, mprime_vals, grid_x, grid_sin,
                            *, l_max: int, m_max: Optional[int] = None,
                            dtype=jnp.float64):
@@ -930,12 +955,14 @@ def delta_from_alm_spin(e_re, e_im, b_re, b_im, m_vals, grid_x, grid_sin, *,
     Inputs (M, l_max+1, K) real/imag parts; returns
     (dq_re, dq_im, du_re, du_im), each (M, R, K).
     """
-    a2_re, a2_im = spin_pack_alm(e_re, e_im, b_re, b_im)
+    with jax.named_scope(FOLD):
+        a2_re, a2_im = spin_pack_alm(e_re, e_im, b_re, b_im)
     m2, mp2 = _spin_rows(m_vals)
     d_re, d_im = delta_from_alm_general(
         a2_re, a2_im, m2, mp2, grid_x, grid_sin, l_max=l_max, m_max=m_max,
         dtype=dtype)
-    return spin_unpack_delta(d_re, d_im)
+    with jax.named_scope(FOLD):
+        return spin_unpack_delta(d_re, d_im)
 
 
 def alm_from_delta_spin(dq_re, dq_im, du_re, du_im, m_vals, grid_x, grid_sin,
@@ -945,12 +972,14 @@ def alm_from_delta_spin(dq_re, dq_im, du_re, du_im, m_vals, grid_x, grid_sin,
 
     Inputs (M, R, K); returns (e_re, e_im, b_re, b_im), each (M, L1, K).
     """
-    d2_re, d2_im = spin_pack_delta(dq_re, dq_im, du_re, du_im)
+    with jax.named_scope(FOLD):
+        d2_re, d2_im = spin_pack_delta(dq_re, dq_im, du_re, du_im)
     m2, mp2 = _spin_rows(m_vals)
     a_re, a_im = alm_from_delta_general(
         d2_re, d2_im, m2, mp2, grid_x, grid_sin, l_max=l_max, m_max=m_max,
         dtype=dtype)
-    return spin_unpack_alm(a_re, a_im)
+    with jax.named_scope(FOLD):
+        return spin_unpack_alm(a_re, a_im)
 
 
 import dataclasses as _dataclasses
@@ -992,28 +1021,39 @@ class HarmonicCore:
     def delta_from_alm(self, alm):
         dt = jnp.dtype(self.dtype)
         if self.spin == 0:
+            with jax.named_scope(FOLD):
+                a_re, a_im = jnp.real(alm), jnp.imag(alm)
             d_re, d_im = delta_from_alm(
-                jnp.real(alm), jnp.imag(alm), self.m_vals, self.grid_x,
-                self.grid_sin, self.log_mu_all, l_max=self.l_max, dtype=dt)
-            return d_re + 1j * d_im
-        e, b = alm[0], alm[1]
+                a_re, a_im, self.m_vals, self.grid_x, self.grid_sin,
+                self.log_mu_all, l_max=self.l_max, dtype=dt)
+            with jax.named_scope(FOLD):
+                return d_re + 1j * d_im
+        with jax.named_scope(FOLD):
+            e, b = alm[0], alm[1]
+            parts = (jnp.real(e), jnp.imag(e), jnp.real(b), jnp.imag(b))
         dq_re, dq_im, du_re, du_im = delta_from_alm_spin(
-            jnp.real(e), jnp.imag(e), jnp.real(b), jnp.imag(b), self.m_vals,
-            self.grid_x, self.grid_sin, l_max=self.l_max, dtype=dt)
-        return jnp.stack([dq_re + 1j * dq_im, du_re + 1j * du_im], axis=0)
+            *parts, self.m_vals, self.grid_x, self.grid_sin,
+            l_max=self.l_max, dtype=dt)
+        with jax.named_scope(FOLD):
+            return jnp.stack([dq_re + 1j * dq_im, du_re + 1j * du_im],
+                             axis=0)
 
     def alm_from_delta(self, delta_w):
         dt = jnp.dtype(self.dtype)
         if self.spin == 0:
             ones = np.ones(np.asarray(self.grid_x).shape[0])
+            with jax.named_scope(FOLD):
+                d_re, d_im = jnp.real(delta_w), jnp.imag(delta_w)
             a_re, a_im = alm_from_delta(
-                jnp.real(delta_w), jnp.imag(delta_w), self.m_vals,
-                self.grid_x, self.grid_sin, ones, self.log_mu_all,
-                l_max=self.l_max, dtype=dt)
-            return a_re + 1j * a_im
-        dq, du = delta_w[0], delta_w[1]
+                d_re, d_im, self.m_vals, self.grid_x, self.grid_sin, ones,
+                self.log_mu_all, l_max=self.l_max, dtype=dt)
+            with jax.named_scope(FOLD):
+                return a_re + 1j * a_im
+        with jax.named_scope(FOLD):
+            dq, du = delta_w[0], delta_w[1]
+            parts = (jnp.real(dq), jnp.imag(dq), jnp.real(du), jnp.imag(du))
         e_re, e_im, b_re, b_im = alm_from_delta_spin(
-            jnp.real(dq), jnp.imag(dq), jnp.real(du), jnp.imag(du),
-            self.m_vals, self.grid_x, self.grid_sin, l_max=self.l_max,
-            dtype=dt)
-        return jnp.stack([e_re + 1j * e_im, b_re + 1j * b_im], axis=0)
+            *parts, self.m_vals, self.grid_x, self.grid_sin,
+            l_max=self.l_max, dtype=dt)
+        with jax.named_scope(FOLD):
+            return jnp.stack([e_re + 1j * e_im, b_re + 1j * b_im], axis=0)

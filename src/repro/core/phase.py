@@ -58,6 +58,7 @@ import numpy as np
 from repro.core import cache as plancache
 from repro.core.autodiff import linear_pair
 from repro.core.grids import BucketLayout, RingGrid
+from repro.tracing import PHASE, scoped
 
 __all__ = [
     "uniform_synth", "uniform_anal", "bucket_synth", "bucket_anal",
@@ -230,6 +231,7 @@ def _uniform_anal_core(maps, phi0, m, n, dtype):
     return jnp.real(A).astype(dtype), jnp.imag(A).astype(dtype)
 
 
+@scoped(PHASE)
 def uniform_synth(delta, m_vals, n: int, phi0, *, dtype,
                   scale_rows=None) -> jnp.ndarray:
     """Synthesis phase stage on a uniform grid.
@@ -265,6 +267,7 @@ def uniform_synth(delta, m_vals, n: int, phi0, *, dtype,
                        (jnp.real(delta), jnp.imag(delta)))
 
 
+@scoped(PHASE)
 def uniform_anal(maps, m_vals, n: int, phi0, weights, *, dtype) -> jnp.ndarray:
     """Analysis phase stage on a uniform grid.
 
@@ -384,6 +387,7 @@ def _bucket_anal_core(maps, pos, n_phi, phi0, m, layout, dtype):
     return jnp.real(A).astype(dtype), jnp.imag(A).astype(dtype)
 
 
+@scoped(PHASE)
 def bucket_synth(delta, layout: BucketLayout, pos, neg, n_phi, phi0, m_vals,
                  *, out_width: int, dtype, scale_rows=None) -> jnp.ndarray:
     """Synthesis phase stage on a ragged grid, one batched FFT per bucket.
@@ -420,6 +424,7 @@ def bucket_synth(delta, layout: BucketLayout, pos, neg, n_phi, phi0, m_vals,
                        (jnp.real(delta), jnp.imag(delta)))
 
 
+@scoped(PHASE)
 def bucket_anal(maps, layout: BucketLayout, pos, n_phi, phi0, weights,
                 m_vals, *, dtype) -> jnp.ndarray:
     """Analysis phase stage on a ragged grid, one batched FFT per bucket.

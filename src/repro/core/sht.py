@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.core import legendre
 from repro.core.grids import RingGrid
+from repro.tracing import FOLD
 
 __all__ = ["SHT", "alm_rect_zeros", "random_alm", "random_alm_spin",
            "alm_mask"]
@@ -185,13 +186,16 @@ class SHT:
         if not self.fold:
             return self._harmonic_core(0).delta_from_alm(alm)
         nh = self.n_north
+        with jax.named_scope(FOLD):
+            a_re, a_im = jnp.real(alm), jnp.imag(alm)
         ere, eim, ore_, oim = legendre.delta_from_alm_folded(
-            jnp.real(alm), jnp.imag(alm), self._m_all, g.cos_theta[:nh],
-            g.sin_theta[:nh], self._log_mu, l_max=self.l_max, dtype=dt)
-        north = (ere + ore_) + 1j * (eim + oim)               # (M, nh, K)
-        ns = nh - 1 if self.has_equator else nh
-        south = (ere - ore_)[:, :ns] + 1j * (eim - oim)[:, :ns]
-        return jnp.concatenate([north, south[:, ::-1]], axis=1)
+            a_re, a_im, self._m_all, g.cos_theta[:nh], g.sin_theta[:nh],
+            self._log_mu, l_max=self.l_max, dtype=dt)
+        with jax.named_scope(FOLD):
+            north = (ere + ore_) + 1j * (eim + oim)           # (M, nh, K)
+            ns = nh - 1 if self.has_equator else nh
+            south = (ere - ore_)[:, :ns] + 1j * (eim - oim)[:, :ns]
+            return jnp.concatenate([north, south[:, ::-1]], axis=1)
 
     def _alm_from_delta(self, delta_w: jnp.ndarray) -> jnp.ndarray:
         """(M, R, K) weighted Delta^S -> (M, L, K) complex alm.
@@ -203,19 +207,23 @@ class SHT:
         if not self.fold:
             return self._harmonic_core(0).alm_from_delta(delta_w)
         nh = self.n_north
-        north = delta_w[:, :nh]
-        ns = nh - 1 if self.has_equator else nh
-        south = delta_w[:, nh:][:, ::-1]                      # mirror order
-        pad = north[:, ns:nh] * 0.0                           # equator slot
-        south_p = jnp.concatenate([south, pad], axis=1) if self.has_equator else south
-        s_e = north + south_p
-        s_o = north - south_p
-        # (equator ring: P_lm(0) = 0 for odd l+m, so its s_o value is inert)
+        with jax.named_scope(FOLD):
+            north = delta_w[:, :nh]
+            ns = nh - 1 if self.has_equator else nh
+            south = delta_w[:, nh:][:, ::-1]                  # mirror order
+            pad = north[:, ns:nh] * 0.0                       # equator slot
+            south_p = jnp.concatenate([south, pad], axis=1) \
+                if self.has_equator else south
+            s_e = north + south_p
+            s_o = north - south_p
+            # (equator ring: P_lm(0) = 0 for odd l+m: its s_o is inert)
+            parts = (jnp.real(s_e), jnp.imag(s_e), jnp.real(s_o),
+                     jnp.imag(s_o))
         a_re, a_im = legendre.alm_from_delta_folded(
-            jnp.real(s_e), jnp.imag(s_e), jnp.real(s_o), jnp.imag(s_o),
-            self._m_all, g.cos_theta[:nh], g.sin_theta[:nh], self._log_mu,
-            l_max=self.l_max, dtype=dt)
-        return a_re + 1j * a_im
+            *parts, self._m_all, g.cos_theta[:nh], g.sin_theta[:nh],
+            self._log_mu, l_max=self.l_max, dtype=dt)
+        with jax.named_scope(FOLD):
+            return a_re + 1j * a_im
 
     # -- public API ----------------------------------------------------------
 
@@ -260,9 +268,11 @@ class SHT:
             alm_eb.shape
         K = alm_eb.shape[-1]
         delta = self._harmonic_core(2).delta_from_alm(alm_eb)  # (2, M, R, K)
-        d2 = jnp.concatenate([delta[0], delta[1]], axis=-1)    # (M, R, 2K)
+        with jax.named_scope(FOLD):
+            d2 = jnp.concatenate([delta[0], delta[1]], axis=-1)  # (M, R, 2K)
         s = self.phase.synth(d2)                               # (R, nphi, 2K)
-        return jnp.stack([s[..., :K], s[..., K:]], axis=0)
+        with jax.named_scope(FOLD):
+            return jnp.stack([s[..., :K], s[..., K:]], axis=0)
 
     def map2alm_spin(self, maps_qu: jnp.ndarray, iters: int = 0) -> jnp.ndarray:
         """Spin-2 analysis: (Q, U) maps (2, R, n_phi, K) -> (E, B) alm
@@ -272,9 +282,11 @@ class SHT:
             maps_qu.shape[1] == self.grid.n_rings, maps_qu.shape
         maps_qu = jnp.asarray(maps_qu)
         K = maps_qu.shape[-1]
-        m2 = jnp.concatenate([maps_qu[0], maps_qu[1]], axis=-1)
+        with jax.named_scope(FOLD):
+            m2 = jnp.concatenate([maps_qu[0], maps_qu[1]], axis=-1)
         dw = self.phase.anal(m2)                               # (M, R, 2K)
-        delta_w = jnp.stack([dw[..., :K], dw[..., K:]], axis=0)
+        with jax.named_scope(FOLD):
+            delta_w = jnp.stack([dw[..., :K], dw[..., K:]], axis=0)
         alm = self._harmonic_core(2).alm_from_delta(delta_w)
         for _ in range(iters):
             resid = maps_qu - self.alm2map_spin(alm)

@@ -101,6 +101,7 @@ from repro.core import legendre
 from repro.core.grids import RingGrid
 from repro.core.sht import SHT, alm_mask, random_alm, random_alm_spin
 from repro.roofline import analysis as roofline
+from repro.tracing import ALM2MAP, FOLD, MAP2ALM
 
 __all__ = ["Plan", "make_plan", "available_backends", "backend_eligibility",
            "clear_plan_cache", "drop_plan"]
@@ -528,19 +529,21 @@ class Plan:
 
         def fn(alm, consts):
             pmm, pms, x32 = consts
-            a32 = jnp.concatenate(
-                [jnp.real(alm), jnp.imag(alm)], axis=-1).astype(jnp.float32)
+            with jax.named_scope(FOLD):
+                a32 = jnp.concatenate([jnp.real(alm), jnp.imag(alm)],
+                                      axis=-1).astype(jnp.float32)
             out = kops.synth(a32, self._m_vals, x32, pmm, pms,
                              l_max=self.l_max, fold=self.fold,
                              variant=variant, layout=layout)
-            if self.fold:
-                e, o = out[:, 0], out[:, 1]               # (M, nh, 2K)
-                north = e + o
-                south = (e - o)[:, :ns][:, ::-1]
-                flat = jnp.concatenate([north, south], axis=1)
-            else:
-                flat = out[:, 0]                          # (M, R, 2K)
-            delta = (flat[..., :K] + 1j * flat[..., K:]).astype(cdt)
+            with jax.named_scope(FOLD):
+                if self.fold:
+                    e, o = out[:, 0], out[:, 1]           # (M, nh, 2K)
+                    north = e + o
+                    south = (e - o)[:, :ns][:, ::-1]
+                    flat = jnp.concatenate([north, south], axis=1)
+                else:
+                    flat = out[:, 0]                      # (M, R, 2K)
+                delta = (flat[..., :K] + 1j * flat[..., K:]).astype(cdt)
             return self._sht.phase.synth(delta).astype(self.dtype)
 
         return _bind(fn, self._seeds())
@@ -555,20 +558,23 @@ class Plan:
         def fn(maps, consts):
             (pmm, pms, x32), mask = consts
             dwc = self._sht.phase.anal(maps)              # (M, R, K) complex
-            dw = jnp.concatenate(
-                [jnp.real(dwc), jnp.imag(dwc)], axis=-1).astype(jnp.float32)
-            if self.fold:
-                n_part = dw[:, :nh]
-                s_part = jnp.zeros_like(n_part)
-                s_part = s_part.at[:, : R - nh].set(dw[:, nh:][:, ::-1])
-                dwk = jnp.stack([n_part + s_part, n_part - s_part], axis=1)
-            else:
-                dwk = dw[:, None]                         # (M, 1, R, 2K)
+            with jax.named_scope(FOLD):
+                dw = jnp.concatenate([jnp.real(dwc), jnp.imag(dwc)],
+                                     axis=-1).astype(jnp.float32)
+                if self.fold:
+                    n_part = dw[:, :nh]
+                    s_part = jnp.zeros_like(n_part)
+                    s_part = s_part.at[:, : R - nh].set(dw[:, nh:][:, ::-1])
+                    dwk = jnp.stack([n_part + s_part, n_part - s_part],
+                                    axis=1)
+                else:
+                    dwk = dw[:, None]                     # (M, 1, R, 2K)
             out = kops.anal(dwk, self._m_vals, x32, pmm, pms,
                             l_max=self.l_max, fold=self.fold, variant=variant,
                             layout=layout)
-            alm = (out[..., :K] + 1j * out[..., K:]).astype(cdt)
-            return jnp.where(mask, alm, 0.0)
+            with jax.named_scope(FOLD):
+                alm = (out[..., :K] + 1j * out[..., K:]).astype(cdt)
+                return jnp.where(mask, alm, 0.0)
 
         return _bind(fn, (self._seeds(), mask))
 
@@ -583,21 +589,25 @@ class Plan:
 
         def fn(alm_eb, consts):
             pmm, pms, x32 = consts
-            e, b = alm_eb[0], alm_eb[1]
-            a2_re, a2_im = leg.spin_pack_alm(
-                jnp.real(e), jnp.imag(e), jnp.real(b), jnp.imag(b))
-            a32 = jnp.concatenate([a2_re, a2_im], axis=-1).astype(jnp.float32)
+            with jax.named_scope(FOLD):
+                e, b = alm_eb[0], alm_eb[1]
+                a2_re, a2_im = leg.spin_pack_alm(
+                    jnp.real(e), jnp.imag(e), jnp.real(b), jnp.imag(b))
+                a32 = jnp.concatenate([a2_re, a2_im],
+                                      axis=-1).astype(jnp.float32)
             out = kops.synth(a32, m2, x32, pmm, pms, l_max=self.l_max,
                              fold=False, variant=variant, mp_vals=mp2,
                              layout=layout)
-            flat = out[:, 0]                          # (2M, R, 2K)
-            dq_re, dq_im, du_re, du_im = leg.spin_unpack_delta(
-                flat[..., :K], flat[..., K:])
-            delta = jnp.concatenate(
-                [dq_re + 1j * dq_im, du_re + 1j * du_im],
-                axis=-1).astype(cdt)                  # (M, R, 2K)
+            with jax.named_scope(FOLD):
+                flat = out[:, 0]                      # (2M, R, 2K)
+                dq_re, dq_im, du_re, du_im = leg.spin_unpack_delta(
+                    flat[..., :K], flat[..., K:])
+                delta = jnp.concatenate(
+                    [dq_re + 1j * dq_im, du_re + 1j * du_im],
+                    axis=-1).astype(cdt)              # (M, R, 2K)
             s = self._sht.phase.synth(delta).astype(self.dtype)
-            return jnp.stack([s[..., :K], s[..., K:]], axis=0)
+            with jax.named_scope(FOLD):
+                return jnp.stack([s[..., :K], s[..., K:]], axis=0)
 
         return _bind(fn, (pmm, pms, x32))
 
@@ -612,21 +622,24 @@ class Plan:
 
         def fn(maps_qu, consts):
             (pmm, pms, x32), mask = consts
-            m2d = jnp.concatenate([maps_qu[0], maps_qu[1]], axis=-1)
+            with jax.named_scope(FOLD):
+                m2d = jnp.concatenate([maps_qu[0], maps_qu[1]], axis=-1)
             dwc = self._sht.phase.anal(m2d)           # (M, R, 2K) complex
-            d2_re, d2_im = leg.spin_pack_delta(
-                jnp.real(dwc[..., :K]), jnp.imag(dwc[..., :K]),
-                jnp.real(dwc[..., K:]), jnp.imag(dwc[..., K:]))
-            dw32 = jnp.concatenate([d2_re, d2_im],
-                                   axis=-1).astype(jnp.float32)[:, None]
+            with jax.named_scope(FOLD):
+                d2_re, d2_im = leg.spin_pack_delta(
+                    jnp.real(dwc[..., :K]), jnp.imag(dwc[..., :K]),
+                    jnp.real(dwc[..., K:]), jnp.imag(dwc[..., K:]))
+                dw32 = jnp.concatenate([d2_re, d2_im],
+                                       axis=-1).astype(jnp.float32)[:, None]
             out = kops.anal(dw32, m2, x32, pmm, pms, l_max=self.l_max,
                             fold=False, variant=variant, mp_vals=mp2,
                             layout=layout)
-            e_re, e_im, b_re, b_im = leg.spin_unpack_alm(
-                out[..., :K], out[..., K:])
-            alm = jnp.stack([e_re + 1j * e_im, b_re + 1j * b_im],
-                            axis=0).astype(cdt)
-            return jnp.where(mask[None], alm, 0.0)
+            with jax.named_scope(FOLD):
+                e_re, e_im, b_re, b_im = leg.spin_unpack_alm(
+                    out[..., :K], out[..., K:])
+                alm = jnp.stack([e_re + 1j * e_im, b_re + 1j * b_im],
+                                axis=0).astype(cdt)
+                return jnp.where(mask[None], alm, 0.0)
 
         return _bind(fn, ((pmm, pms, x32), mask))
 
@@ -782,19 +795,22 @@ class Plan:
 
         if self.spin == 0:
             def fn(alm, consts):
-                a32 = jnp.concatenate(
-                    [jnp.real(alm), jnp.imag(alm)],
-                    axis=-1).astype(jnp.float32)
+                with jax.named_scope(FOLD):
+                    a32 = jnp.concatenate(
+                        [jnp.real(alm), jnp.imag(alm)],
+                        axis=-1).astype(jnp.float32)
                 return run(a32, consts).astype(self.dtype)
         else:
             def fn(alm_eb, consts):
-                e, b = alm_eb[0], alm_eb[1]
-                a2_re, a2_im = leg.spin_pack_alm(
-                    jnp.real(e), jnp.imag(e), jnp.real(b), jnp.imag(b))
-                a32 = jnp.concatenate([a2_re, a2_im],
-                                      axis=-1).astype(jnp.float32)
+                with jax.named_scope(FOLD):
+                    e, b = alm_eb[0], alm_eb[1]
+                    a2_re, a2_im = leg.spin_pack_alm(
+                        jnp.real(e), jnp.imag(e), jnp.real(b), jnp.imag(b))
+                    a32 = jnp.concatenate([a2_re, a2_im],
+                                          axis=-1).astype(jnp.float32)
                 s = run(a32, consts).astype(self.dtype)
-                return jnp.stack([s[..., :K], s[..., K:]], axis=0)
+                with jax.named_scope(FOLD):
+                    return jnp.stack([s[..., :K], s[..., K:]], axis=0)
 
         return _bind(fn, consts)
 
@@ -816,17 +832,20 @@ class Plan:
         if self.spin == 0:
             def fn(maps, consts):
                 out = run(maps, consts)
-                alm = (out[..., :K] + 1j * out[..., K:]).astype(cdt)
-                return jnp.where(consts[2], alm, 0.0)
+                with jax.named_scope(FOLD):
+                    alm = (out[..., :K] + 1j * out[..., K:]).astype(cdt)
+                    return jnp.where(consts[2], alm, 0.0)
         else:
             def fn(maps_qu, consts):
-                m2d = jnp.concatenate([maps_qu[0], maps_qu[1]], axis=-1)
+                with jax.named_scope(FOLD):
+                    m2d = jnp.concatenate([maps_qu[0], maps_qu[1]], axis=-1)
                 out = run(m2d, consts)
-                e_re, e_im, b_re, b_im = leg.spin_unpack_alm(
-                    out[..., :K], out[..., K:])
-                alm = jnp.stack([e_re + 1j * e_im, b_re + 1j * b_im],
-                                axis=0).astype(cdt)
-                return jnp.where(consts[2][None], alm, 0.0)
+                with jax.named_scope(FOLD):
+                    e_re, e_im, b_re, b_im = leg.spin_unpack_alm(
+                        out[..., :K], out[..., K:])
+                    alm = jnp.stack([e_re + 1j * e_im, b_re + 1j * b_im],
+                                    axis=0).astype(cdt)
+                    return jnp.where(consts[2][None], alm, 0.0)
 
         return _bind(fn, (consts, w, mask))
 
@@ -1120,7 +1139,8 @@ class Plan:
         """
         assert alm.shape == self._alm_shape, \
             (alm.shape, f"plan was built for {self._alm_shape}")
-        return self._synth_fn(self.backends["synth"])(jnp.asarray(alm))
+        with jax.profiler.TraceAnnotation(ALM2MAP):
+            return self._synth_fn(self.backends["synth"])(jnp.asarray(alm))
 
     def map2alm(self, maps, iters: int = 0) -> jnp.ndarray:
         """Direct SHT (analysis): maps -> alm through the chosen backend.
@@ -1133,12 +1153,13 @@ class Plan:
         """
         assert maps.shape == self._maps_shape, \
             (maps.shape, f"plan was built for {self._maps_shape}")
-        maps = jnp.asarray(maps)
-        alm = self._anal_fn(self.backends["anal"])(maps)
-        for _ in range(iters):
-            resid = maps - self.alm2map(alm)
-            alm = alm + self._anal_fn(self.backends["anal"])(resid)
-        return alm
+        with jax.profiler.TraceAnnotation(MAP2ALM):
+            maps = jnp.asarray(maps)
+            alm = self._anal_fn(self.backends["anal"])(maps)
+            for _ in range(iters):
+                resid = maps - self.alm2map(alm)
+                alm = alm + self._anal_fn(self.backends["anal"])(resid)
+            return alm
 
     def warmup(self, directions=("synth", "anal")) -> "Plan":
         """Compile and execute each direction once on zero inputs.

@@ -82,6 +82,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.autodiff import linear_pair
+from repro.tracing import FOLD, LEGENDRE, PHASE, scoped
 from repro.kernels.legendre_pallas import (F32_DOT, _pad_rows, _ring_rows,
                                            _step)
 
@@ -821,6 +822,7 @@ def rotation_tables(m_vals, *, phase_kind, phi0, n=None, fold_rings=None,
     return tuple(tabs), tuple(rot)
 
 
+@scoped(LEGENDRE)
 def _kernel_synth(a, tabs, x, pmm, pms, *, l_max, var, bf16, lo, lp_size,
                   interpret, spin, rot):
     """Packed fused kernel leg: a (Mr, L1, 2K) + (Mr, n_pl, 4, R) tables ->
@@ -848,6 +850,7 @@ def _kernel_synth(a, tabs, x, pmm, pms, *, l_max, var, bf16, lo, lp_size,
     return kops._unpack_rows(seg, lo, Mr)[:, :, :R, :]
 
 
+@scoped(LEGENDRE)
 def _kernel_anal(fp, tabs, x, pmm, pms, *, l_max, var, bf16, lo, lp_size,
                  interpret, spin, rot):
     """Packed fused kernel leg: per-plane unrotated-input rows fp
@@ -875,6 +878,7 @@ def _kernel_anal(fp, tabs, x, pmm, pms, *, l_max, var, bf16, lo, lp_size,
     return kops._unpack_alm(out, lo)
 
 
+@scoped(PHASE)
 def _bucket_scatter(hc, m_vals, layout, pos, neg, n_phi, out_width):
     """Host epilogue of the fused bucket synthesis: rotated rows hc
     (M, R, C) complex64 -> ring samples (R, out_width, C) f32.  The
@@ -908,6 +912,7 @@ def _bucket_scatter(hc, m_vals, layout, pos, neg, n_phi, out_width):
     return out
 
 
+@scoped(PHASE)
 def _bucket_gather(maps_w, m_vals, layout, pos, n_phi):
     """Host prologue of the fused bucket analysis: ring samples (R, W, C)
     -> gathered UNrotated spectrum rows (M, R, C) complex64 (the in-kernel
@@ -950,32 +955,36 @@ def _synth_chain(a, m_vals, x, pmm, pms, tabs, *, l_max, var, bf16, lo,
     h = _kernel_synth(a, tabs[0], x, pmm, pms, l_max=l_max, var=var,
                       bf16=bf16, lo=lo, lp_size=lp_size, interpret=interpret,
                       spin=spin, rot=rot[0])
-    if fold_rings is not None:
-        # in-kernel combine already produced (north | south) planes; the
-        # south rows come out in fold order (equator-out), reverse + trim
-        ns = fold_rings - x.shape[0]
-        flat = jnp.concatenate([h[:, 0], h[:, 1, :ns][:, ::-1]], axis=1)
-    else:
-        flat = h[:, 0]                        # (Mr, R, 2K)
-    if spin:
-        dq_re, dq_im, du_re, du_im = leg.spin_unpack_delta(
-            flat[..., :n_k], flat[..., n_k:])
-        hc = jnp.concatenate([dq_re + 1j * dq_im, du_re + 1j * du_im],
-                             axis=-1).astype(jnp.complex64)   # (M, R, 2K)
-        mv = np.asarray(m_vals)[:a.shape[0] // 2]
-    else:
-        hc = (flat[..., :n_k] + 1j * flat[..., n_k:]).astype(jnp.complex64)
-        mv = np.asarray(m_vals)
+    with jax.named_scope(FOLD):
+        if fold_rings is not None:
+            # in-kernel combine already produced (north | south) planes;
+            # the south rows come out in fold order (equator-out),
+            # reverse + trim
+            ns = fold_rings - x.shape[0]
+            flat = jnp.concatenate([h[:, 0], h[:, 1, :ns][:, ::-1]], axis=1)
+        else:
+            flat = h[:, 0]                    # (Mr, R, 2K)
+        if spin:
+            dq_re, dq_im, du_re, du_im = leg.spin_unpack_delta(
+                flat[..., :n_k], flat[..., n_k:])
+            hc = jnp.concatenate([dq_re + 1j * dq_im, du_re + 1j * du_im],
+                                 axis=-1).astype(jnp.complex64)  # (M, R, 2K)
+            mv = np.asarray(m_vals)[:a.shape[0] // 2]
+        else:
+            hc = (flat[..., :n_k] + 1j * flat[..., n_k:]).astype(
+                jnp.complex64)
+            mv = np.asarray(m_vals)
     if phase_kind == "bucket":
         return _bucket_scatter(hc, mv, bucket["layout"], bucket["pos"],
                                bucket["neg"], bucket["n_phi"],
                                bucket["out_width"])
-    R_out, C = hc.shape[1], hc.shape[-1]
-    bins, _, _ = phase.uniform_bin_maps(mv, n)
-    half = n // 2 + 1
-    H = jnp.zeros((R_out, half, C), jnp.complex64)
-    H = H.at[:, jnp.asarray(bins)].add(jnp.moveaxis(hc, 0, 1))
-    return (jnp.fft.irfft(H, n=n, axis=1) * n).astype(jnp.float32)
+    with jax.named_scope(PHASE):
+        R_out, C = hc.shape[1], hc.shape[-1]
+        bins, _, _ = phase.uniform_bin_maps(mv, n)
+        half = n // 2 + 1
+        H = jnp.zeros((R_out, half, C), jnp.complex64)
+        H = H.at[:, jnp.asarray(bins)].add(jnp.moveaxis(hc, 0, 1))
+        return (jnp.fft.irfft(H, n=n, axis=1) * n).astype(jnp.float32)
 
 
 def _anal_chain(maps_w, m_vals, x, pmm, pms, tabs, *, l_max, var, bf16, lo,
@@ -992,26 +1001,28 @@ def _anal_chain(maps_w, m_vals, x, pmm, pms, tabs, *, l_max, var, bf16, lo,
         Fm = _bucket_gather(maps_w, mv, bucket["layout"], bucket["pos"],
                             bucket["n_phi"])
     else:
-        F = jnp.fft.rfft(maps_w.astype(jnp.float32), axis=1)   # (R, half, C)
-        bins, _, _ = phase.uniform_bin_maps(mv, n)
-        Fm = jnp.moveaxis(F[:, jnp.asarray(bins), :], 1, 0)    # (M, R, C)
-    if spin:
-        n_k = Fm.shape[-1] // 2
-        f_re, f_im = leg.spin_pack_delta(
-            jnp.real(Fm[..., :n_k]), jnp.imag(Fm[..., :n_k]),
-            jnp.real(Fm[..., n_k:]), jnp.imag(Fm[..., n_k:]))
-        f = jnp.concatenate([f_re, f_im], axis=-1).astype(jnp.float32)
-    else:
-        f = jnp.concatenate([jnp.real(Fm), jnp.imag(Fm)],
-                            axis=-1).astype(jnp.float32)       # (M, R, 2K)
-    if fold_rings is not None:
-        nh = x.shape[0]
-        ns = R_full - nh
-        f_n = f[:, :nh]
-        f_s = jnp.zeros_like(f_n).at[:, :ns].set(f[:, nh:][:, ::-1])
-        fp = jnp.stack([f_n, f_s], axis=1)    # (Mr, 2, nh, 2K)
-    else:
-        fp = f[:, None]                       # (Mr, 1, R, 2K)
+        with jax.named_scope(PHASE):
+            F = jnp.fft.rfft(maps_w.astype(jnp.float32), axis=1)  # (R, h, C)
+            bins, _, _ = phase.uniform_bin_maps(mv, n)
+            Fm = jnp.moveaxis(F[:, jnp.asarray(bins), :], 1, 0)   # (M, R, C)
+    with jax.named_scope(FOLD):
+        if spin:
+            n_k = Fm.shape[-1] // 2
+            f_re, f_im = leg.spin_pack_delta(
+                jnp.real(Fm[..., :n_k]), jnp.imag(Fm[..., :n_k]),
+                jnp.real(Fm[..., n_k:]), jnp.imag(Fm[..., n_k:]))
+            f = jnp.concatenate([f_re, f_im], axis=-1).astype(jnp.float32)
+        else:
+            f = jnp.concatenate([jnp.real(Fm), jnp.imag(Fm)],
+                                axis=-1).astype(jnp.float32)   # (M, R, 2K)
+        if fold_rings is not None:
+            nh = x.shape[0]
+            ns = R_full - nh
+            f_n = f[:, :nh]
+            f_s = jnp.zeros_like(f_n).at[:, :ns].set(f[:, nh:][:, ::-1])
+            fp = jnp.stack([f_n, f_s], axis=1)    # (Mr, 2, nh, 2K)
+        else:
+            fp = f[:, None]                       # (Mr, 1, R, 2K)
     return _kernel_anal(fp, tabs[1], x, pmm, pms, l_max=l_max, var=var,
                         bf16=bf16, lo=lo, lp_size=lp_size,
                         interpret=interpret, spin=spin, rot=rot[1])
@@ -1071,8 +1082,9 @@ def _linear_anal(maps, weights, m_vals, x, pmm, pms, tabs, kw):
     from repro.core.phase import _fac_rows
     fac = _fac_rows(m_vals, jnp.float32)
     bsc = 0.5 if kw["spin"] else 1.0
-    w = jnp.asarray(weights, jnp.float32)
-    maps_w = jnp.asarray(maps, jnp.float32) * w[:, None, None]
+    with jax.named_scope(PHASE):
+        w = jnp.asarray(weights, jnp.float32)
+        maps_w = jnp.asarray(maps, jnp.float32) * w[:, None, None]
 
     def fwd(res, mw):
         return _anal_chain(mw, m_vals, *res, **kw)

@@ -28,6 +28,7 @@ from repro.core.autodiff import linear_pair
 from repro.kernels import legendre_pallas as lk
 from repro.kernels import pack as kpack
 from repro.kernels import ref as kref
+from repro.tracing import LEGENDRE, scoped
 
 __all__ = ["synth", "anal", "delta_from_alm_auto", "alm_from_delta_auto",
            "delta_from_alm_spin_auto", "alm_from_delta_spin_auto",
@@ -374,6 +375,7 @@ def _anal_exec(dw, m_vals, x, pmm, pms, mp_vals, *, l_max, l1p, fold, var,
     return out[:, :L1, :]
 
 
+@scoped(LEGENDRE)
 def synth(a, m_vals, x, pmm, pms, *, l_max, fold=False, variant=None,
           mp_vals=None, lp_size=128, interpret=None, layout=None):
     """Kernel-backed synthesis with automatic padding.
@@ -412,6 +414,7 @@ def synth(a, m_vals, x, pmm, pms, *, l_max, fold=False, variant=None,
     return linear_pair(fwd, bwd, (m_vals, x, pmm, pms, mp_vals), a)
 
 
+@scoped(LEGENDRE)
 def anal(dw, m_vals, x, pmm, pms, *, l_max, l1p=None, fold=False,
          variant=None, mp_vals=None, lp_size=128, interpret=None,
          layout=None):

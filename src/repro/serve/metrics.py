@@ -4,7 +4,8 @@ Per-request timing is split the way a serving dashboard wants it:
 
 * ``queue``   -- submit() to the moment its batch starts executing;
 * ``compute`` -- the device wall time of the coalesced batch it rode in
-  (shared by every request of that batch);
+  (shared by every request of that batch; the upload and the download
+  excluded);
 * ``total``   -- submit() to future resolution.
 
 ``percentile`` reimplements numpy's default linear-interpolation estimator

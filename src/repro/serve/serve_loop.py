@@ -72,11 +72,16 @@ import time
 from collections import deque
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import transform
 from repro.serve.metrics import Calibration, LatencyWindow
 from repro.serve.pool import PlanPool, PlanSig
+from repro.tracing import (ENGINE_DOWNLOAD, ENGINE_EXECUTE, ENGINE_FORM,
+                           ENGINE_HANDOFF, ENGINE_IDLE, ENGINE_SCATTER,
+                           ENGINE_STACK, ENGINE_UPLOAD)
 
 __all__ = ["ShtEngine", "ShtRequest", "ShtFuture", "BackpressureError",
            "ShtTimeoutError", "InvalidStateError"]
@@ -100,7 +105,11 @@ class ShtFuture:
 
     ``result(timeout)`` blocks until the engine resolves it (re-raising
     the failure, if any); ``timing`` carries the per-request latency split
-    (``queue_s`` / ``compute_s`` / ``total_s``) once done.
+    once done: ``queue_s`` (submit to the start of the batch's
+    execution), ``form_s`` (the batch's host staging, upload included),
+    ``upload_s``, ``compute_s`` (execution alone), ``download_s``,
+    ``total_s`` (submit to the result handed over) and ``batch``, the
+    batch's sequence number that its profiler spans carry.
     """
 
     def __init__(self, rid: int):
@@ -203,12 +212,14 @@ class _Staged:
     thread hands to the execute thread through the double-buffer slot."""
 
     gkey: tuple                       # (PlanSig, direction, iters)
+    batch: int                        # sequence number of the batch
     plan: object
     good: list                        # _Pending entries riding this batch
     dev: object                       # stacked device payload (K = k_plan)
     k_total: int
     k_plan: int
-    form_s: float                     # host-side staging wall time
+    form_s: float                     # host staging wall time, upload in
+    upload_s: float                   # host to device, until ready
     predicted_s: Optional[float]      # admission model's batch estimate
 
 
@@ -383,6 +394,7 @@ class ShtEngine:
         self._n_failed = 0
         self._n_timed_out = 0
         self._n_batches = 0
+        self._batch_seq = 0                 # next batch sequence number
         self._sum_batch_requests = 0
         self._sum_batch_k = 0
         self._sum_batch_k_plan = 0
@@ -628,34 +640,40 @@ class ShtEngine:
 
     def _form_once(self):
         """Evict expired requests and stage one micro-batch (host side:
-        pop, plan lookup, validation, payload stacking + upload).
+        pop, plan lookup, validation, payload stacking + upload), each
+        step in its profiler span (`repro.tracing`).
         Returns ``(staged_or_None, n_retired_during_formation)``."""
-        now = time.perf_counter()
-        with self._lock:
-            expired = self._evict_expired_locked(now)
-            gkey, batch = self._pop_batch_locked()
-        n = 0
-        for p in expired:
-            waited = now - p.t_submit
-            self._retire(p, exc=ShtTimeoutError(
-                f"request {p.future.rid} evicted after {waited:.3f}s in "
-                f"queue (timeout)"), kind="timeout",
-                timing={"queue_s": waited, "compute_s": 0.0,
-                        "total_s": waited})
-            n += 1
-        if not batch:
-            return None, n
-        staged, n_failed = self._stage(gkey, batch)
-        return staged, n + n_failed
+        with self._lock:                 # the number the batch will carry
+            seq = self._batch_seq
+            self._batch_seq += 1
+        with jax.profiler.TraceAnnotation(ENGINE_FORM, batch=seq):
+            now = time.perf_counter()
+            with self._lock:
+                expired = self._evict_expired_locked(now)
+                gkey, batch = self._pop_batch_locked()
+                if not batch and self._batch_seq == seq + 1:
+                    self._batch_seq = seq    # nothing formed: not a batch
+            n = 0
+            for p in expired:
+                waited = now - p.t_submit
+                self._retire(p, exc=ShtTimeoutError(
+                    f"request {p.future.rid} evicted after {waited:.3f}s "
+                    f"in queue (timeout)"), kind="timeout",
+                    timing={"queue_s": waited, "compute_s": 0.0,
+                            "total_s": waited})
+                n += 1
+            if not batch:
+                return None, n
+            t_form = time.perf_counter()
+            checked, n_failed = self._check(seq, gkey, batch, t_form)
+        if checked is None:
+            return None, n + n_failed
+        return self._stack_upload(seq, gkey, t_form, *checked), n + n_failed
 
-    def _stage(self, gkey, batch: list[_Pending]):
-        """Host-side half of a micro-batch: resolve the pooled plan,
-        validate each payload against it, stack along K and upload.
-        Returns ``(staged_or_None, n_retired)``."""
-        import jax.numpy as jnp
-
+    def _check(self, seq: int, gkey, batch: list[_Pending], t_form: float):
+        """Resolve the pooled plan and validate each payload against it.
+        Returns ``((plan, good, k_total, k_plan) or None, n_retired)``."""
         sig, direction, iters = gkey
-        t_form = time.perf_counter()
         k_claim = sum(p.k for p in batch)
         k_plan = self._k_bucket(k_claim)
 
@@ -664,14 +682,15 @@ class ShtEngine:
         except Exception as e:
             for p in batch:
                 self._retire(p, exc=e, kind="failed",
-                             timing={"queue_s": t_form - p.t_submit})
-            self._log_batch(sig, direction, batch, k_claim, k_plan, ok=False)
+                             timing={"queue_s": t_form - p.t_submit,
+                                     "batch": seq})
+            self._log_batch(seq, sig, direction, batch, k_claim, k_plan,
+                            ok=False)
             return None, len(batch)
 
         # per-request shape validation against the *resolved* plan: a
         # payload that lied about its signature fails alone, not its batch
-        base = (plan._alm_shape if direction == "alm2map"
-                else plan._maps_shape)[:-1]
+        base = self._payload_base(plan, direction)
         good, k_total = [], 0
         for p in batch:
             if p.payload.shape[:-1] != base:
@@ -679,31 +698,50 @@ class ShtEngine:
                     f"payload shape {p.payload.shape} does not match plan "
                     f"{sig.label()} (expected {base} + (K,))"),
                     kind="failed",
-                    timing={"queue_s": t_form - p.t_submit})
+                    timing={"queue_s": t_form - p.t_submit, "batch": seq})
             else:
                 good.append(p)
                 k_total += p.k
         if not good:
-            self._log_batch(sig, direction, batch, 0, k_plan, ok=False)
+            self._log_batch(seq, sig, direction, batch, 0, k_plan, ok=False)
             return None, len(batch)
+        return (plan, good, k_total, k_plan), len(batch) - len(good)
 
-        cdtype = np.complex128 if sig.dtype == "float64" else np.complex64
-        rdtype = np.dtype(sig.dtype)
-        want = cdtype if direction == "alm2map" else rdtype
-        parts = [np.ascontiguousarray(p.payload, dtype=want) for p in good]
-        if k_total < plan.K:                       # dense K bucket: zero-pad
-            parts.append(np.zeros(base + (plan.K - k_total,), dtype=want))
-        dev = jnp.asarray(np.concatenate(parts, axis=-1))
+    @staticmethod
+    def _payload_base(plan, direction: str) -> tuple:
+        return (plan._alm_shape if direction == "alm2map"
+                else plan._maps_shape)[:-1]
+
+    def _stack_upload(self, seq: int, gkey, t_form: float, plan, good,
+                      k_total: int, k_plan: int) -> _Staged:
+        """Stack the payloads along K (zero-padded to the plan's K) and
+        upload them; the upload is waited for here, in the formation
+        thread, since the batch cannot execute before it ends."""
+        sig, direction, _ = gkey
+        with jax.profiler.TraceAnnotation(ENGINE_STACK, batch=seq):
+            base = self._payload_base(plan, direction)
+            cdtype = np.complex128 if sig.dtype == "float64" \
+                else np.complex64
+            want = cdtype if direction == "alm2map" else np.dtype(sig.dtype)
+            parts = [np.ascontiguousarray(p.payload, dtype=want)
+                     for p in good]
+            if k_total < plan.K:                   # dense K bucket: zero-pad
+                parts.append(np.zeros(base + (plan.K - k_total,),
+                                      dtype=want))
+            host = np.concatenate(parts, axis=-1)
+        with jax.profiler.TraceAnnotation(ENGINE_UPLOAD, batch=seq):
+            t_up = time.perf_counter()
+            dev = jax.block_until_ready(jnp.asarray(host))
+            t_ready = time.perf_counter()
 
         adm = self._admission.get(gkey)
         predicted = None
         if adm is not None:
             predicted = adm["predicted_s_by_k"].get(k_plan)
-        staged = _Staged(gkey=gkey, plan=plan, good=good, dev=dev,
-                         k_total=k_total, k_plan=k_plan,
-                         form_s=time.perf_counter() - t_form,
-                         predicted_s=predicted)
-        return staged, len(batch) - len(good)
+        return _Staged(gkey=gkey, batch=seq, plan=plan, good=good, dev=dev,
+                       k_total=k_total, k_plan=k_plan,
+                       form_s=t_ready - t_form, upload_s=t_ready - t_up,
+                       predicted_s=predicted)
 
     # -- execution ------------------------------------------------------------
 
@@ -735,15 +773,16 @@ class ShtEngine:
             self._t_last_done = time.perf_counter()
             self._idle.notify_all()
 
-    def _log_batch(self, sig: PlanSig, direction: str, batch, k_total: int,
-                   k_plan: int, ok: bool) -> None:
+    def _log_batch(self, seq: int, sig: PlanSig, direction: str, batch,
+                   k_total: int, k_plan: int, ok: bool) -> None:
         with self._lock:
             self._n_batches += 1
             self._sum_batch_requests += len(batch)
             self._sum_batch_k += k_total
             self._sum_batch_k_plan += k_plan
             self.batch_log.append({
-                "signature": sig.label(), "direction": direction,
+                "batch": seq, "signature": sig.label(),
+                "direction": direction,
                 "rids": [p.future.rid for p in batch],
                 "n_requests": len(batch), "k_total": k_total,
                 "k_plan": k_plan, "ok": ok,
@@ -753,49 +792,59 @@ class ShtEngine:
                                    - self._batch_log_cap]
 
     def _execute_staged(self, staged: _Staged) -> int:
-        """Device half of a micro-batch: run the transform, scatter the
-        K slices back to their futures.  Returns requests retired."""
-        import jax
-
+        """Device half of a micro-batch: run the transform, download the
+        result, scatter the K slices back to their futures.  Returns
+        requests retired."""
         sig, direction, iters = staged.gkey
-        plan, good = staged.plan, staged.good
-        t_start = time.perf_counter()
-        try:
-            if direction == "alm2map":
-                out = plan.alm2map(staged.dev)
-            else:
-                out = plan.map2alm(staged.dev, iters=iters)
-            jax.block_until_ready(out)
-        except Exception as e:
+        plan, good, seq = staged.plan, staged.good, staged.batch
+        with jax.profiler.TraceAnnotation(ENGINE_EXECUTE, batch=seq):
+            t_start = time.perf_counter()
+            try:
+                if direction == "alm2map":
+                    out = plan.alm2map(staged.dev)
+                else:
+                    out = plan.map2alm(staged.dev, iters=iters)
+                jax.block_until_ready(out)
+                error = None
+            except Exception as e:
+                error = e
+            t_done = time.perf_counter()
+        if error is not None:
             for p in good:
-                self._retire(p, exc=e, kind="failed",
-                             timing={"queue_s": t_start - p.t_submit})
-            self._log_batch(sig, direction, good, staged.k_total,
+                self._retire(p, exc=error, kind="failed",
+                             timing={"queue_s": t_start - p.t_submit,
+                                     "batch": seq})
+            self._log_batch(seq, sig, direction, good, staged.k_total,
                             staged.k_plan, ok=False)
             return len(good)
-        t_done = time.perf_counter()
         compute_s = t_done - t_start
         if staged.predicted_s is not None:
             with self._lock:
                 self._calib.record(staged.predicted_s, compute_s)
 
-        out = np.asarray(out)
-        off = 0
-        for p in good:
-            res = out[..., off:off + p.k]
-            off += p.k
-            if p.squeeze:
-                res = res[..., 0]
-            self._retire(p, result=res, kind="ok", timing={
-                "queue_s": t_start - p.t_submit,
-                "form_s": staged.form_s,
-                "compute_s": compute_s,
-                "total_s": t_done - p.t_submit,
-                "k_plan": staged.k_plan,
-                "coalesced_with": len(good) - 1,
-            })
-        self._log_batch(sig, direction, good, staged.k_total, staged.k_plan,
-                        ok=True)
+        with jax.profiler.TraceAnnotation(ENGINE_DOWNLOAD, batch=seq):
+            out = np.asarray(out)
+            t_host = time.perf_counter()
+        with jax.profiler.TraceAnnotation(ENGINE_SCATTER, batch=seq):
+            off = 0
+            for p in good:
+                res = out[..., off:off + p.k]
+                off += p.k
+                if p.squeeze:
+                    res = res[..., 0]
+                self._retire(p, result=res, kind="ok", timing={
+                    "queue_s": t_start - p.t_submit,
+                    "form_s": staged.form_s,
+                    "upload_s": staged.upload_s,
+                    "compute_s": compute_s,
+                    "download_s": t_host - t_done,
+                    "total_s": time.perf_counter() - p.t_submit,
+                    "k_plan": staged.k_plan,
+                    "coalesced_with": len(good) - 1,
+                    "batch": seq,
+                })
+            self._log_batch(seq, sig, direction, good, staged.k_total,
+                            staged.k_plan, ok=True)
         return len(good)
 
     # -- synchronous serving ---------------------------------------------------
@@ -864,12 +913,19 @@ class ShtEngine:
     def _formation_loop(self) -> None:
         while True:
             with self._work:
-                while not self._stop and self._n_queued == 0:
-                    self._work.wait(timeout=0.1)
+                if not self._stop and self._n_queued == 0:
+                    with jax.profiler.TraceAnnotation(ENGINE_IDLE):
+                        while not self._stop and self._n_queued == 0:
+                            self._work.wait(timeout=0.1)
                 if self._stop:
                     return
             staged, _ = self._form_once()
-            if staged is not None and not self._slot.put(staged):
+            if staged is None:
+                continue
+            with jax.profiler.TraceAnnotation(ENGINE_HANDOFF,
+                                              batch=staged.batch):
+                handed = self._slot.put(staged)
+            if not handed:
                 # slot closed mid-handoff (stop raced us): never strand
                 # an in-flight batch -- run it here instead
                 self._execute_staged(staged)
