@@ -45,6 +45,9 @@ __all__ = [
     "scale_bits_for",
     "pmm_scaled",
     "recurrence_step",
+    "row_step_bytes",
+    "row_blocks",
+    "loop_blocks",
     "delta_from_alm",
     "alm_from_delta",
     "delta_from_alm_folded",
@@ -227,43 +230,165 @@ def _prep(m_vals, grid_x, grid_sin, log_mu_all, dtype, scale_bits):
     return m, x, pmm_mant, pmm_scale
 
 
-@functools.partial(jax.jit, static_argnames=("l_max", "scale_bits", "dtype_name"))
+# ---------------------------------------------------------------------------
+# Row blocks.  Every l step streams the (m, ring) carries through memory, and
+# at step l the rows with m > l only write zeros: summed over the loop that
+# is half the rows of an m_max = l_max transform.  The jnp loops therefore
+# run contiguous blocks of m rows, each from its first non-zero l.
+# ---------------------------------------------------------------------------
+
+#: Least bytes one l step of a row block should stream (see `row_blocks`).
+#: Each loop iteration costs a fixed time on the device while its bytes
+#: shrink with the block, so the m rows are split only as far as every
+#: block's step still streams this much.  From a chip sweep of the block
+#: count on a TPU v5e (PERF.md §5), at l_max 4096, K=4: 1141 MB a step at
+#: C = 1, 7.95 s a call; 287 MB at C = 4, 3.46 s; 74-96 MB at C = 12-16,
+#: 1.33-1.39 s, where a block's working set stays in on-chip memory and
+#: an iteration of ~15 device ops takes 30-40 us.  Blocks that fine run
+#: ~300 000 device ops a second, more than a profiler trace of a 30 s
+#: window keeps (the benchmark's trace reader reads 2 000 000), so the
+#: per-stage device times could no longer be read; this floor keeps each
+#: op above ~20 us, with C = 4 at l_max 4096, K=4.
+BLOCK_STEP_BYTES = 256 << 20
+
+#: Arrays of R values per map that one step's accumulate streams for each
+#: m row, by (direction, fold): the analysis reads its weighted Delta
+#: (re, im; even and odd when folded), the synthesis reads and writes its
+#: accumulators (re, im; even and odd when folded).
+_ACCUMULATE_ARRAYS = {("anal", False): 2, ("anal", True): 4,
+                      ("synth", False): 4, ("synth", True): 8}
+
+
+def row_step_bytes(direction: str, *, fold: bool, n_rings: int, K: int,
+                   dtype) -> int:
+    """Bytes one l step of the jnp loop streams per m row over ``n_rings``
+    rings (the northern rings when folded): the recurrence reads and
+    writes its three carries and reads both seeds, the accumulate reads
+    the value row and its operands (``_ACCUMULATE_ARRAYS``) for K maps."""
+    arrays = 9 + _ACCUMULATE_ARRAYS[direction, bool(fold)] * int(K)
+    return arrays * int(n_rings) * jnp.dtype(dtype).itemsize
+
+
+def row_blocks(m_vals, row_bytes: int) -> int:
+    """Rows per block of the jnp loop, or 0 for one loop over every row.
+
+    The concrete m rows are split into C contiguous blocks of a multiple
+    of 8 rows, C the largest count whose block step still streams
+    :data:`BLOCK_STEP_BYTES` (``row_bytes`` per row, from
+    :func:`row_step_bytes`).  Traced ``m_vals`` (the distributed stage 1
+    inside shard_map) are not known at trace time: one full loop.
+    """
+    m = _concrete(m_vals)
+    if m is None:
+        return 0
+    M = m.shape[0]
+    c = int(M * row_bytes // BLOCK_STEP_BYTES)
+    if c < 2:
+        return 0
+    rows = -(-M // c)
+    rows = -(-rows // 8) * 8
+    return rows if rows < M else 0
+
+
+def loop_blocks(m_vals, *, l_max: int, row_bytes: int) -> dict:
+    """What the jnp loop runs for these rows: its block count, rows per
+    block, and the row-steps it runs as a share of the full
+    ``M x (l_max + 1)`` rectangle (1.0 for one full loop; about
+    (C + 1) / 2C for C blocks of the rows m = 0..l_max)."""
+    m = np.asarray(m_vals).reshape(-1)
+    M, L = m.shape[0], l_max + 1
+    rows = row_blocks(m, row_bytes)
+    if not rows:
+        return {"blocks": 1, "rows_per_block": M, "row_step_share": 1.0}
+    C = -(-M // rows)
+    mb = np.concatenate([m, np.full(C * rows - M, -1)]).reshape(C, rows)
+    l0 = np.where(mb >= 0, mb, L).min(axis=1)
+    return {"blocks": C, "rows_per_block": rows,
+            "row_step_share": float(rows * (L - l0).sum() / (M * L))}
+
+
+def _by_row_blocks(loop, block_rows: int, l_max: int, m, *rows):
+    """``loop(l_lo, m, *rows)`` over all m rows, or block by block.
+
+    ``loop`` runs the recurrence from ``l_lo`` to ``l_max`` over the rows
+    it is given (axis 0 of ``m`` and of each of ``rows``) and returns
+    arrays with those rows on axis 0.  With ``block_rows`` 0 it runs once,
+    from l = 0.  Otherwise the rows are padded with m = -1 rows (zero
+    seeds: they stay zero) to C blocks of ``block_rows``, and one compiled
+    loop is mapped over the blocks, each from l0 = its least m.  Before l0
+    the full loop leaves every carry of the block at zero and writes only
+    zeros, so each row runs the same steps in the same l order.
+    """
+    if not block_rows:
+        return loop(0, m, *rows)
+    M = m.shape[0]
+    C = -(-M // block_rows)
+
+    def split(a, fill):
+        pad = [(0, C * block_rows - M)] + [(0, 0)] * (a.ndim - 1)
+        a = jnp.pad(a, pad, constant_values=fill)
+        return a.reshape((C, block_rows) + a.shape[1:])
+
+    m_b = split(m, -1)
+    l0 = jnp.min(jnp.where(m_b >= 0, m_b, l_max + 1), axis=(1, 2))
+    out = jax.lax.map(lambda blk: loop(*blk),
+                      (l0, m_b, *(split(a, 0) for a in rows)))
+    return tuple(o.reshape((C * block_rows,) + o.shape[2:])[:M] for o in out)
+
+
+def _blocks_for(m_vals, direction, *, fold, n_rings, K, dtype) -> int:
+    return row_blocks(m_vals, row_step_bytes(direction, fold=fold,
+                                             n_rings=n_rings, K=K,
+                                             dtype=dtype))
+
+
+_IMPL_STATICS = ("l_max", "scale_bits", "dtype_name", "block_rows")
+
+
+@functools.partial(jax.jit, static_argnames=_IMPL_STATICS)
 def _delta_from_alm_impl(a_re, a_im, m, x, pmm_mant, pmm_scale, *, l_max,
-                         scale_bits, dtype_name):
+                         scale_bits, dtype_name, block_rows=0):
     dtype = jnp.dtype(dtype_name)
-    M, R = m.shape[0], x.shape[1]
-    K = a_re.shape[-1]
-    # K-major rows throughout the loop (see `_scan_to_mlk`): the a_lm
-    # stream as (L, K*M), the accumulators as (K*M, R).
-    rows_re = jnp.transpose(a_re, (1, 2, 0)).reshape(l_max + 1, K * M)
-    rows_im = jnp.transpose(a_im, (1, 2, 0)).reshape(l_max + 1, K * M)
-    carry0 = (
-        jnp.zeros((M, R), dtype),          # P_{l-2} mantissa
-        jnp.zeros((M, R), dtype),          # P_{l-1} mantissa
-        jnp.zeros((M, R), jnp.int32),      # scale
-        jnp.zeros((K * M, R), dtype),      # d_re accumulator
-        jnp.zeros((K * M, R), dtype),      # d_im accumulator
-    )
+    R, K = x.shape[1], a_re.shape[-1]
 
-    def body(l, carry):
-        mp, mc, sc, dre, dim = carry
-        with jax.named_scope(RECURRENCE):
-            mp, mc, sc, val = recurrence_step(
-                l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
-                scale_bits=scale_bits, dtype=dtype)
-        # Delta_m(r) += a_{l,m} * P_{l,m}(r)   (paper eq. 12)
-        with jax.named_scope(ACCUMULATE):
-            are = jax.lax.dynamic_index_in_dim(rows_re, l, axis=0,
-                                               keepdims=False)
-            aim = jax.lax.dynamic_index_in_dim(rows_im, l, axis=0,
-                                               keepdims=False)
-            dre = dre + (are.reshape(K, M)[:, :, None] * val).reshape(K * M, R)
-            dim = dim + (aim.reshape(K, M)[:, :, None] * val).reshape(K * M, R)
-        return mp, mc, sc, dre, dim
+    def loop(lo, m, pmm_mant, pmm_scale, a_re, a_im):
+        M = m.shape[0]
+        # K-major rows throughout the loop (see `_scan_to_mlk`): the a_lm
+        # stream as (L, K*M), the accumulators as (K*M, R).
+        rows_re = jnp.transpose(a_re, (1, 2, 0)).reshape(l_max + 1, K * M)
+        rows_im = jnp.transpose(a_im, (1, 2, 0)).reshape(l_max + 1, K * M)
+        carry0 = (
+            jnp.zeros((M, R), dtype),          # P_{l-2} mantissa
+            jnp.zeros((M, R), dtype),          # P_{l-1} mantissa
+            jnp.zeros((M, R), jnp.int32),      # scale
+            jnp.zeros((K * M, R), dtype),      # d_re accumulator
+            jnp.zeros((K * M, R), dtype),      # d_im accumulator
+        )
 
-    _, _, _, d_re, d_im = jax.lax.fori_loop(0, l_max + 1, body, carry0)
-    unrow = lambda d: jnp.transpose(d.reshape(K, M, R), (1, 2, 0))
-    return unrow(d_re), unrow(d_im)
+        def body(l, carry):
+            mp, mc, sc, dre, dim = carry
+            with jax.named_scope(RECURRENCE):
+                mp, mc, sc, val = recurrence_step(
+                    l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
+                    scale_bits=scale_bits, dtype=dtype)
+            # Delta_m(r) += a_{l,m} * P_{l,m}(r)   (paper eq. 12)
+            with jax.named_scope(ACCUMULATE):
+                are = jax.lax.dynamic_index_in_dim(rows_re, l, axis=0,
+                                                   keepdims=False)
+                aim = jax.lax.dynamic_index_in_dim(rows_im, l, axis=0,
+                                                   keepdims=False)
+                dre = dre + (are.reshape(K, M)[:, :, None]
+                             * val).reshape(K * M, R)
+                dim = dim + (aim.reshape(K, M)[:, :, None]
+                             * val).reshape(K * M, R)
+            return mp, mc, sc, dre, dim
+
+        _, _, _, d_re, d_im = jax.lax.fori_loop(lo, l_max + 1, body, carry0)
+        unrow = lambda d: jnp.transpose(d.reshape(K, M, R), (1, 2, 0))
+        return unrow(d_re), unrow(d_im)
+
+    return _by_row_blocks(loop, block_rows, l_max, m, pmm_mant, pmm_scale,
+                          a_re, a_im)
 
 
 @scoped(LEGENDRE)
@@ -274,6 +399,7 @@ def delta_from_alm(a_re, a_im, m_vals, grid_x, grid_sin, log_mu_all, *,
     a_re/a_im: (M, l_max+1, K) with rows l < m zero-padded.
     Returns (d_re, d_im): (M, R, K).  This is paper Algorithm 2 STEP 2 /
     Algorithm 3 STEP 2, vectorised over (m, ring) with the l loop sequential.
+    Concrete rows run in blocks from their first non-zero l (`row_blocks`).
 
     Differentiable both ways via the adjoint identity (VJP = analysis with
     unit weights); ``m_vals`` may be traced (the distributed stage-1 path).
@@ -287,27 +413,27 @@ def delta_from_alm(a_re, a_im, m_vals, grid_x, grid_sin, log_mu_all, *,
     a_im = jnp.asarray(a_im, dtype)
     assert a_re.shape[1] == l_max + 1, (a_re.shape, l_max)
     R = gx.shape[0]
+    kw = dict(l_max=l_max, scale_bits=sb, dtype_name=dtype.name,
+              block_rows=_blocks_for(m_vals, "synth", fold=False, n_rings=R,
+                                     K=a_re.shape[-1], dtype=dtype))
 
     def fwd(pre, ops):
         ar, ai = ops
         m, x, pm, ps = pre
-        return _delta_from_alm_impl(ar, ai, m, x, pm, ps, l_max=l_max,
-                                    scale_bits=sb, dtype_name=dtype.name)
+        return _delta_from_alm_impl(ar, ai, m, x, pm, ps, **kw)
 
     def bwd(pre, cts):
         gd_re, gd_im = cts
         m, x, pm, ps = pre
         ones = jnp.ones((R,), dtype)
-        return _alm_from_delta_impl(gd_re, gd_im, m, x, pm, ps, ones,
-                                    l_max=l_max, scale_bits=sb,
-                                    dtype_name=dtype.name)
+        return _alm_from_delta_impl(gd_re, gd_im, m, x, pm, ps, ones, **kw)
 
     return linear_pair(fwd, bwd, _prep(m_vals, gx, gs, lm_all, dtype, sb),
                        (a_re, a_im))
 
 
 def _scan_to_mlk(a, K: int):
-    """Per-l scan outputs stacked as (L, K*M) -> (M, L, K).  Each step
+    """Per-l loop outputs stacked as (L, K*M) -> (M, L, K).  Each step
     emits its (K, M) row block flattened: a stack with K as its minor
     dimension is padded to 128 lanes on a TPU (32x at K=4: 16 GB at
     l_max=4096), and a rank-3 (L, K, M) stack still gets that layout from
@@ -315,35 +441,52 @@ def _scan_to_mlk(a, K: int):
     return jnp.transpose(a.reshape(a.shape[0], K, -1), (2, 0, 1))
 
 
-@functools.partial(jax.jit, static_argnames=("l_max", "scale_bits", "dtype_name"))
+def _stack_loop(lo, l_max, M, K, carry0, step, dtype):
+    """``fori_loop`` from ``lo`` to ``l_max`` of ``step(l, carry) ->
+    (carry, (a_re_l, a_im_l))``, the per-l (K*M,) outputs stacked as
+    (L, K*M) (rows before ``lo`` stay zero) -> two (M, L, K) arrays."""
+    def body(l, c):
+        carry, (a_re, a_im) = c
+        carry, (a_re_l, a_im_l) = step(l, carry)
+        upd = lambda a, v: jax.lax.dynamic_update_index_in_dim(a, v, l, 0)
+        return carry, (upd(a_re, a_re_l), upd(a_im, a_im_l))
+
+    out0 = (jnp.zeros((l_max + 1, K * M), dtype),) * 2
+    _, (a_re, a_im) = jax.lax.fori_loop(lo, l_max + 1, body, (carry0, out0))
+    return _scan_to_mlk(a_re, K), _scan_to_mlk(a_im, K)
+
+
+@functools.partial(jax.jit, static_argnames=_IMPL_STATICS)
 def _alm_from_delta_impl(d_re, d_im, m, x, pmm_mant, pmm_scale, w, *, l_max,
-                         scale_bits, dtype_name):
+                         scale_bits, dtype_name, block_rows=0):
     dtype = jnp.dtype(dtype_name)
-    M, R, K = m.shape[0], x.shape[1], d_re.shape[-1]
+    R, K = x.shape[1], d_re.shape[-1]
     dw_re = d_re * w[None, :, None]
     dw_im = d_im * w[None, :, None]
-    carry0 = (
-        jnp.zeros((M, R), dtype),
-        jnp.zeros((M, R), dtype),
-        jnp.zeros((M, R), jnp.int32),
-    )
 
-    def step(carry, l):
-        mp, mc, sc = carry
-        with jax.named_scope(RECURRENCE):
-            mp, mc, sc, val = recurrence_step(
-                l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
-                scale_bits=scale_bits, dtype=dtype)
-        # a_{l,m} = sum_r w_r Delta^S_m(r) P_lm(r)   (paper eq. 13)
-        with jax.named_scope(ACCUMULATE):
-            a_re_l = jnp.einsum("mr,mrk->km", val, dw_re,
-                                precision=_HIGHEST).reshape(-1)
-            a_im_l = jnp.einsum("mr,mrk->km", val, dw_im,
-                                precision=_HIGHEST).reshape(-1)
-        return (mp, mc, sc), (a_re_l, a_im_l)
+    def loop(lo, m, pmm_mant, pmm_scale, dw_re, dw_im):
+        M = m.shape[0]
 
-    _, (a_re, a_im) = jax.lax.scan(step, carry0, jnp.arange(l_max + 1))
-    return _scan_to_mlk(a_re, K), _scan_to_mlk(a_im, K)
+        def step(l, carry):
+            mp, mc, sc = carry
+            with jax.named_scope(RECURRENCE):
+                mp, mc, sc, val = recurrence_step(
+                    l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
+                    scale_bits=scale_bits, dtype=dtype)
+            # a_{l,m} = sum_r w_r Delta^S_m(r) P_lm(r)   (paper eq. 13)
+            with jax.named_scope(ACCUMULATE):
+                a_re_l = jnp.einsum("mr,mrk->km", val, dw_re,
+                                    precision=_HIGHEST).reshape(-1)
+                a_im_l = jnp.einsum("mr,mrk->km", val, dw_im,
+                                    precision=_HIGHEST).reshape(-1)
+            return (mp, mc, sc), (a_re_l, a_im_l)
+
+        carry0 = (jnp.zeros((M, R), dtype), jnp.zeros((M, R), dtype),
+                  jnp.zeros((M, R), jnp.int32))
+        return _stack_loop(lo, l_max, M, K, carry0, step, dtype)
+
+    return _by_row_blocks(loop, block_rows, l_max, m, pmm_mant, pmm_scale,
+                          dw_re, dw_im)
 
 
 @scoped(LEGENDRE)
@@ -352,7 +495,8 @@ def alm_from_delta(d_re, d_im, m_vals, grid_x, grid_sin, weights, log_mu_all,
     """Analysis inner step: a_lm = sum_r w_r Delta^S_m(r) P_lm(cos theta_r).
 
     d_re/d_im: (M, R, K).  Returns (a_re, a_im): (M, l_max+1, K) with rows
-    l < m exactly zero.  Paper Algorithm 1 STEP 3.
+    l < m exactly zero.  Paper Algorithm 1 STEP 3.  Concrete rows run in
+    blocks from their first non-zero l (`row_blocks`).
 
     Differentiable both ways via the adjoint identity (VJP = weights times
     synthesis of the cotangent).
@@ -364,22 +508,22 @@ def alm_from_delta(d_re, d_im, m_vals, grid_x, grid_sin, weights, log_mu_all,
     lm_all = np.asarray(log_mu_all)
     d_re = jnp.asarray(d_re, dtype)
     d_im = jnp.asarray(d_im, dtype)
+    kw = dict(l_max=l_max, scale_bits=sb, dtype_name=dtype.name,
+              block_rows=_blocks_for(m_vals, "anal", fold=False,
+                                     n_rings=gx.shape[0], K=d_re.shape[-1],
+                                     dtype=dtype))
 
     def fwd(res, ops):
         pre, w = res
         dr, di = ops
         m, x, pm, ps = pre
-        return _alm_from_delta_impl(dr, di, m, x, pm, ps, w,
-                                    l_max=l_max, scale_bits=sb,
-                                    dtype_name=dtype.name)
+        return _alm_from_delta_impl(dr, di, m, x, pm, ps, w, **kw)
 
     def bwd(res, cts):
         pre, w = res
         ga_re, ga_im = cts
         m, x, pm, ps = pre
-        gd_re, gd_im = _delta_from_alm_impl(ga_re, ga_im, m, x, pm, ps,
-                                            l_max=l_max, scale_bits=sb,
-                                            dtype_name=dtype.name)
+        gd_re, gd_im = _delta_from_alm_impl(ga_re, ga_im, m, x, pm, ps, **kw)
         return gd_re * w[None, :, None], gd_im * w[None, :, None]
 
     return linear_pair(fwd, bwd, (_prep(m_vals, gx, gs, lm_all, dtype, sb),
@@ -399,39 +543,44 @@ def alm_from_delta(d_re, d_im, m_vals, grid_x, grid_sin, weights, log_mu_all,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("l_max", "scale_bits", "dtype_name"))
+@functools.partial(jax.jit, static_argnames=_IMPL_STATICS)
 def _delta_from_alm_folded_impl(a_re, a_im, m, x, pmm_mant, pmm_scale, *,
-                                l_max, scale_bits, dtype_name):
+                                l_max, scale_bits, dtype_name, block_rows=0):
     dtype = jnp.dtype(dtype_name)
-    M, R = m.shape[0], x.shape[1]      # R = number of *northern* rings
+    R = x.shape[1]                     # R = number of *northern* rings
     K = a_re.shape[-1]
     zeros = lambda *s: jnp.zeros(s, dtype)
-    carry0 = (zeros(M, R), zeros(M, R), jnp.zeros((M, R), jnp.int32),
-              zeros(M, R, K), zeros(M, R, K),   # even re/im
-              zeros(M, R, K), zeros(M, R, K))   # odd re/im
 
-    def body(l, carry):
-        mp, mc, sc, ere, eim, ore_, oim = carry
-        with jax.named_scope(RECURRENCE):
-            mp, mc, sc, val = recurrence_step(
-                l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
-                scale_bits=scale_bits, dtype=dtype)
-        with jax.named_scope(ACCUMULATE):
-            are = jax.lax.dynamic_index_in_dim(a_re, l, axis=1,
-                                               keepdims=False)
-            aim = jax.lax.dynamic_index_in_dim(a_im, l, axis=1,
-                                               keepdims=False)
-            cre = val[..., None] * are[:, None, :]
-            cim = val[..., None] * aim[:, None, :]
-            even = (((l + m) % 2) == 0)[..., None]     # (M, 1, 1)
-            ere = ere + jnp.where(even, cre, 0.0)
-            eim = eim + jnp.where(even, cim, 0.0)
-            ore_ = ore_ + jnp.where(even, 0.0, cre)
-            oim = oim + jnp.where(even, 0.0, cim)
-        return mp, mc, sc, ere, eim, ore_, oim
+    def loop(lo, m, pmm_mant, pmm_scale, a_re, a_im):
+        M = m.shape[0]
+        carry0 = (zeros(M, R), zeros(M, R), jnp.zeros((M, R), jnp.int32),
+                  zeros(M, R, K), zeros(M, R, K),   # even re/im
+                  zeros(M, R, K), zeros(M, R, K))   # odd re/im
 
-    _, _, _, ere, eim, ore_, oim = jax.lax.fori_loop(0, l_max + 1, body, carry0)
-    return ere, eim, ore_, oim
+        def body(l, carry):
+            mp, mc, sc, ere, eim, ore_, oim = carry
+            with jax.named_scope(RECURRENCE):
+                mp, mc, sc, val = recurrence_step(
+                    l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
+                    scale_bits=scale_bits, dtype=dtype)
+            with jax.named_scope(ACCUMULATE):
+                are = jax.lax.dynamic_index_in_dim(a_re, l, axis=1,
+                                                   keepdims=False)
+                aim = jax.lax.dynamic_index_in_dim(a_im, l, axis=1,
+                                                   keepdims=False)
+                cre = val[..., None] * are[:, None, :]
+                cim = val[..., None] * aim[:, None, :]
+                even = (((l + m) % 2) == 0)[..., None]     # (M, 1, 1)
+                ere = ere + jnp.where(even, cre, 0.0)
+                eim = eim + jnp.where(even, cim, 0.0)
+                ore_ = ore_ + jnp.where(even, 0.0, cre)
+                oim = oim + jnp.where(even, 0.0, cim)
+            return mp, mc, sc, ere, eim, ore_, oim
+
+        return jax.lax.fori_loop(lo, l_max + 1, body, carry0)[3:]
+
+    return _by_row_blocks(loop, block_rows, l_max, m, pmm_mant, pmm_scale,
+                          a_re, a_im)
 
 
 @scoped(LEGENDRE)
@@ -440,7 +589,8 @@ def delta_from_alm_folded(a_re, a_im, m_vals, north_x, north_sin, log_mu_all,
     """Folded synthesis: returns even/odd partials over the northern rings.
 
     (d_even_re, d_even_im, d_odd_re, d_odd_im), each (M, R_north, K).
-    North ring r: even + odd; its mirror: even - odd.
+    North ring r: even + odd; its mirror: even - odd.  Concrete rows run in
+    blocks from their first non-zero l (`row_blocks`).
 
     Differentiable both ways: the VJP is the folded analysis of the even/odd
     cotangent partials (the parity split is its own transpose).
@@ -453,53 +603,58 @@ def delta_from_alm_folded(a_re, a_im, m_vals, north_x, north_sin, log_mu_all,
     a_re = jnp.asarray(a_re, dtype)
     a_im = jnp.asarray(a_im, dtype)
     assert a_re.shape[1] == l_max + 1, (a_re.shape, l_max)
+    kw = dict(l_max=l_max, scale_bits=sb, dtype_name=dtype.name,
+              block_rows=_blocks_for(m_vals, "synth", fold=True,
+                                     n_rings=gx.shape[0], K=a_re.shape[-1],
+                                     dtype=dtype))
 
     def fwd(pre, ops):
         ar, ai = ops
         m, x, pm, ps = pre
-        return _delta_from_alm_folded_impl(ar, ai, m, x, pm, ps,
-                                           l_max=l_max, scale_bits=sb,
-                                           dtype_name=dtype.name)
+        return _delta_from_alm_folded_impl(ar, ai, m, x, pm, ps, **kw)
 
     def bwd(pre, cts):
         ge_re, ge_im, go_re, go_im = cts
         m, x, pm, ps = pre
         return _alm_from_delta_folded_impl(ge_re, ge_im, go_re, go_im, m, x,
-                                           pm, ps, l_max=l_max,
-                                           scale_bits=sb,
-                                           dtype_name=dtype.name)
+                                           pm, ps, **kw)
 
     return linear_pair(fwd, bwd, _prep(m_vals, gx, gs, lm_all, dtype, sb),
                        (a_re, a_im))
 
 
-@functools.partial(jax.jit, static_argnames=("l_max", "scale_bits", "dtype_name"))
+@functools.partial(jax.jit, static_argnames=_IMPL_STATICS)
 def _alm_from_delta_folded_impl(s_e_re, s_e_im, s_o_re, s_o_im, m, x,
                                 pmm_mant, pmm_scale, *, l_max, scale_bits,
-                                dtype_name):
+                                dtype_name, block_rows=0):
     dtype = jnp.dtype(dtype_name)
-    M, R, K = m.shape[0], x.shape[1], s_e_re.shape[-1]
-    carry0 = (jnp.zeros((M, R), dtype), jnp.zeros((M, R), dtype),
-              jnp.zeros((M, R), jnp.int32))
+    R, K = x.shape[1], s_e_re.shape[-1]
 
-    def step(carry, l):
-        mp, mc, sc = carry
-        with jax.named_scope(RECURRENCE):
-            mp, mc, sc, val = recurrence_step(
-                l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
-                scale_bits=scale_bits, dtype=dtype)
-        with jax.named_scope(ACCUMULATE):
-            even = (((l + m) % 2) == 0)[..., None]  # (M, 1) -> (M, 1, 1)
-            sre = jnp.where(even, s_e_re, s_o_re)
-            sim = jnp.where(even, s_e_im, s_o_im)
-            a_re_l = jnp.einsum("mr,mrk->km", val, sre,
-                                precision=_HIGHEST).reshape(-1)
-            a_im_l = jnp.einsum("mr,mrk->km", val, sim,
-                                precision=_HIGHEST).reshape(-1)
-        return (mp, mc, sc), (a_re_l, a_im_l)
+    def loop(lo, m, pmm_mant, pmm_scale, s_e_re, s_e_im, s_o_re, s_o_im):
+        M = m.shape[0]
 
-    _, (a_re, a_im) = jax.lax.scan(step, carry0, jnp.arange(l_max + 1))
-    return _scan_to_mlk(a_re, K), _scan_to_mlk(a_im, K)
+        def step(l, carry):
+            mp, mc, sc = carry
+            with jax.named_scope(RECURRENCE):
+                mp, mc, sc, val = recurrence_step(
+                    l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
+                    scale_bits=scale_bits, dtype=dtype)
+            with jax.named_scope(ACCUMULATE):
+                even = (((l + m) % 2) == 0)[..., None]  # (M, 1) -> (M, 1, 1)
+                sre = jnp.where(even, s_e_re, s_o_re)
+                sim = jnp.where(even, s_e_im, s_o_im)
+                a_re_l = jnp.einsum("mr,mrk->km", val, sre,
+                                    precision=_HIGHEST).reshape(-1)
+                a_im_l = jnp.einsum("mr,mrk->km", val, sim,
+                                    precision=_HIGHEST).reshape(-1)
+            return (mp, mc, sc), (a_re_l, a_im_l)
+
+        carry0 = (jnp.zeros((M, R), dtype), jnp.zeros((M, R), dtype),
+                  jnp.zeros((M, R), jnp.int32))
+        return _stack_loop(lo, l_max, M, K, carry0, step, dtype)
+
+    return _by_row_blocks(loop, block_rows, l_max, m, pmm_mant, pmm_scale,
+                          s_e_re, s_e_im, s_o_re, s_o_im)
 
 
 @scoped(LEGENDRE)
@@ -510,7 +665,8 @@ def alm_from_delta_folded(sum_e_re, sum_e_im, sum_o_re, sum_o_im, m_vals,
     pairs: sum_e = w_n*Delta(north) + w_s*Delta(south mirror), sum_o = the
     difference (equator ring, if any, contributes to sum_e and sum_o with the
     same value and half... no: with its own weight in sum_e and ZERO in sum_o
-    handled by the caller).  Each (M, R_north, K).
+    handled by the caller).  Each (M, R_north, K).  Concrete rows run in
+    blocks from their first non-zero l (`row_blocks`).
 
     Differentiable both ways: the VJP is the folded synthesis of the alm
     cotangent (even/odd partials of the gradient).
@@ -522,21 +678,21 @@ def alm_from_delta_folded(sum_e_re, sum_e_im, sum_o_re, sum_o_im, m_vals,
     lm_all = np.asarray(log_mu_all)
     ops = tuple(jnp.asarray(v, dtype)
                 for v in (sum_e_re, sum_e_im, sum_o_re, sum_o_im))
+    kw = dict(l_max=l_max, scale_bits=sb, dtype_name=dtype.name,
+              block_rows=_blocks_for(m_vals, "anal", fold=True,
+                                     n_rings=gx.shape[0], K=ops[0].shape[-1],
+                                     dtype=dtype))
 
     def fwd(pre, ops_):
         se_re, se_im, so_re, so_im = ops_
         m, x, pm, ps = pre
         return _alm_from_delta_folded_impl(se_re, se_im, so_re, so_im, m, x,
-                                           pm, ps, l_max=l_max,
-                                           scale_bits=sb,
-                                           dtype_name=dtype.name)
+                                           pm, ps, **kw)
 
     def bwd(pre, cts):
         ga_re, ga_im = cts
         m, x, pm, ps = pre
-        return _delta_from_alm_folded_impl(ga_re, ga_im, m, x, pm, ps,
-                                           l_max=l_max, scale_bits=sb,
-                                           dtype_name=dtype.name)
+        return _delta_from_alm_folded_impl(ga_re, ga_im, m, x, pm, ps, **kw)
 
     return linear_pair(fwd, bwd, _prep(m_vals, gx, gs, lm_all, dtype, sb),
                        ops)

@@ -1212,6 +1212,26 @@ class Plan:
         out["total_bytes"] = sum(out.values())
         return out
 
+    def _jnp_blocks(self) -> dict:
+        """Per direction run by the jnp backend: the row blocks of its
+        Legendre loop (`legendre.loop_blocks`: block count and the share
+        of the M x L row-steps it runs).  Spin-2 loops run every row."""
+        out = {}
+        for d in ("synth", "anal"):
+            if self.backends.get(d) != "jnp":
+                continue
+            if self.spin:
+                out[d] = {"blocks": 1, "rows_per_block": 2 * (self.m_max + 1),
+                          "row_step_share": 1.0}
+                continue
+            rings = self._sht.n_north if self.fold else self.grid.n_rings
+            out[d] = legendre.loop_blocks(
+                self._m_vals, l_max=self.l_max,
+                row_bytes=legendre.row_step_bytes(
+                    d, fold=self.fold, n_rings=rings, K=self.K,
+                    dtype=self.dtype))
+        return out
+
     def describe(self) -> dict:
         """Structured report: signature, chosen kernels, predicted vs
         measured seconds per candidate, memory footprint, cache counters.
@@ -1262,7 +1282,8 @@ class Plan:
             "skipped": dict(self.skipped),
             # grouped view of the packing decision; panels comes from the
             # sht_work() call above (same legendre_panel_counts dict)
-            "legendre": {"layouts": layouts, "panels": w["panels"]},
+            "legendre": {"layouts": layouts, "panels": w["panels"],
+                         "jnp_blocks": self._jnp_blocks()},
             "phase": self._sht.phase.describe(),
             "predicted_s": self.predicted_s,
             "measured_s": self.measured_s,
@@ -1300,6 +1321,11 @@ class Plan:
             f"{pc['plain_launched']} grid steps "
             f"({pc['launched_ratio']:.2f}x fewer, occupancy "
             f"{pc['packed_occupancy']:.2f})")
+        for direction, b in d["legendre"]["jnp_blocks"].items():
+            lines.append(
+                f"  jnp loop {direction}: {b['blocks']} row blocks of "
+                f"{b['rows_per_block']}, {b['row_step_share']:.3f} of the "
+                f"M x L row-steps")
         for direction in ("synth", "anal"):
             chosen = d["backends"].get(direction, "?")
             pred = d["predicted_s"].get(chosen, {}).get(direction)

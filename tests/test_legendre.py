@@ -120,3 +120,132 @@ def test_recurrence_vs_direct_formula(l, dm):
                      + lgamma(l - m + 1) - lgamma(l + m + 1))
     ref = p_curr * np.exp(lognorm)
     assert np.max(np.abs(P - ref)) < 1e-8 * max(1.0, np.abs(ref).max())
+
+
+# -- row blocks of the jnp loop ----------------------------------------------
+
+_L_BLOCKS = 29
+_ROW_SETS = {
+    "m_max_eq_l_max": np.arange(_L_BLOCKS + 1),           # M = 30
+    "m_max_lt_l_max": np.arange(20),                      # M = 20
+    "padded": np.r_[np.arange(19), -1, -1],               # M = 21
+}
+_BLOCK_ROWS = {
+    "C1": lambda M: M,                  # one block through the block path
+    "C2": lambda M: -(-M // 2),         # two blocks
+    "uneven": lambda M: 8,              # last block shorter than the rest
+}
+
+
+def _loop_case(direction, fold, m_vals, seed=0):
+    """(impl, operands, static kwargs) of one direction's jnp loop over the
+    rows ``m_vals`` on the GL grid at ``_L_BLOCKS``, with random data."""
+    g = grids.make_grid("gl", l_max=_L_BLOCKS)
+    R = (g.n_rings + 1) // 2 if fold else g.n_rings
+    sb = legendre.scale_bits_for(jnp.float64)
+    m, x, pm, ps = legendre._prep(m_vals, g.cos_theta[:R], g.sin_theta[:R],
+                                  legendre.log_mu(_L_BLOCKS), jnp.float64, sb)
+    rng = np.random.default_rng(seed)
+    M, K = m.shape[0], 3
+    if direction == "synth":
+        ops = [rng.normal(size=(M, _L_BLOCKS + 1, K)) for _ in range(2)]
+    else:
+        ops = [rng.normal(size=(M, R, K)) for _ in range(4 if fold else 2)]
+    impl = {("synth", False): legendre._delta_from_alm_impl,
+            ("synth", True): legendre._delta_from_alm_folded_impl,
+            ("anal", False): legendre._alm_from_delta_impl,
+            ("anal", True): legendre._alm_from_delta_folded_impl}[
+                direction, fold]
+    args = (*ops, m, x, pm, ps)
+    if (direction, fold) == ("anal", False):
+        args += (jnp.asarray(g.weights),)
+    return impl, args, dict(l_max=_L_BLOCKS, scale_bits=sb,
+                            dtype_name="float64")
+
+
+@pytest.mark.parametrize("blocks", sorted(_BLOCK_ROWS))
+@pytest.mark.parametrize("rows", sorted(_ROW_SETS))
+@pytest.mark.parametrize("fold", [False, True])
+@pytest.mark.parametrize("direction", ["anal", "synth"])
+def test_blocked_loop_matches_full_loop(direction, fold, rows, blocks):
+    """Row blocks run from their first non-zero l give the single full
+    loop's result: the same steps per row, in the same l order."""
+    m_vals = _ROW_SETS[rows]
+    impl, args, kw = _loop_case(direction, fold, m_vals)
+    full = impl(*args, **kw)
+    blocked = impl(*args, **kw, block_rows=_BLOCK_ROWS[blocks](len(m_vals)))
+    for f, b in zip(full, blocked):
+        f, b = np.asarray(f), np.asarray(b)
+        assert f.shape == b.shape
+        assert np.max(np.abs(b - f)) <= 1e-12 * np.max(np.abs(f))
+    if rows == "padded":
+        pad = m_vals < 0
+        assert all(np.all(np.asarray(b)[pad] == 0.0) for b in blocked)
+
+
+@pytest.mark.parametrize("fold", [False, True])
+@pytest.mark.parametrize("direction", ["anal", "synth"])
+def test_traced_rows_take_the_full_loop(direction, fold, monkeypatch):
+    """Rows known at trace time run in blocks; traced rows (the dist
+    stage 1 inside shard_map) run the single full loop, to the same
+    result."""
+    monkeypatch.setattr(legendre, "BLOCK_STEP_BYTES", 1 << 12)
+    g = grids.make_grid("gl", l_max=_L_BLOCKS)
+    nh = (g.n_rings + 1) // 2
+    x, s = (g.cos_theta[:nh], g.sin_theta[:nh]) if fold else \
+        (g.cos_theta, g.sin_theta)
+    lm = legendre.log_mu(_L_BLOCKS)
+    m_vals = np.arange(_L_BLOCKS + 1)
+    rng = np.random.default_rng(3)
+    shape = (len(m_vals), _L_BLOCKS + 1 if direction == "synth" else len(x),
+             2)
+    ops = [rng.normal(size=shape) for _ in range(
+        4 if (direction, fold) == ("anal", True) else 2)]
+    if direction == "synth":
+        fn = legendre.delta_from_alm_folded if fold else \
+            legendre.delta_from_alm
+        call = lambda mv: fn(*ops, mv, x, s, lm, l_max=_L_BLOCKS)
+    elif fold:
+        call = lambda mv: legendre.alm_from_delta_folded(
+            *ops, mv, x, s, lm, l_max=_L_BLOCKS)
+    else:
+        call = lambda mv: legendre.alm_from_delta(
+            *ops, mv, x, s, g.weights, lm, l_max=_L_BLOCKS)
+    row_bytes = legendre.row_step_bytes(direction, fold=fold,
+                                        n_rings=len(x), K=2,
+                                        dtype=jnp.float64)
+    assert legendre.row_blocks(m_vals, row_bytes) > 0
+
+    def traced(mv):
+        assert legendre.row_blocks(mv, row_bytes) == 0
+        return call(mv)
+
+    for b, f in zip(call(m_vals), jax.jit(traced)(m_vals)):
+        f, b = np.asarray(f), np.asarray(b)
+        assert np.max(np.abs(b - f)) <= 1e-12 * np.max(np.abs(f))
+
+
+@pytest.mark.parametrize("fold", [False, True])
+@pytest.mark.parametrize("C", [2, 4])
+def test_describe_reports_jnp_row_blocks(C, fold, monkeypatch):
+    """``Plan.describe()`` gives the jnp loop's block count and the share
+    of the M x L row-steps it runs: (C + 1) / 2C for C blocks of the rows
+    m = 0..l_max, 1.0 for one full loop."""
+    import repro
+    l_max, K = 31, 2                     # M = L = 32 rows, blocks of 8
+    plan = repro.make_plan("gl", l_max=l_max, K=K, dtype="float64",
+                           mode="jnp", fold=fold, cache="off")
+    got = plan.describe()["legendre"]["jnp_blocks"]
+    assert set(got) == {"synth", "anal"}
+    for d in got.values():
+        assert d["blocks"] == 1 and d["row_step_share"] == 1.0
+    rings = (plan.grid.n_rings + 1) // 2 if fold else plan.grid.n_rings
+    row_bytes = legendre.row_step_bytes("anal", fold=fold, n_rings=rings,
+                                        K=K, dtype="float64")
+    monkeypatch.setattr(legendre, "BLOCK_STEP_BYTES",
+                        (l_max + 1) * row_bytes // C)
+    anal = plan.describe()["legendre"]["jnp_blocks"]["anal"]
+    assert anal["blocks"] == C
+    assert anal["rows_per_block"] == (l_max + 1) // C
+    assert anal["row_step_share"] == pytest.approx((C + 1) / (2 * C))
+    assert f"jnp loop anal: {C} row blocks" in plan.report()

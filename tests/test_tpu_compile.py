@@ -123,3 +123,21 @@ def test_jnp_plan_is_lane_dense_at_k4(direction, one_chip, tpu_like):
     plan = _plan(4, "jnp")
     mem = _compile(plan, direction, "jnp", None, one_chip).memory_analysis()
     assert mem.temp_size_in_bytes < 8 * mem.argument_size_in_bytes
+
+
+def test_blocked_jnp_analysis_compiles_at_k4(one_chip, tpu_like, monkeypatch):
+    """The jnp analysis in row blocks (each run from its first non-zero l)
+    compiles for the chip with no more temp memory than the single full
+    loop: its carries shrink to one block's rows."""
+    from repro.core import legendre
+    full = _compile(_plan(4, "jnp"), "anal", "jnp", None,
+                    one_chip).memory_analysis()
+    row_bytes = legendre.row_step_bytes("anal", fold=False,
+                                        n_rings=L_MAX + 1, K=4,
+                                        dtype="float32")
+    monkeypatch.setattr(legendre, "BLOCK_STEP_BYTES",
+                        (L_MAX + 1) * row_bytes // 4)
+    plan = _plan(4, "jnp")
+    assert plan.describe()["legendre"]["jnp_blocks"]["anal"]["blocks"] == 4
+    blocked = _compile(plan, "anal", "jnp", None, one_chip).memory_analysis()
+    assert blocked.temp_size_in_bytes <= full.temp_size_in_bytes
