@@ -27,9 +27,9 @@ at the paper's widths (``repro.configs.sht_cmb``):
            plan's own column bit for bit, and agree with a K=1 plan run of
            the same request.
   dist     (``--chips 4`` only) the distributed two-stage transform at
-           ``anal_4k_k4`` (l_max=4096, K=4), with one exchange chunk and
-           with the chunk count the overlap model picks, against the
-           one-chip fused plan.
+           ``anal_4k_k4`` (l_max=4096, K=4), with the plan's default
+           exchange chunk count and, where that is not 1, with one chunk,
+           against the one-chip jnp plan.
 
 Each phase prints its backend and layout, whether Pallas ran in interpret
 mode (it must not), the backend compile seconds, one warm call's seconds,
@@ -413,13 +413,13 @@ def phase_engine(l_max: int, k: int, mode: str, rng,
 
 
 def phase_dist(l_max: int, K: int, rng, clock: CompileClock) -> None:
-    """The distributed plan over every visible chip vs the one-chip fused
-    plan, with one exchange chunk and with the modelled chunk count.  One
-    call per direction, its compile included (``first_*_s``): the stage-1
-    MXU kernel takes tens of seconds a call at l_max=4096."""
+    """The distributed plan over every visible chip vs the one-chip jnp
+    plan, with the plan's default exchange chunk count and, where that is
+    not 1, with one chunk.  One call per direction, its compile included
+    (``first_*_s``)."""
     import jax
     from jax.sharding import NamedSharding
-    ref = _plan(l_max, K, "pallas_vpu")
+    ref = _plan(l_max, K, "jnp")
     alm = random_alm(rng, l_max, K)
     clock.lap()
     maps_ref = np.asarray(ref.alm2map(alm))
@@ -427,7 +427,8 @@ def phase_dist(l_max: int, K: int, rng, clock: CompileClock) -> None:
     log(f"dist/lmax{l_max}_k{K}/one-chip", layout=ref.layouts,
         compile_s=f"{clock.lap():.1f}", **device_fields())
     release(ref)
-    for chunks in (1, "auto"):
+    default = _plan(l_max, K, "dist").comm_chunks
+    for chunks in ("auto",) + ((1,) if set(default.values()) != {1} else ()):
         plan = _plan(l_max, K, "dist", comm_chunks=chunks)
         eng = plan._dist_engine(plan.comm_chunks["synth"])
         packed = eng.plan.pack_alm(alm)

@@ -14,7 +14,9 @@ Design notes (DESIGN.md §2):
 * The SHTPlan pads the m list and the ring-pair list so every shard has
   identical slot counts: `lax.all_to_all(tiled=True)` replaces Alltoallv.
 * Real/imag (and the K map batch) are packed into one trailing channel axis
-  so each transform issues exactly ONE collective, like the paper.
+  so each transform issues exactly ONE collective, like the paper.  The
+  packing, the collective and the unpacking run under the ``sht.exchange``
+  scope (`repro.tracing.EXCHANGE`).
 * `fold=True` runs the Legendre recurrence on northern rings only
   (equatorial symmetry), the libpsht-style optimisation.
 * `comm_dtype` optionally down-casts the Delta exchange (e.g. bfloat16) --
@@ -56,6 +58,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import legendre
 from repro.core import phase as phaselib
 from repro.core.plan import SHTPlan
+from repro.tracing import EXCHANGE
 
 __all__ = ["DistSHT"]
 
@@ -252,25 +255,38 @@ class DistSHT:
         to_rings:  (m_local, R_pad, C) -> (Mp, r_local, C)
         else:      (Mp, r_local, C)    -> (m_local, R_pad, C)
         """
-        n = self.plan.n_shards
-        split_axis = 1 if to_rings else 0
-        what = "dealt ring-pair slot" if to_rings else "dealt m-row slot"
-        if x.shape[split_axis] % n != 0:
-            raise ValueError(
-                f"all_to_all(tiled=True) needs the {what} count to be a "
-                f"multiple of the device count: axis {split_axis} has "
-                f"{x.shape[split_axis]} slots but the mesh "
-                f"{dict(self.mesh.shape)} spans {n} devices over axes "
-                f"{self.axis_names} (shape {x.shape})")
-        if self.comm_dtype is not None:
-            x = x.astype(self.comm_dtype)
-        if to_rings:
-            out = jax.lax.all_to_all(x, self._axis, split_axis=1,
-                                     concat_axis=0, tiled=True)
-        else:
-            out = jax.lax.all_to_all(x, self._axis, split_axis=0,
-                                     concat_axis=1, tiled=True)
-        return out.astype(self.dtype)
+        with jax.named_scope(EXCHANGE):
+            n = self.plan.n_shards
+            split_axis = 1 if to_rings else 0
+            what = "dealt ring-pair slot" if to_rings else "dealt m-row slot"
+            if x.shape[split_axis] % n != 0:
+                raise ValueError(
+                    f"all_to_all(tiled=True) needs the {what} count to be a "
+                    f"multiple of the device count: axis {split_axis} has "
+                    f"{x.shape[split_axis]} slots but the mesh "
+                    f"{dict(self.mesh.shape)} spans {n} devices over axes "
+                    f"{self.axis_names} (shape {x.shape})")
+            if self.comm_dtype is not None:
+                x = x.astype(self.comm_dtype)
+            if to_rings:
+                out = jax.lax.all_to_all(x, self._axis, split_axis=1,
+                                         concat_axis=0, tiled=True)
+            else:
+                out = jax.lax.all_to_all(x, self._axis, split_axis=0,
+                                         concat_axis=1, tiled=True)
+            return out.astype(self.dtype)
+
+    def _exchange_parts(self, parts, *, to_rings: bool, widths=None):
+        """:meth:`_exchange` of ``parts`` (one shape but for the trailing
+        channel axis) packed into one channel axis, unpacked into arrays
+        of ``widths`` channels (by default the parts' own), all under the
+        exchange scope: ONE collective for every channel."""
+        with jax.named_scope(EXCHANGE):
+            out = self._exchange(jnp.concatenate(parts, axis=-1),
+                                 to_rings=to_rings)
+            if widths is None:
+                widths = [p.shape[-1] for p in parts]
+            return jnp.split(out, np.cumsum(widths)[:-1].tolist(), axis=-1)
 
     # -- chunked pipelined exchange helpers ----------------------------------
     #
@@ -355,28 +371,25 @@ class DistSHT:
                 for k0, k1 in bounds:
                     d_re, d_im = self._stage1_synth(
                         a_re[..., k0:k1], a_im[..., k0:k1], m_loc)
-                    parts.append(self._exchange(
-                        jnp.concatenate([d_re, d_im], axis=-1),
-                        to_rings=True))                 # (Mp, r_local, 2kc)
-                d_re = jnp.concatenate(
-                    [p[..., : p.shape[-1] // 2] for p in parts], axis=-1)
-                d_im = jnp.concatenate(
-                    [p[..., p.shape[-1] // 2:] for p in parts], axis=-1)
+                    parts.append(self._exchange_parts(
+                        [d_re, d_im], to_rings=True))   # (Mp, r_local, kc)
+                with jax.named_scope(EXCHANGE):
+                    d_re = jnp.concatenate([p[0] for p in parts], axis=-1)
+                    d_im = jnp.concatenate([p[1] for p in parts], axis=-1)
             elif axis == "m":
                 parts = []
                 for m0, m1 in bounds:
                     d_re, d_im = self._stage1_synth(
                         a_re[m0:m1], a_im[m0:m1], m_loc[m0:m1])
-                    parts.append(self._exchange(
-                        jnp.concatenate([d_re, d_im], axis=-1),
-                        to_rings=True))              # (n*mc, r_local, 2K)
-                packed = self._merge_m_chunks(parts)   # (Mp, r_local, 2K)
-                d_re, d_im = packed[..., :K], packed[..., K:]
+                    parts.append(self._exchange_parts(
+                        [d_re, d_im], to_rings=True))  # (n*mc, r_local, K)
+                with jax.named_scope(EXCHANGE):         # (Mp, r_local, K)
+                    d_re = self._merge_m_chunks([p[0] for p in parts])
+                    d_im = self._merge_m_chunks([p[1] for p in parts])
             else:
                 d_re, d_im = self._stage1_synth(a_re, a_im, m_loc)
-                packed = jnp.concatenate([d_re, d_im], axis=-1)  # (m_local, R_pad, 2K)
-                packed = self._exchange(packed, to_rings=True)   # (Mp, r_local, 2K)
-                d_re, d_im = packed[..., :K], packed[..., K:]
+                d_re, d_im = self._exchange_parts(
+                    [d_re, d_im], to_rings=True)       # (Mp, r_local, K)
             return self._synth_fft(d_re, d_im, phi0_loc, valid_loc, fft_ops)
 
         def anal_shard(maps_loc, m_loc, phi0_loc, w_loc, *fft_ops):
@@ -387,31 +400,27 @@ class DistSHT:
                 for k0, k1 in bounds:
                     dw_re, dw_im = self._anal_fft(
                         maps_loc[..., k0:k1], phi0_loc, w_loc, fft_ops)
-                    packed = self._exchange(
-                        jnp.concatenate([dw_re, dw_im], axis=-1),
-                        to_rings=False)              # (m_local, R_pad, 2kc)
-                    kc = k1 - k0
-                    res.append(self._stage1_anal(
-                        packed[..., :kc], packed[..., kc:], m_loc))
+                    dw_re, dw_im = self._exchange_parts(
+                        [dw_re, dw_im], to_rings=False)  # (m_local, R_pad, kc)
+                    res.append(self._stage1_anal(dw_re, dw_im, m_loc))
                 return (jnp.concatenate([r[0] for r in res], axis=-1),
                         jnp.concatenate([r[1] for r in res], axis=-1))
             if axis == "m":
                 dw_re, dw_im = self._anal_fft(maps_loc, phi0_loc, w_loc,
-                                              fft_ops)
-                full = jnp.concatenate([dw_re, dw_im], axis=-1)  # (Mp, r, 2K)
+                                              fft_ops)       # (Mp, r, K)
                 res = []
                 for m0, m1 in bounds:
-                    packed = self._exchange(
-                        self._split_m_chunk(full, m0, m1),
-                        to_rings=False)                  # (mc, R_pad, 2K)
-                    res.append(self._stage1_anal(
-                        packed[..., :K], packed[..., K:], m_loc[m0:m1]))
+                    with jax.named_scope(EXCHANGE):
+                        pieces = [self._split_m_chunk(d, m0, m1)
+                                  for d in (dw_re, dw_im)]
+                    c_re, c_im = self._exchange_parts(
+                        pieces, to_rings=False)          # (mc, R_pad, K)
+                    res.append(self._stage1_anal(c_re, c_im, m_loc[m0:m1]))
                 return (jnp.concatenate([r[0] for r in res], axis=0),
                         jnp.concatenate([r[1] for r in res], axis=0))
             dw_re, dw_im = self._anal_fft(maps_loc, phi0_loc, w_loc, fft_ops)
-            packed = jnp.concatenate([dw_re, dw_im], axis=-1)    # (Mp, r_local, 2K)
-            packed = self._exchange(packed, to_rings=False)      # (m_local, R_pad, 2K)
-            dw_re, dw_im = packed[..., :K], packed[..., K:]
+            dw_re, dw_im = self._exchange_parts(
+                [dw_re, dw_im], to_rings=False)          # (m_local, R_pad, K)
             return self._stage1_anal(dw_re, dw_im, m_loc)
 
         spec = self._spec_sharded()
@@ -444,12 +453,12 @@ class DistSHT:
         axis, bounds = self._schedule(K, ncomp=2)
 
         def _synth_one(e_re, e_im, b_re, b_im, m_loc):
-            """Stage 1 + exchange for one chunk -> packed (Mp, r, 4kc)."""
+            """Stage 1 + exchange for one chunk -> [dq_re, du_re, dq_im,
+            du_im], each (Mp, r, kc)."""
             dq_re, dq_im, du_re, du_im = self._stage1_synth_spin(
                 e_re, e_im, b_re, b_im, m_loc)
-            packed = jnp.concatenate([dq_re, du_re, dq_im, du_im],
-                                     axis=-1)          # (m_local, R_pad, 4kc)
-            return self._exchange(packed, to_rings=True)
+            return self._exchange_parts([dq_re, du_re, dq_im, du_im],
+                                  to_rings=True)
 
         def synth_shard(e_re, e_im, b_re, b_im, m_loc, phi0_loc, valid_loc,
                         *fft_ops):
@@ -457,28 +466,27 @@ class DistSHT:
                 parts = [_synth_one(e_re[..., k0:k1], e_im[..., k0:k1],
                                     b_re[..., k0:k1], b_im[..., k0:k1], m_loc)
                          for k0, k1 in bounds]
-                quad = [[p.reshape(p.shape[:-1] + (4, p.shape[-1] // 4))
-                         [..., c, :] for p in parts] for c in range(4)]
-                d_re = jnp.concatenate(quad[0] + quad[1], axis=-1)  # [Q|U] re
-                d_im = jnp.concatenate(quad[2] + quad[3], axis=-1)  # [Q|U] im
+                quad = [[p[c] for p in parts] for c in range(4)]
             elif axis == "m":
                 parts = [_synth_one(e_re[m0:m1], e_im[m0:m1], b_re[m0:m1],
                                     b_im[m0:m1], m_loc[m0:m1])
                          for m0, m1 in bounds]
-                packed = self._merge_m_chunks(parts)     # (Mp, r_local, 4K)
-                d_re, d_im = packed[..., :2 * K], packed[..., 2 * K:]
+                with jax.named_scope(EXCHANGE):          # (Mp, r_local, K)
+                    quad = [[self._merge_m_chunks([p[c] for p in parts])]
+                            for c in range(4)]
             else:
-                packed = _synth_one(e_re, e_im, b_re, b_im, m_loc)
-                d_re, d_im = packed[..., :2 * K], packed[..., 2 * K:]
+                quad = [[p] for p in _synth_one(e_re, e_im, b_re, b_im,
+                                                m_loc)]
+            with jax.named_scope(EXCHANGE):
+                d_re = jnp.concatenate(quad[0] + quad[1], axis=-1)  # [Q|U] re
+                d_im = jnp.concatenate(quad[2] + quad[3], axis=-1)  # [Q|U] im
             return self._synth_fft(d_re, d_im, phi0_loc, valid_loc, fft_ops)
 
         def _anal_one(maps_c, kc, m_loc, phi0_loc, w_loc, fft_ops):
             """FFT + exchange + stage 1 for one (r_local, n_phi, 2kc) chunk."""
             dw_re, dw_im = self._anal_fft(maps_c, phi0_loc, w_loc, fft_ops)
-            packed = jnp.concatenate([dw_re, dw_im], axis=-1)  # (Mp, r, 4kc)
-            packed = self._exchange(packed, to_rings=False)
-            dq_re, du_re = packed[..., :kc], packed[..., kc:2 * kc]
-            dq_im, du_im = packed[..., 2 * kc:3 * kc], packed[..., 3 * kc:]
+            dq_re, du_re, dq_im, du_im = self._exchange_parts(
+                [dw_re, dw_im], to_rings=False, widths=[kc] * 4)
             return self._stage1_anal_spin(dq_re, dq_im, du_re, du_im, m_loc)
 
         def anal_shard(maps_loc, m_loc, phi0_loc, w_loc, *fft_ops):
@@ -495,15 +503,14 @@ class DistSHT:
                              for c in range(4))
             if axis == "m":
                 dw_re, dw_im = self._anal_fft(maps_loc, phi0_loc, w_loc,
-                                              fft_ops)
-                full = jnp.concatenate([dw_re, dw_im], axis=-1)  # (Mp, r, 4K)
+                                              fft_ops)     # (Mp, r, 2K)
                 res = []
                 for m0, m1 in bounds:
-                    packed = self._exchange(
-                        self._split_m_chunk(full, m0, m1), to_rings=False)
-                    dq_re, du_re = packed[..., :K], packed[..., K:2 * K]
-                    dq_im = packed[..., 2 * K:3 * K]
-                    du_im = packed[..., 3 * K:]
+                    with jax.named_scope(EXCHANGE):
+                        pieces = [self._split_m_chunk(d, m0, m1)
+                                  for d in (dw_re, dw_im)]
+                    dq_re, du_re, dq_im, du_im = self._exchange_parts(
+                        pieces, to_rings=False, widths=[K] * 4)
                     res.append(self._stage1_anal_spin(
                         dq_re, dq_im, du_re, du_im, m_loc[m0:m1]))
                 return tuple(jnp.concatenate([r[c] for r in res], axis=0)

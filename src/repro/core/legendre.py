@@ -272,16 +272,14 @@ def row_step_bytes(direction: str, *, fold: bool, n_rings: int, K: int,
 def row_blocks(m_vals, row_bytes: int) -> int:
     """Rows per block of the jnp loop, or 0 for one loop over every row.
 
-    The concrete m rows are split into C contiguous blocks of a multiple
-    of 8 rows, C the largest count whose block step still streams
+    The m rows are split into C contiguous blocks of a multiple of 8
+    rows, C the largest count whose block step still streams
     :data:`BLOCK_STEP_BYTES` (``row_bytes`` per row, from
-    :func:`row_step_bytes`).  Traced ``m_vals`` (the distributed stage 1
-    inside shard_map) are not known at trace time: one full loop.
+    :func:`row_step_bytes`).  Only the row count matters, so traced
+    ``m_vals`` (the distributed stage 1 inside shard_map, whose rows are
+    static in shape) get the blocks of concrete rows of the same count.
     """
-    m = _concrete(m_vals)
-    if m is None:
-        return 0
-    M = m.shape[0]
+    M = int(np.shape(m_vals)[0])
     c = int(M * row_bytes // BLOCK_STEP_BYTES)
     if c < 2:
         return 0
@@ -399,7 +397,7 @@ def delta_from_alm(a_re, a_im, m_vals, grid_x, grid_sin, log_mu_all, *,
     a_re/a_im: (M, l_max+1, K) with rows l < m zero-padded.
     Returns (d_re, d_im): (M, R, K).  This is paper Algorithm 2 STEP 2 /
     Algorithm 3 STEP 2, vectorised over (m, ring) with the l loop sequential.
-    Concrete rows run in blocks from their first non-zero l (`row_blocks`).
+    The rows run in blocks from their first non-zero l (`row_blocks`).
 
     Differentiable both ways via the adjoint identity (VJP = analysis with
     unit weights); ``m_vals`` may be traced (the distributed stage-1 path).
@@ -495,7 +493,7 @@ def alm_from_delta(d_re, d_im, m_vals, grid_x, grid_sin, weights, log_mu_all,
     """Analysis inner step: a_lm = sum_r w_r Delta^S_m(r) P_lm(cos theta_r).
 
     d_re/d_im: (M, R, K).  Returns (a_re, a_im): (M, l_max+1, K) with rows
-    l < m exactly zero.  Paper Algorithm 1 STEP 3.  Concrete rows run in
+    l < m exactly zero.  Paper Algorithm 1 STEP 3.  The rows run in
     blocks from their first non-zero l (`row_blocks`).
 
     Differentiable both ways via the adjoint identity (VJP = weights times
@@ -589,7 +587,7 @@ def delta_from_alm_folded(a_re, a_im, m_vals, north_x, north_sin, log_mu_all,
     """Folded synthesis: returns even/odd partials over the northern rings.
 
     (d_even_re, d_even_im, d_odd_re, d_odd_im), each (M, R_north, K).
-    North ring r: even + odd; its mirror: even - odd.  Concrete rows run in
+    North ring r: even + odd; its mirror: even - odd.  The rows run in
     blocks from their first non-zero l (`row_blocks`).
 
     Differentiable both ways: the VJP is the folded analysis of the even/odd
@@ -665,7 +663,7 @@ def alm_from_delta_folded(sum_e_re, sum_e_im, sum_o_re, sum_o_im, m_vals,
     pairs: sum_e = w_n*Delta(north) + w_s*Delta(south mirror), sum_o = the
     difference (equator ring, if any, contributes to sum_e and sum_o with the
     same value and half... no: with its own weight in sum_e and ZERO in sum_o
-    handled by the caller).  Each (M, R_north, K).  Concrete rows run in
+    handled by the caller).  Each (M, R_north, K).  The rows run in
     blocks from their first non-zero l (`row_blocks`).
 
     Differentiable both ways: the VJP is the folded synthesis of the alm

@@ -6,7 +6,8 @@ arrays consumed by ``dist_sht``:
 * **m distribution with min-max pairing** (paper Fig. 5): the global m list
   is reordered as [0, m_max, 1, m_max-1, ...] and pairs are dealt
   round-robin to shards, so every shard's total recurrence length is the
-  paper's invariant  sum over pairs of (2 l_max - m_max + 2).  Padding slots
+  paper's invariant  sum over pairs of (2 l_max - m_max + 2); each shard
+  then holds its rows in ascending m.  Padding slots
   (m = -1) keep every shard's slot count identical -- the TPU analogue of
   `Alltoallv` raggedness (DESIGN.md §2).
 * **ring distribution**: rings are dealt to shards as blocks of mirror pairs
@@ -83,7 +84,10 @@ class SHTPlan:
         """(n_shards, m_local) global m value per slot; -1 = padding.
 
         Pairs from ``minmax_m_order`` are dealt round-robin: pair p goes to
-        shard p % n_shards, preserving the paper's balance invariant.
+        shard p % n_shards, preserving the paper's balance invariant.  Each
+        shard's rows then ascend, padding last, so that a row block of the
+        jnp Legendre loop holds neighbouring m and starts at its least m
+        (`legendre.row_blocks`).
         """
         order = minmax_m_order(self.m_max)
         # Group into pairs [(0, m_max), (1, m_max-1), ...]; a lone middle
@@ -95,7 +99,7 @@ class SHTPlan:
         m_local = max(len(s) for s in per_shard)
         out = np.full((self.n_shards, m_local), -1, dtype=np.int64)
         for i, s in enumerate(per_shard):
-            out[i, : len(s)] = s
+            out[i, : len(s)] = sorted(s)
         return out
 
     @property
@@ -135,12 +139,12 @@ class SHTPlan:
         M = self.m_max + 1
         out_shape = (M,) + tuple(packed.shape[1:])
         out = xp.zeros(out_shape, packed.dtype)
-        valid = self.m_flat >= 0
-        idx = self.m_flat[valid]
+        src = np.flatnonzero(self.m_flat >= 0)
+        idx = self.m_flat[src]
         if xp is np:
-            out[idx] = packed[valid]
+            out[idx] = packed[src]
             return out
-        return out.at[xp.asarray(idx)].set(packed[xp.asarray(valid)])
+        return out.at[idx].set(packed[src])
 
     # ---- chunked-exchange dealing -------------------------------------------
 
@@ -314,12 +318,12 @@ class SHTPlan:
         xp = jnp if not isinstance(maps_plan, np.ndarray) else np
         R = self.grid.n_rings
         out = xp.zeros((R,) + tuple(maps_plan.shape[1:]), maps_plan.dtype)
-        valid = self.ring_order >= 0
-        idx = self.ring_order[valid]
+        src = np.flatnonzero(self.ring_order >= 0)
+        idx = self.ring_order[src]
         if xp is np:
-            out[idx] = maps_plan[valid]
+            out[idx] = maps_plan[src]
             return out
-        return out.at[xp.asarray(idx)].set(maps_plan[xp.asarray(valid)])
+        return out.at[idx].set(maps_plan[src])
 
     def gather_map(self, maps_grid: np.ndarray) -> np.ndarray:
         """(R, n_phi, K) grid-order maps -> (R_pad, n_phi, K) plan order."""

@@ -101,7 +101,7 @@ from repro.core import legendre
 from repro.core.grids import RingGrid
 from repro.core.sht import SHT, alm_mask, random_alm, random_alm_spin
 from repro.roofline import analysis as roofline
-from repro.tracing import ALM2MAP, FOLD, MAP2ALM
+from repro.tracing import ALM2MAP, FOLD, MAP2ALM, RESHARD, scoped
 
 __all__ = ["Plan", "make_plan", "available_backends", "backend_eligibility",
            "clear_plan_cache", "drop_plan"]
@@ -397,11 +397,39 @@ class Plan:
                                     SHTPlan(self.grid, self.l_max,
                                             self.m_max, n))
             mesh, splan = self._dist_splan
-            stage1 = "pallas" if self.dtype == "float32" else "jnp"
+            # stage 1 is the jnp loop in every dtype: on a TPU v5e at
+            # l_max 4096, K=4 it beats the Pallas kernels, which get traced
+            # rows inside shard_map (PERF.md §5)
             self._dists[C] = DistSHT(splan, mesh, ("sht",), dtype=self.dtype,
-                                     fold=False, stage1=stage1,
+                                     fold=False, stage1="jnp",
                                      comm_chunks=C)
         return self._dists[C]
+
+    def _dist_fns(self, direction: str, C: int):
+        """The dist backend's dense-in, dense-out callable for one
+        direction: the plan's reorder and reshard into the dealt layout,
+        the sharded two-stage core, and the reorder back, the reorders
+        jitted under the reshard scope."""
+        d = self._dist_engine(comm_chunks=C)
+        sp = d.plan
+
+        def both(f):                     # spin 2: the pair's leading axis
+            return lambda x: jnp.stack([f(x[0]), f(x[1])], axis=0)
+
+        spin = self.spin != 0
+        if direction == "synth":
+            pre = jax.jit(scoped(RESHARD)(both(sp.pack_alm) if spin
+                                          else sp.pack_alm))
+            post = jax.jit(scoped(RESHARD)(both(sp.scatter_map) if spin
+                                           else sp.scatter_map))
+            core = d.alm2map_spin if spin else d.alm2map
+        else:
+            pre = jax.jit(scoped(RESHARD)(both(sp.gather_map) if spin
+                                          else sp.gather_map))
+            post = jax.jit(scoped(RESHARD)(both(sp.unpack_alm) if spin
+                                           else sp.unpack_alm))
+            core = d.map2alm_spin if spin else d.map2alm
+        return lambda x: post(core(pre(x)))
 
     # -- per-backend execution ------------------------------------------------
 
@@ -455,20 +483,7 @@ class Plan:
             else:
                 fn = self._make_pallas_synth(variant=variant, layout=layout)
         elif backend == "dist":
-            d = self._dist_engine(comm_chunks=int(layout or 1))
-            splan = d.plan
-
-            if spin:
-                def fn(alm_eb):
-                    packed = jnp.stack([splan.pack_alm(alm_eb[0]),
-                                        splan.pack_alm(alm_eb[1])], axis=0)
-                    mp = d.alm2map_spin(packed)        # (2, R_pad, nphi, K)
-                    return jnp.stack([splan.scatter_map(mp[0]),
-                                      splan.scatter_map(mp[1])], axis=0)
-            else:
-                def fn(alm):
-                    maps_plan = d.alm2map(splan.pack_alm(alm))
-                    return splan.scatter_map(maps_plan)
+            fn = self._dist_fns("synth", int(layout or 1))
         else:
             raise ValueError(f"unknown backend {backend!r}")
         self._compiled[key] = fn
@@ -502,20 +517,7 @@ class Plan:
             else:
                 fn = self._make_pallas_anal(variant=variant, layout=layout)
         elif backend == "dist":
-            d = self._dist_engine(comm_chunks=int(layout or 1))
-            splan = d.plan
-
-            if spin:
-                def fn(maps_qu):
-                    packed = jnp.stack([splan.gather_map(maps_qu[0]),
-                                        splan.gather_map(maps_qu[1])], axis=0)
-                    alm_p = d.map2alm_spin(packed)     # (2, Mp, L, K)
-                    return jnp.stack([splan.unpack_alm(alm_p[0]),
-                                      splan.unpack_alm(alm_p[1])], axis=0)
-            else:
-                def fn(maps):
-                    alm_packed = d.map2alm(splan.gather_map(maps))
-                    return splan.unpack_alm(alm_packed)
+            fn = self._dist_fns("anal", int(layout or 1))
         else:
             raise ValueError(f"unknown backend {backend!r}")
         self._compiled[key] = fn
@@ -1232,6 +1234,36 @@ class Plan:
                     dtype=self.dtype))
         return out
 
+    def _dist_layout(self) -> Optional[dict]:
+        """The dist backend's shards, if a direction runs it: the stage-1
+        path, the dealt m rows per shard, and per direction the chunk
+        count and the row blocks of shard 0's stage-1 loop (per chunk)."""
+        dirs = [d for d in ("synth", "anal") if self.backends.get(d) == "dist"]
+        if not dirs:
+            return None
+        out, ncomp = {"chunks": {}, "row_blocks": {}}, 1 + (self.spin != 0)
+        for d in dirs:
+            C = self.comm_chunks.get(d) or 1
+            eng = self._dist_engine(C)
+            sp = eng.plan
+            out.update(stage1=eng.stage1, shards=sp.n_shards,
+                       m_local=sp.m_local)
+            axis, bounds = sp.chunk_schedule(self.K, ncomp=ncomp, chunks=C)
+            rows, K = sp.m_assignment[0], self.K
+            if axis == "k":
+                K = max(b - a for a, b in bounds)
+            elif axis == "m":
+                rows = rows[bounds[0][0]:bounds[0][1]]
+            out["chunks"][d] = {"C": C, "axis": axis}
+            out["row_blocks"][d] = (
+                {"blocks": 1, "rows_per_block": 2 * len(rows),
+                 "row_step_share": 1.0} if self.spin else
+                legendre.loop_blocks(rows, l_max=self.l_max,
+                                     row_bytes=legendre.row_step_bytes(
+                                         d, fold=False, n_rings=sp.r_pad,
+                                         K=K, dtype=self.dtype)))
+        return out
+
     def describe(self) -> dict:
         """Structured report: signature, chosen kernels, predicted vs
         measured seconds per candidate, memory footprint, cache counters.
@@ -1284,6 +1316,7 @@ class Plan:
             # sht_work() call above (same legendre_panel_counts dict)
             "legendre": {"layouts": layouts, "panels": w["panels"],
                          "jnp_blocks": self._jnp_blocks()},
+            "dist": self._dist_layout(),
             "phase": self._sht.phase.describe(),
             "predicted_s": self.predicted_s,
             "measured_s": self.measured_s,
@@ -1326,6 +1359,17 @@ class Plan:
                 f"  jnp loop {direction}: {b['blocks']} row blocks of "
                 f"{b['rows_per_block']}, {b['row_step_share']:.3f} of the "
                 f"M x L row-steps")
+        dl = d["dist"]
+        if dl:
+            lines.append(f"  dist: {dl['shards']} shards x {dl['m_local']} "
+                         f"m rows, stage 1 {dl['stage1']}")
+            for direction, b in dl["row_blocks"].items():
+                cc = dl["chunks"][direction]
+                lines.append(
+                    f"  dist {direction}: C={cc['C']} ({cc['axis']}), shard "
+                    f"0 loop: {b['blocks']} row blocks of "
+                    f"{b['rows_per_block']}, {b['row_step_share']:.3f} of "
+                    f"its row-steps")
         for direction in ("synth", "anal"):
             chosen = d["backends"].get(direction, "?")
             pred = d["predicted_s"].get(chosen, {}).get(direction)

@@ -36,6 +36,9 @@ class Hardware:
     hbm_bw: float            # bytes/s per chip
     link_bw: float           # bytes/s per ICI link
     coll_latency: float = 1e-6   # launch latency per collective [s]
+    #: fixed device time of one l step of the Legendre loop, whatever its
+    #: rows [s]: ~28 us for the jnp loop on a TPU v5e (PERF.md §5)
+    loop_step: float = 28e-6
 
 
 HW_V5E = Hardware("tpu-v5e", 197e12, 819e9, 50e9)
@@ -45,7 +48,8 @@ HW_V5E = Hardware("tpu-v5e", 197e12, 819e9, 50e9)
 #: numbers matter less than the *relative* per-backend ranking.  Simulated
 #: host "collectives" are memcpys behind a dispatch, so the per-collective
 #: launch latency is an order worse than real ICI.
-HW_HOST = Hardware("host-cpu", 2e11, 5e10, 1e10, coll_latency=1e-5)
+HW_HOST = Hardware("host-cpu", 2e11, 5e10, 1e10, coll_latency=1e-5,
+                   loop_step=2e-6)
 
 #: Per-chip peaks keyed by JAX's ``device_kind``.  Source: Google Cloud
 #: documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
@@ -212,8 +216,11 @@ def predict_sht_time(backend: str, *, l_max: int, m_max: int, n_rings: int,
 
     where ``comm_chunk = comm/C + hw.coll_latency`` -- each chunk's
     collective hides behind the adjacent chunk's compute, at the price of
-    one extra collective-launch latency per chunk.  ``C=1`` reproduces
-    the serial sum exactly.
+    one extra collective-launch latency per chunk.  The chunks also repeat
+    stage-1 work (`SHTPlan.chunk_schedule` splits K when K >= C, else the
+    local m rows): each K chunk runs the K-independent recurrence again,
+    and each m chunk runs its own loop over every l, ``hw.loop_step`` a
+    step.  ``C=1`` reproduces the serial sum exactly.
     """
     if backend not in BACKEND_MODELS:
         raise ValueError(f"unknown backend {backend!r}")
@@ -251,6 +258,12 @@ def predict_sht_time(backend: str, *, l_max: int, m_max: int, n_rings: int,
         comm = wire / hw.link_bw
         C = max(1, int(comm_chunks))
         if overlap and C > 1 and comm > 0.0:
+            m_local = -(-(m_max + 2) // (2 * n_devices)) * 2
+            if K >= C:
+                t += (C - 1) * w["recurrence_flops"] * leg_scale \
+                    / vec_rate / n_devices
+            else:
+                t += (min(C, m_local) - 1) * (l_max + 1) * hw.loop_step
             comp_c = t / C
             comm_c = comm / C + hw.coll_latency
             t = comp_c + comm_c + (C - 1) * max(comp_c, comm_c)

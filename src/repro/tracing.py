@@ -14,7 +14,13 @@ Device scopes (``op_name`` segments):
   the staged and fused Pallas kernels), with the sub-scopes
   :data:`RECURRENCE` and :data:`ACCUMULATE` inside the jnp scan step;
 * :data:`FOLD` -- hemisphere sums and mirrors, complex assembly and the
-  layout copies between the two stages.
+  layout copies between the two stages;
+* :data:`EXCHANGE` -- the distributed transform's ``all_to_all`` of the
+  Delta block, with the packing and unpacking of its channels (one per
+  exchange chunk, every shard);
+* :data:`RESHARD` -- the distributed plan's reorders around its sharded
+  core: grid rings to dealt ring slots and dealt m slots back to dense
+  m rows, with the moves between one device and the shards they imply.
 
 Host spans: :data:`ALM2MAP` / :data:`MAP2ALM` around each ``Plan``
 dispatch, and the serving engine's per-batch spans (:data:`ENGINE_SPANS`),
@@ -27,19 +33,24 @@ import functools
 
 import jax
 
-__all__ = ["PHASE", "LEGENDRE", "FOLD", "RECURRENCE", "ACCUMULATE",
-           "STAGES", "SUB_STAGES", "ALM2MAP", "MAP2ALM", "ENGINE_IDLE",
-           "ENGINE_FORM", "ENGINE_STACK", "ENGINE_UPLOAD", "ENGINE_HANDOFF",
-           "ENGINE_EXECUTE", "ENGINE_DOWNLOAD", "ENGINE_SCATTER",
-           "ENGINE_SPANS", "ENGINE_HOST", "ENGINE_WAITS", "scoped"]
+__all__ = ["PHASE", "LEGENDRE", "FOLD", "EXCHANGE", "RESHARD", "RECURRENCE",
+           "ACCUMULATE", "STAGES", "DIST_STAGES", "SUB_STAGES", "ALM2MAP",
+           "MAP2ALM", "ENGINE_IDLE", "ENGINE_FORM", "ENGINE_STACK",
+           "ENGINE_UPLOAD", "ENGINE_HANDOFF", "ENGINE_EXECUTE",
+           "ENGINE_DOWNLOAD", "ENGINE_SCATTER", "ENGINE_SPANS", "ENGINE_HOST",
+           "ENGINE_WAITS", "scoped"]
 
 PHASE = "sht.phase"
 LEGENDRE = "sht.legendre"
 FOLD = "sht.fold"
+EXCHANGE = "sht.exchange"
+RESHARD = "sht.reshard"
 RECURRENCE = "recurrence"
 ACCUMULATE = "accumulate"
 #: the transform's stage scopes
 STAGES = (PHASE, LEGENDRE, FOLD)
+#: the distributed plan's own stage scopes (a one-chip plan has neither)
+DIST_STAGES = (EXCHANGE, RESHARD)
 #: sub-scopes of the jnp Legendre scan step
 SUB_STAGES = (RECURRENCE, ACCUMULATE)
 

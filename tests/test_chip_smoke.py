@@ -106,7 +106,10 @@ def test_dist_phase_on_host_devices():
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=600, env=env, cwd=ROOT)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
-    assert "chunks=1" in r.stdout and "chunks=auto" in r.stdout
+    # the plan's default C (1 here, so C = 1 is not run a second time)
+    assert "/chunks=auto]" in r.stdout and "/chunks=1]" not in r.stdout
+    assert "comm_chunks={'synth': 1, 'anal': 1}" in r.stdout
+    assert "stage1=jnp" in r.stdout
     assert "operand_shards=[(0," in r.stdout
 
 

@@ -10,12 +10,12 @@ import pytest
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(helper):
+def _run(helper, *args):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
     r = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "tests", "helpers", helper)],
-        capture_output=True, text=True, timeout=560, env=env)
+        [sys.executable, os.path.join(_ROOT, "tests", "helpers", helper),
+         *args], capture_output=True, text=True, timeout=560, env=env)
     assert r.returncode == 0, f"{helper} failed:\n{r.stdout}\n{r.stderr}"
     return r.stdout
 
@@ -33,6 +33,16 @@ def test_dist_chunked_exchange_matches_monolithic():
     out = _run("dist_chunk_check.py")
     assert out.count("OK") == 10
     assert "bit-identical=True" in out
+
+
+@pytest.mark.parametrize("case", ["float32", "float64", "blocks"])
+def test_dist_plan_matches_serial(case):
+    # make_plan(mode="dist") on 4 simulated devices against the serial
+    # float64 transform, both directions: jnp stage 1 with no layout
+    # warning, one exchange chunk, and (case "blocks") each shard's rows in
+    # two row blocks; the exchange and reshard scopes in its program.
+    out = _run("dist_plan_check.py", case)
+    assert out.count("OK") == 4, out
 
 
 def test_moe_expert_parallel_matches_local():

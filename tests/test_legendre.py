@@ -186,9 +186,9 @@ def test_blocked_loop_matches_full_loop(direction, fold, rows, blocks):
 @pytest.mark.parametrize("fold", [False, True])
 @pytest.mark.parametrize("direction", ["anal", "synth"])
 def test_traced_rows_take_the_full_loop(direction, fold, monkeypatch):
-    """Rows known at trace time run in blocks; traced rows (the dist
-    stage 1 inside shard_map) run the single full loop, to the same
-    result."""
+    """Traced rows (the dist stage 1 inside shard_map) run in the blocks
+    that concrete rows of the same count run, to the same result: the
+    block count comes from the row count alone."""
     monkeypatch.setattr(legendre, "BLOCK_STEP_BYTES", 1 << 12)
     g = grids.make_grid("gl", l_max=_L_BLOCKS)
     nh = (g.n_rings + 1) // 2
@@ -217,12 +217,26 @@ def test_traced_rows_take_the_full_loop(direction, fold, monkeypatch):
     assert legendre.row_blocks(m_vals, row_bytes) > 0
 
     def traced(mv):
-        assert legendre.row_blocks(mv, row_bytes) == 0
+        assert legendre.row_blocks(mv, row_bytes) == \
+            legendre.row_blocks(m_vals, row_bytes)
         return call(mv)
 
     for b, f in zip(call(m_vals), jax.jit(traced)(m_vals)):
         f, b = np.asarray(f), np.asarray(b)
         assert np.max(np.abs(b - f)) <= 1e-12 * np.max(np.abs(f))
+
+
+@pytest.mark.parametrize("M", [1, 7, 8, 33, 1025])
+def test_row_blocks_traced_equals_concrete(M):
+    """`row_blocks` of traced rows is that of concrete rows of the same
+    count, at every row budget (the count alone decides)."""
+    m_vals = np.arange(M)
+    B = legendre.BLOCK_STEP_BYTES
+    for row_bytes in (1, B // 64, B // 8, B):
+        seen = []
+        jax.jit(lambda mv: seen.append(legendre.row_blocks(mv, row_bytes))
+                or mv).lower(m_vals)
+        assert seen == [legendre.row_blocks(m_vals, row_bytes)]
 
 
 @pytest.mark.parametrize("fold", [False, True])

@@ -105,3 +105,29 @@ def test_mirror_pairs_adjacent():
         assert ro[2 * i + 1] == R - 1 - i
     assert ro[2 * (R // 2)] == R // 2      # equator north slot
     assert ro[2 * (R // 2) + 1] == -1      # equator's dummy south
+
+
+@pytest.mark.parametrize("l_max,n_shards", [(4096, 4), (40, 4), (31, 8),
+                                            (16, 2)])
+def test_shard_rows_ascend_and_pack_unpack_roundtrip(l_max, n_shards):
+    """Each shard's dealt m rows ascend, padding last, and pack_alm /
+    unpack_alm invert each other in that order, in numpy and in a jitted
+    jnp program (the dist plan's reshards)."""
+    import jax
+    import jax.numpy as jnp
+    g = grids.make_grid("gl", l_max=l_max)
+    p = SHTPlan(g, l_max, l_max, n_shards)
+    for rows in p.m_assignment:
+        real = rows[rows >= 0]
+        assert np.all(np.diff(real) > 0)
+        assert np.all(rows[len(real):] == -1)
+    if l_max > 64:
+        return
+    rng = np.random.default_rng(2)
+    alm = rng.normal(size=(l_max + 1, l_max + 1, 2)).astype(np.complex64)
+    packed = p.pack_alm(alm)
+    assert np.all(packed[p.m_flat < 0] == 0)
+    assert np.array_equal(packed[p.m_flat >= 0], alm[p.m_flat[p.m_flat >= 0]])
+    assert np.array_equal(p.unpack_alm(packed), alm)
+    back = jax.jit(lambda a: p.unpack_alm(p.pack_alm(a)))(jnp.asarray(alm))
+    assert np.array_equal(np.asarray(back), alm)

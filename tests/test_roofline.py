@@ -1,4 +1,5 @@
 import numpy as np
+import pytest
 
 import repro  # noqa: F401
 from repro.core import comm_model as CM
@@ -120,3 +121,14 @@ def test_predict_comm_chunks_respects_axis_bounds():
                                K=1, hw=RA.HW_V5E, n_devices=8, max_chunks=64)
     assert c >= 1
     assert c <= max(1, 64)
+
+
+@pytest.mark.parametrize("direction", ["synth", "anal"])
+def test_predict_comm_chunks_at_anal_4k_k4_on_four_chips(direction):
+    # the chunk count measured fastest on a four-chip TPU v5e host at
+    # l_max 4096, K=4 (PERF.md §5): each extra chunk repeats stage-1
+    # work (the recurrence per K chunk, a loop over l per m chunk)
+    c = RA.predict_comm_chunks(l_max=4096, m_max=4096, n_rings=4097,
+                               n_phi=8194, K=4, direction=direction,
+                               hw=RA.HW_V5E, n_devices=4)
+    assert c == 1

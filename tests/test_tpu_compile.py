@@ -46,6 +46,17 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(scope="module")
+def four_chip_mesh(one_chip):
+    """A one-axis mesh over the described 2x2 host's four chips."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return Mesh(np.array(topo.devices), ("sht",))
+
+
 @pytest.fixture()
 def tpu_like(monkeypatch):
     """Trace as on a TPU backend: Pallas kernels for Mosaic, and JAX's
@@ -141,3 +152,29 @@ def test_blocked_jnp_analysis_compiles_at_k4(one_chip, tpu_like, monkeypatch):
     assert plan.describe()["legendre"]["jnp_blocks"]["anal"]["blocks"] == 4
     blocked = _compile(plan, "anal", "jnp", None, one_chip).memory_analysis()
     assert blocked.temp_size_in_bytes <= full.temp_size_in_bytes
+
+
+def test_dist_analysis_compiles_for_four_chips(four_chip_mesh, tpu_like):
+    """The dist plan's sharded analysis (jnp stage 1, one chunk) compiles
+    for four described chips: one all_to_all under the exchange scope, no
+    Pallas kernel, no float64, and its temps within the chip."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro import tracing
+    from repro.core import grids
+    from repro.core.dist_sht import DistSHT
+    from repro.core.plan import SHTPlan
+    sp = SHTPlan(grids.make_grid("gl", l_max=L_MAX), L_MAX, L_MAX, 4)
+    d = DistSHT(sp, four_chip_mesh, ("sht",), dtype="float32", stage1="jnp")
+    _, anal, c = d._build(4)
+    sh = NamedSharding(four_chip_mesh, P("sht"))
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+    lowered = anal.lower(sds((sp.r_pad, 2 * L_MAX + 2, 4), jnp.float32),
+                         sds(c["m_flat"].shape, jnp.int32),
+                         sds(c["phi0"].shape, jnp.float32),
+                         sds(c["w"].shape, jnp.float32))
+    assert tracing.EXCHANGE in lowered.as_text(debug_info=True)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert hlo.count("all-to-all(") == 1
+    assert "tpu_custom_call" not in hlo and "f64" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
