@@ -46,6 +46,7 @@ __all__ = [
     "pmm_scaled",
     "recurrence_step",
     "row_step_bytes",
+    "planes_per_step",
     "row_blocks",
     "loop_blocks",
     "delta_from_alm",
@@ -237,10 +238,10 @@ def _prep(m_vals, grid_x, grid_sin, log_mu_all, dtype, scale_bits):
 # run contiguous blocks of m rows, each from its first non-zero l.
 # ---------------------------------------------------------------------------
 
-#: Least bytes one l step of a row block should stream (see `row_blocks`).
+#: Bytes one l step of a row block should stream (see `row_blocks`).
 #: Each loop iteration costs a fixed time on the device while its bytes
 #: shrink with the block, so the m rows are split only as far as every
-#: block's step still streams this much.  From a chip sweep of the block
+#: block's step streams about this much.  From a chip sweep of the block
 #: count on a TPU v5e (PERF.md §5), at l_max 4096, K=4: 1141 MB a step at
 #: C = 1, 7.95 s a call; 287 MB at C = 4, 3.46 s; 74-96 MB at C = 12-16,
 #: 1.33-1.39 s, where a block's working set stays in on-chip memory and
@@ -253,10 +254,10 @@ BLOCK_STEP_BYTES = 256 << 20
 
 #: Arrays of R values per map that one step's accumulate streams for each
 #: m row, by (direction, fold): the analysis reads its weighted Delta
-#: (re, im; even and odd when folded), the synthesis reads and writes its
-#: accumulators (re, im; even and odd when folded).
-_ACCUMULATE_ARRAYS = {("anal", False): 2, ("anal", True): 4,
-                      ("synth", False): 4, ("synth", True): 8}
+#: (re, im), the synthesis reads and writes its accumulators (re, im).
+#: The folded loops stream one parity plane a step (plane l % 2).
+_ACCUMULATE_ARRAYS = {("anal", False): 2, ("anal", True): 2,
+                      ("synth", False): 4, ("synth", True): 4}
 
 
 def row_step_bytes(direction: str, *, fold: bool, n_rings: int, K: int,
@@ -269,18 +270,29 @@ def row_step_bytes(direction: str, *, fold: bool, n_rings: int, K: int,
     return arrays * int(n_rings) * jnp.dtype(dtype).itemsize
 
 
+def planes_per_step(direction: str, *, fold: bool) -> int:
+    """Operand planes a step of the jnp loop streams per map, by the byte
+    model: the unfolded loop has one; a folded step reads the even or the
+    odd (l + m) plane, not both (2 would mean both)."""
+    return (_ACCUMULATE_ARRAYS[direction, bool(fold)]
+            // _ACCUMULATE_ARRAYS[direction, False])
+
+
 def row_blocks(m_vals, row_bytes: int) -> int:
     """Rows per block of the jnp loop, or 0 for one loop over every row.
 
     The m rows are split into C contiguous blocks of a multiple of 8
-    rows, C the largest count whose block step still streams
+    rows, C the count nearest to the step's bytes over
     :data:`BLOCK_STEP_BYTES` (``row_bytes`` per row, from
-    :func:`row_step_bytes`).  Only the row count matters, so traced
+    :func:`row_step_bytes`), so a block step streams at least 3/4 of it.
+    Rounded down instead, a step of 1.6 x that took one block: the folded
+    synthesis at l_max 4096, K=1, 2.60 s a call on a v5e against 0.77 s
+    in 3 blocks (PERF.md §6).  Only the row count matters, so traced
     ``m_vals`` (the distributed stage 1 inside shard_map, whose rows are
     static in shape) get the blocks of concrete rows of the same count.
     """
     M = int(np.shape(m_vals)[0])
-    c = int(M * row_bytes // BLOCK_STEP_BYTES)
+    c = int(M * row_bytes / BLOCK_STEP_BYTES + 0.5)
     if c < 2:
         return 0
     rows = -(-M // c)
@@ -530,15 +542,25 @@ def alm_from_delta(d_re, d_im, m_vals, grid_x, grid_sin, weights, log_mu_all,
 
 
 # ---------------------------------------------------------------------------
-# Equator-folded variants (beyond-paper optimisation; libpsht-style).
+# Equator-folded variants (libpsht/libsharp-style; the default of symmetric
+# spin-0 plans, see `transform.make_plan`).
 #
 # P_lm(-x) = (-1)^(l+m) P_lm(x), so for a grid symmetric about the equator the
 # recurrence only needs to run over the northern half of the rings:
 #   Delta(north r) = E(r) + O(r),   Delta(mirror r) = E(r) - O(r)
-# with E/O the even/odd (l+m) partial sums.  Halves the recurrence flops; the
-# accumulate flops stay constant.  Used by the `fold=True` engine path and the
-# Pallas kernel hillclimb (EXPERIMENTS.md §Perf).
+# with E/O the even/odd (l+m) partial sums.  Row m at step l needs the even
+# plane when l + m is even, so with each row's two planes stacked by the
+# parity of m (plane 0: the one used at even l) the plane a step reads is
+# plane l % 2 for every row: one dynamic index, and each step streams one
+# plane, not both.
 # ---------------------------------------------------------------------------
+
+
+def _by_m_parity(m, even, odd):
+    """Per row: ``even`` where m is even, ``odd`` where it is odd (rows on
+    axis 0; ``m`` is (M, 1))."""
+    return jnp.where((m % 2 == 0).reshape((-1,) + (1,) * (even.ndim - 1)),
+                     even, odd)
 
 
 @functools.partial(jax.jit, static_argnames=_IMPL_STATICS)
@@ -547,35 +569,49 @@ def _delta_from_alm_folded_impl(a_re, a_im, m, x, pmm_mant, pmm_scale, *,
     dtype = jnp.dtype(dtype_name)
     R = x.shape[1]                     # R = number of *northern* rings
     K = a_re.shape[-1]
-    zeros = lambda *s: jnp.zeros(s, dtype)
 
     def loop(lo, m, pmm_mant, pmm_scale, a_re, a_im):
         M = m.shape[0]
-        carry0 = (zeros(M, R), zeros(M, R), jnp.zeros((M, R), jnp.int32),
-                  zeros(M, R, K), zeros(M, R, K),   # even re/im
-                  zeros(M, R, K), zeros(M, R, K))   # odd re/im
+        # K-major rows as in `_delta_from_alm_impl`: the a_lm stream as
+        # (L, K*M), the accumulators as (2, K*M, R), one plane per parity
+        # of l.
+        rows_re = jnp.transpose(a_re, (1, 2, 0)).reshape(l_max + 1, K * M)
+        rows_im = jnp.transpose(a_im, (1, 2, 0)).reshape(l_max + 1, K * M)
+        carry0 = (jnp.zeros((M, R), dtype), jnp.zeros((M, R), dtype),
+                  jnp.zeros((M, R), jnp.int32),
+                  jnp.zeros((2, K * M, R), dtype),
+                  jnp.zeros((2, K * M, R), dtype))
 
         def body(l, carry):
-            mp, mc, sc, ere, eim, ore_, oim = carry
+            mp, mc, sc, dre, dim = carry
             with jax.named_scope(RECURRENCE):
                 mp, mc, sc, val = recurrence_step(
                     l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
                     scale_bits=scale_bits, dtype=dtype)
             with jax.named_scope(ACCUMULATE):
-                are = jax.lax.dynamic_index_in_dim(a_re, l, axis=1,
+                are = jax.lax.dynamic_index_in_dim(rows_re, l, axis=0,
                                                    keepdims=False)
-                aim = jax.lax.dynamic_index_in_dim(a_im, l, axis=1,
+                aim = jax.lax.dynamic_index_in_dim(rows_im, l, axis=0,
                                                    keepdims=False)
-                cre = val[..., None] * are[:, None, :]
-                cim = val[..., None] * aim[:, None, :]
-                even = (((l + m) % 2) == 0)[..., None]     # (M, 1, 1)
-                ere = ere + jnp.where(even, cre, 0.0)
-                eim = eim + jnp.where(even, cim, 0.0)
-                ore_ = ore_ + jnp.where(even, 0.0, cre)
-                oim = oim + jnp.where(even, 0.0, cim)
-            return mp, mc, sc, ere, eim, ore_, oim
 
-        return jax.lax.fori_loop(lo, l_max + 1, body, carry0)[3:]
+                def add(d, a):
+                    plane = jax.lax.dynamic_index_in_dim(d, l % 2, axis=0,
+                                                         keepdims=False)
+                    plane = plane + (a.reshape(K, M)[:, :, None]
+                                     * val).reshape(K * M, R)
+                    return jax.lax.dynamic_update_index_in_dim(
+                        d, plane, l % 2, axis=0)
+
+                dre, dim = add(dre, are), add(dim, aim)
+            return mp, mc, sc, dre, dim
+
+        _, _, _, d_re, d_im = jax.lax.fori_loop(lo, l_max + 1, body, carry0)
+        unrow = lambda d: jnp.transpose(d.reshape(K, M, R), (1, 2, 0))
+        r0, r1, i0, i1 = map(unrow, (d_re[0], d_re[1], d_im[0], d_im[1]))
+        # even l holds the even (l + m) partial of even rows, the odd one
+        # of odd rows
+        return (_by_m_parity(m, r0, r1), _by_m_parity(m, i0, i1),
+                _by_m_parity(m, r1, r0), _by_m_parity(m, i1, i0))
 
     return _by_row_blocks(loop, block_rows, l_max, m, pmm_mant, pmm_scale,
                           a_re, a_im)
@@ -588,7 +624,8 @@ def delta_from_alm_folded(a_re, a_im, m_vals, north_x, north_sin, log_mu_all,
 
     (d_even_re, d_even_im, d_odd_re, d_odd_im), each (M, R_north, K).
     North ring r: even + odd; its mirror: even - odd.  The rows run in
-    blocks from their first non-zero l (`row_blocks`).
+    blocks from their first non-zero l (`row_blocks`), and each step adds
+    to the accumulators of its parity of l.
 
     Differentiable both ways: the VJP is the folded analysis of the even/odd
     cotangent partials (the parity split is its own transpose).
@@ -627,8 +664,15 @@ def _alm_from_delta_folded_impl(s_e_re, s_e_im, s_o_re, s_o_im, m, x,
                                 dtype_name, block_rows=0):
     dtype = jnp.dtype(dtype_name)
     R, K = x.shape[1], s_e_re.shape[-1]
+    # each row's planes (M, 2, R, K) by the parity of l that reads them:
+    # plane 0 (even l) is the even sum of even rows, the odd sum of odd
+    with jax.named_scope(FOLD):
+        planes_re = jnp.stack([_by_m_parity(m, s_e_re, s_o_re),
+                               _by_m_parity(m, s_o_re, s_e_re)], axis=1)
+        planes_im = jnp.stack([_by_m_parity(m, s_e_im, s_o_im),
+                               _by_m_parity(m, s_o_im, s_e_im)], axis=1)
 
-    def loop(lo, m, pmm_mant, pmm_scale, s_e_re, s_e_im, s_o_re, s_o_im):
+    def loop(lo, m, pmm_mant, pmm_scale, planes_re, planes_im):
         M = m.shape[0]
 
         def step(l, carry):
@@ -638,9 +682,10 @@ def _alm_from_delta_folded_impl(s_e_re, s_e_im, s_o_re, s_o_im, m, x,
                     l, m, x, mp, mc, sc, pmm_mant, pmm_scale,
                     scale_bits=scale_bits, dtype=dtype)
             with jax.named_scope(ACCUMULATE):
-                even = (((l + m) % 2) == 0)[..., None]  # (M, 1) -> (M, 1, 1)
-                sre = jnp.where(even, s_e_re, s_o_re)
-                sim = jnp.where(even, s_e_im, s_o_im)
+                sre = jax.lax.dynamic_index_in_dim(planes_re, l % 2, axis=1,
+                                                   keepdims=False)
+                sim = jax.lax.dynamic_index_in_dim(planes_im, l % 2, axis=1,
+                                                   keepdims=False)
                 a_re_l = jnp.einsum("mr,mrk->km", val, sre,
                                     precision=_HIGHEST).reshape(-1)
                 a_im_l = jnp.einsum("mr,mrk->km", val, sim,
@@ -652,7 +697,7 @@ def _alm_from_delta_folded_impl(s_e_re, s_e_im, s_o_re, s_o_im, m, x,
         return _stack_loop(lo, l_max, M, K, carry0, step, dtype)
 
     return _by_row_blocks(loop, block_rows, l_max, m, pmm_mant, pmm_scale,
-                          s_e_re, s_e_im, s_o_re, s_o_im)
+                          planes_re, planes_im)
 
 
 @scoped(LEGENDRE)
@@ -661,10 +706,10 @@ def alm_from_delta_folded(sum_e_re, sum_e_im, sum_o_re, sum_o_im, m_vals,
                           dtype=jnp.float64):
     """Folded analysis.  Inputs are the pre-folded weighted sums over ring
     pairs: sum_e = w_n*Delta(north) + w_s*Delta(south mirror), sum_o = the
-    difference (equator ring, if any, contributes to sum_e and sum_o with the
-    same value and half... no: with its own weight in sum_e and ZERO in sum_o
-    handled by the caller).  Each (M, R_north, K).  The rows run in
-    blocks from their first non-zero l (`row_blocks`).
+    difference; an equator ring, if any, enters sum_e alone (the caller
+    pairs it with zero; P_lm(0) = 0 for odd l + m).  Each (M, R_north, K).
+    The rows run in blocks from their first non-zero l (`row_blocks`), and
+    each step reads the one sum its rows need.
 
     Differentiable both ways: the VJP is the folded synthesis of the alm
     cotangent (even/odd partials of the gradient).

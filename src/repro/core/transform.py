@@ -703,9 +703,9 @@ class Plan:
                 try:
                     us, _ = db.get_or_measure(measure, **fields)
                 except Exception:
-                    if _on_tpu():   # a kernel that fails to build is an
-                        raise       # error on the chip, not a slow entry
-                    us = None
+                    if _on_tpu() and not self.fold:  # a kernel that fails
+                        raise       # to build is an error on the chip
+                    us = None       # (folded kernels: see _measure_all)
                 times[int(c)] = float("inf") if us is None else float(us)
         if not times or not np.isfinite(min(times.values())):
             g = self.grid
@@ -1004,12 +1004,20 @@ class Plan:
                         if status == "skipped":
                             out[b][f"{direction}_skipped"] = True
                     except Exception as e:  # unusable here: rank last
-                        if _on_tpu():   # ... but on the chip a candidate
-                            raise       # that fails to build is an error
+                        # ... but on the chip a candidate that fails to
+                        # build is an error, except a folded pallas layout:
+                        # it is skipped as the staged VPU kernels are
+                        if _on_tpu() and not (
+                                self.fold and b.startswith("pallas")):
+                            raise
                         t = float("inf")
                         errs[lay] = f"{type(e).__name__}: {e}"
                         if lay is not None:
                             out[b][f"{direction}_{lay}_error"] = errs[lay]
+                        if _on_tpu():    # Mosaic's first line
+                            self.skipped[f"{b}[{lay}]"] = (
+                                f"{direction}: "
+                                f"{errs[lay].splitlines()[0]}")
                     if lay is not None:
                         out[b][f"{direction}_{lay}"] = t
                     if t < best:
@@ -1217,7 +1225,9 @@ class Plan:
     def _jnp_blocks(self) -> dict:
         """Per direction run by the jnp backend: the row blocks of its
         Legendre loop (`legendre.loop_blocks`: block count and the share
-        of the M x L row-steps it runs).  Spin-2 loops run every row."""
+        of the M x L row-steps it runs), whether it folds, and the
+        operand planes a step streams (`legendre.planes_per_step`).
+        Spin-2 loops run every row."""
         out = {}
         for d in ("synth", "anal"):
             if self.backends.get(d) != "jnp":
@@ -1225,13 +1235,15 @@ class Plan:
             if self.spin:
                 out[d] = {"blocks": 1, "rows_per_block": 2 * (self.m_max + 1),
                           "row_step_share": 1.0}
-                continue
-            rings = self._sht.n_north if self.fold else self.grid.n_rings
-            out[d] = legendre.loop_blocks(
-                self._m_vals, l_max=self.l_max,
-                row_bytes=legendre.row_step_bytes(
-                    d, fold=self.fold, n_rings=rings, K=self.K,
-                    dtype=self.dtype))
+            else:
+                rings = self._sht.n_north if self.fold else self.grid.n_rings
+                out[d] = legendre.loop_blocks(
+                    self._m_vals, l_max=self.l_max,
+                    row_bytes=legendre.row_step_bytes(
+                        d, fold=self.fold, n_rings=rings, K=self.K,
+                        dtype=self.dtype))
+            out[d].update(fold=self.fold, planes_per_step=(
+                legendre.planes_per_step(d, fold=self.fold)))
         return out
 
     def _dist_layout(self) -> Optional[dict]:
@@ -1358,7 +1370,8 @@ class Plan:
             lines.append(
                 f"  jnp loop {direction}: {b['blocks']} row blocks of "
                 f"{b['rows_per_block']}, {b['row_step_share']:.3f} of the "
-                f"M x L row-steps")
+                f"M x L row-steps, fold={b['fold']}, "
+                f"{b['planes_per_step']} plane(s) a step")
         dl = d["dist"]
         if dl:
             lines.append(f"  dist: {dl['shards']} shards x {dl['m_local']} "
@@ -1441,7 +1454,8 @@ def _resolve_grid(grid, l_max, nside, cache_kind, cache_dir):
 def make_plan(grid: Union[str, RingGrid] = "gl", l_max: Optional[int] = None,
               *, nside: Optional[int] = None, m_max: Optional[int] = None,
               K: int = 1, dtype: Optional[str] = None, mode: str = "auto",
-              fold: bool = False, spin: int = 0, cache: str = "auto",
+              fold: Optional[bool] = None, spin: int = 0,
+              cache: str = "auto",
               cache_dir: Optional[str] = None,
               n_shards: Optional[int] = None,
               comm_chunks: Union[int, str] = "auto") -> Plan:
@@ -1462,7 +1476,12 @@ def make_plan(grid: Union[str, RingGrid] = "gl", l_max: Optional[int] = None,
     mode : ``"auto"`` (autotune, cached), ``"model"`` (cost model), or an
         explicit backend name (``"jnp"``, ``"pallas_vpu"``, ``"pallas_mxu"``,
         ``"dist"``).
-    fold : use the equator-fold optimisation (symmetric grids only).
+    fold : run the Legendre stage over the northern rings only, with even
+        and odd (l + m) sums for the mirror pairs (symmetric grids, spin 0
+        only).  None (default) folds on a uniform grid symmetric about the
+        equator (GL, ECP) at spin 0, outside ``mode="dist"`` (whose
+        sharded stage 1 does not fold); ragged grids stay unfolded, where
+        the fused kernels do not fold.  True or False forces it.
     spin : 0 (scalar) or 2 (polarisation).  A spin-2 plan transforms
         (E, B) alm pairs ``(2, M, L, K)`` <-> (Q, U) map pairs
         ``(2, R, n_phi, K)`` on every backend; costs ~2x the Legendre
@@ -1514,8 +1533,14 @@ def make_plan(grid: Union[str, RingGrid] = "gl", l_max: Optional[int] = None,
     assert m_max <= l_max, (m_max, l_max)
     if spin:
         assert l_max >= spin, (l_max, spin)
-    if fold:
+    if fold is None:
+        # a ragged grid's fused kernels do not fold (no folded bucket
+        # tables), so there the fold would cost the plan its fused layout
+        fold = (g.equator_symmetric and g.uniform and spin == 0
+                and mode != "dist")
+    elif fold:
         assert g.equator_symmetric, "fold requires a symmetric grid"
+    fold = bool(fold)
 
     # cache policy is part of the memoisation key: a plan built with
     # cache="off" must not shadow a later request for disk persistence.

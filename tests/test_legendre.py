@@ -263,3 +263,100 @@ def test_describe_reports_jnp_row_blocks(C, fold, monkeypatch):
     assert anal["rows_per_block"] == (l_max + 1) // C
     assert anal["row_step_share"] == pytest.approx((C + 1) / (2 * C))
     assert f"jnp loop anal: {C} row blocks" in plan.report()
+
+
+# -- the folded loops against the unfolded ones -------------------------------
+
+
+def _mirror_rings(n_rings):
+    """(cos, sin) of ``n_rings`` rings mirror-symmetric about the equator,
+    north first, with the equator ring (cos = 0 exactly) when the count is
+    odd.  The nodes need not be a quadrature: both loops take the same."""
+    nh = (n_rings + 1) // 2
+    xn = np.cos(np.linspace(0.15, np.pi / 2, nh, endpoint=n_rings % 2 == 1))
+    if n_rings % 2:
+        xn[-1] = 0.0
+    x = np.concatenate([xn, -xn[: n_rings - nh][::-1]])
+    return x, np.sqrt(1.0 - x * x)
+
+
+@pytest.mark.parametrize("block_rows", [0, 5])
+@pytest.mark.parametrize("K", [1, 4])
+@pytest.mark.parametrize("equator", [False, True])
+@pytest.mark.parametrize("l_max", [23, 24])
+@pytest.mark.parametrize("direction", ["anal", "synth"])
+def test_folded_loops_match_unfolded(direction, l_max, equator, K,
+                                     block_rows):
+    """The folded loops (one parity plane a step) give the unfolded loop's
+    result over every ring, with and without an equator ring, at odd and
+    even l_max, m_max < l_max, and in blocks of 5 rows (blocks that start
+    at odd l)."""
+    n_rings = 2 * 9 + int(equator)
+    nh, ns = (n_rings + 1) // 2, n_rings // 2
+    x, s = _mirror_rings(n_rings)
+    m_vals = np.arange(l_max - 4)                     # m_max < l_max
+    sb = legendre.scale_bits_for(jnp.float64)
+    lm = legendre.log_mu(l_max)
+    full = legendre._prep(m_vals, x, s, lm, jnp.float64, sb)
+    north = legendre._prep(m_vals, x[:nh], s[:nh], lm, jnp.float64, sb)
+    kw = dict(l_max=l_max, scale_bits=sb, dtype_name="float64")
+    rng = np.random.default_rng(l_max + 2 * K)
+    M = len(m_vals)
+    if direction == "synth":
+        a = [rng.normal(size=(M, l_max + 1, K)) for _ in range(2)]
+        for op in a:                                  # zero below l = m
+            op[np.arange(l_max + 1)[None, :] < m_vals[:, None]] = 0.0
+        want = legendre._delta_from_alm_impl(*a, *full, **kw)
+        e_re, e_im, o_re, o_im = legendre._delta_from_alm_folded_impl(
+            *a, *north, **kw, block_rows=block_rows)
+        got = [np.concatenate([e + o, (e - o)[:, :ns][:, ::-1]], axis=1)
+               for e, o in ((e_re, o_re), (e_im, o_im))]
+    else:
+        d = [rng.normal(size=(M, n_rings, K)) for _ in range(2)]
+        w = rng.uniform(0.5, 1.5, n_rings)
+        want = legendre._alm_from_delta_impl(*d, *full, jnp.asarray(w), **kw)
+        sums = []
+        for op in d:
+            dw = op * w[None, :, None]
+            south = np.zeros((M, nh, K))
+            south[:, :ns] = dw[:, nh:][:, ::-1]
+            sums.append((dw[:, :nh] + south, dw[:, :nh] - south))
+        (se_re, so_re), (se_im, so_im) = sums
+        got = legendre._alm_from_delta_folded_impl(
+            se_re, se_im, so_re, so_im, *north, **kw, block_rows=block_rows)
+    for g_, w_ in zip(got, want):
+        g_, w_ = np.asarray(g_), np.asarray(w_)
+        assert g_.shape == w_.shape
+        assert np.max(np.abs(g_ - w_)) <= 1e-12 * np.max(np.abs(w_))
+
+
+def test_folded_synthesis_carry_is_k_major():
+    """The folded synthesis loop carries its accumulators K-major, as
+    (2, K*M, R), one plane per parity of l: a K-minor (M, R, K) carry is
+    padded to 128 lanes on a TPU (~17 GB at l_max 4096)."""
+    l_max, M, R, K = 11, 12, 5, 4
+    x, s = _mirror_rings(2 * R - 1)
+    sb = legendre.scale_bits_for(jnp.float64)
+    args = legendre._prep(np.arange(M), x[:R], s[:R], legendre.log_mu(l_max),
+                          jnp.float64, sb)
+    a = jnp.zeros((M, l_max + 1, K))
+    jaxpr = jax.make_jaxpr(lambda a_re, a_im: legendre
+                           ._delta_from_alm_folded_impl(
+                               a_re, a_im, *args, l_max=l_max,
+                               scale_bits=sb, dtype_name="float64"))(a, a)
+    loops = []
+
+    def walk(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name in ("while", "scan"):
+                loops.append([v.aval.shape for v in eqn.outvars])
+            for p in eqn.params.values():
+                inner = getattr(p, "jaxpr", p)
+                if hasattr(inner, "eqns"):
+                    walk(inner)
+
+    walk(jaxpr.jaxpr)
+    assert loops
+    carry = [shape for shapes in loops for shape in shapes]
+    assert carry.count((2, K * M, R)) == 2            # re, im
+    assert (M, R, K) not in carry

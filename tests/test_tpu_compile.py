@@ -136,6 +136,26 @@ def test_jnp_plan_is_lane_dense_at_k4(direction, one_chip, tpu_like):
     assert mem.temp_size_in_bytes < 8 * mem.argument_size_in_bytes
 
 
+@pytest.mark.parametrize("direction", ["synth", "anal"])
+def test_unfolded_jnp_plan_is_lane_dense_at_k4(direction, one_chip,
+                                               tpu_like):
+    """As above for the unfolded loops (``fold=False``, the dist stage 1)."""
+    plan = repro.make_plan("gl", l_max=L_MAX, K=4, dtype="float32",
+                           mode="jnp", fold=False, cache="off")
+    mem = _compile(plan, direction, "jnp", None, one_chip).memory_analysis()
+    assert mem.temp_size_in_bytes < 8 * mem.argument_size_in_bytes
+
+
+def test_folded_jnp_synthesis_fits_at_4096(one_chip, tpu_like):
+    """The folded synthesis, the default of a symmetric spin-0 plan,
+    compiles at the paper's l_max 4096 (K=1) within ~0.5 GB of temps: its
+    accumulators are K-major (K*M, R) (K-minor ones were ~17 GB)."""
+    plan = _plan(1, "jnp", l_max=4096)
+    assert plan.fold
+    mem = _compile(plan, "synth", "jnp", None, one_chip).memory_analysis()
+    assert mem.temp_size_in_bytes < 1e9
+
+
 def test_blocked_jnp_analysis_compiles_at_k4(one_chip, tpu_like, monkeypatch):
     """The jnp analysis in row blocks (each run from its first non-zero l)
     compiles for the chip with no more temp memory than the single full
@@ -143,8 +163,8 @@ def test_blocked_jnp_analysis_compiles_at_k4(one_chip, tpu_like, monkeypatch):
     from repro.core import legendre
     full = _compile(_plan(4, "jnp"), "anal", "jnp", None,
                     one_chip).memory_analysis()
-    row_bytes = legendre.row_step_bytes("anal", fold=False,
-                                        n_rings=L_MAX + 1, K=4,
+    row_bytes = legendre.row_step_bytes("anal", fold=True,
+                                        n_rings=(L_MAX + 2) // 2, K=4,
                                         dtype="float32")
     monkeypatch.setattr(legendre, "BLOCK_STEP_BYTES",
                         (L_MAX + 1) * row_bytes // 4)

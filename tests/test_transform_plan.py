@@ -115,9 +115,10 @@ def test_disk_cache_keys_distinguish_layout_and_spin(tmp_path):
     s0 = p0._seeds()
     s2 = p2._seeds_spin()
     assert p0.cache_events["seeds"] != p2.cache_events["seeds_spin"]
-    # fold changes the seed table layout -> its own key
+    # fold changes the seed table layout -> its own key (p0 folds by
+    # default: a symmetric spin-0 plan)
     pf = repro.make_plan("gl", l_max=LMAX, K=1, dtype="float32",
-                         mode="pallas_vpu", fold=True, cache="disk",
+                         mode="pallas_vpu", fold=False, cache="disk",
                          cache_dir=d)
     sf = pf._seeds()
     assert pf.cache_events["seeds"] != p0.cache_events["seeds"]
@@ -378,3 +379,90 @@ def test_peaks_table_rejects_unknown_device():
     Dev.device_kind = "TPU v5 lite"
     assert analysis.hardware_for(Dev()) is analysis.HW_V5E
     assert analysis.hardware_for(jax.devices("cpu")[0]) is analysis.HW_HOST
+
+
+# ---------------------------------------------------------------------------
+# the equator fold: derived default, engagement counter, chip policy
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("case, want", [
+    (dict(grid="gl"), True),
+    (dict(grid="ecp"), True),
+    (dict(grid="gl", spin=2), False),
+    (dict(grid="gl", mode="dist", n_shards=2), False),
+    (dict(grid="gl", fold=False), False),
+    (dict(grid="healpix", nside=4), False),
+    (dict(grid="healpix", nside=4, fold=True), True),
+])
+def test_fold_default_is_derived(case, want):
+    """``fold=None`` folds a uniform symmetric spin-0 plan outside the
+    dist backend (a ragged HEALPix grid keeps its fused layout unfolded);
+    an explicit value is honoured."""
+    kw = dict(l_max=None if "nside" in case else 8, dtype="float64",
+              mode="jnp", cache="off")
+    kw.update(case)
+    plan = repro.make_plan(kw.pop("grid"), **kw)
+    assert plan.fold is want
+    if kw["mode"] == "jnp":          # describe() of a dist plan needs devices
+        d = plan.describe()
+        assert d["signature"]["fold"] is want
+        assert all(b["fold"] is want
+                   for b in d["legendre"]["jnp_blocks"].values())
+
+
+def test_describe_reports_fold_and_planes_a_step():
+    plan = repro.make_plan("gl", l_max=LMAX, K=K, dtype="float64",
+                           mode="jnp")
+    blocks = plan.describe()["legendre"]["jnp_blocks"]
+    assert set(blocks) == {"synth", "anal"}
+    for b in blocks.values():
+        assert b["fold"] is True and b["planes_per_step"] == 1
+    report = plan.report()
+    assert "fold=True" in report.splitlines()[0]
+    assert "fold=True, 1 plane(s) a step" in report
+
+
+@pytest.mark.parametrize("direction, K, arrays, blocks, rows", [
+    ("anal", 4, 17, 2, 2056),       # the benchmark's cell
+    ("synth", 1, 13, 2, 2056),      # 1.6 x BLOCK_STEP_BYTES a step
+    ("synth", 4, 25, 3, 1368),
+])
+def test_folded_row_blocks_at_4096(direction, K, arrays, blocks, rows):
+    """At l_max 4096 in float32 a folded step streams 9 + 2K (analysis)
+    or 9 + 4K (synthesis) arrays of 2049 northern rings a row, and the
+    rows split into the block count nearest to the step's bytes over
+    BLOCK_STEP_BYTES."""
+    from repro.core import legendre
+    row_bytes = legendre.row_step_bytes(direction, fold=True, n_rings=2049,
+                                        K=K, dtype="float32")
+    assert row_bytes == arrays * 2049 * 4
+    got = legendre.loop_blocks(np.arange(4097), l_max=4096,
+                               row_bytes=row_bytes)
+    assert (got["blocks"], got["rows_per_block"]) == (blocks, rows)
+    assert legendre.planes_per_step(direction, fold=True) == 1
+
+
+@pytest.mark.parametrize("fold", [True, False])
+def test_failed_folded_pallas_layout_is_skipped_on_tpu(fold, monkeypatch):
+    """On a TPU a folded plan's pallas layout that fails to build is
+    skipped with its reason, as the staged VPU kernels are, and the auto
+    build ends; an unfolded plan's failure is still an error."""
+    def refuse(self, *a, **k):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(transform, "_on_tpu", lambda: True)
+    monkeypatch.setattr(transform.Plan, "_make_fused_anal", refuse)
+    monkeypatch.setattr(transform.Plan, "_make_fused_synth", refuse)
+    build = lambda: repro.make_plan("gl", l_max=10, K=3, dtype="float32",
+                                    mode="auto", fold=fold, cache="off")
+    if not fold:
+        with pytest.raises(RuntimeError, match="Mosaic refused"):
+            build()
+        return
+    plan = build()
+    skipped = plan.describe()["skipped"]
+    assert "Mosaic refused" in skipped["pallas_vpu[fused]"]
+    assert set(plan.backends) == {"synth", "anal"}
+    assert all(np.isfinite(plan.measured_s[b][d])
+               for d, b in plan.backends.items())
