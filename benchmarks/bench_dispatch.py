@@ -49,11 +49,11 @@ def main():
                 emit(f"dispatch/{direction}/lmax{l_max}-K{K}", meas * 1e6,
                      f"{chosen} (predicted {pred * 1e6:.1f}us)")
             else:
-                # chardb smoke mode skips corners missing from the DB (the
-                # decision falls back to the cost model, measured_s = inf);
+                # one device: auto takes the only candidate untimed (and
+                # chardb smoke mode skips corners missing from the DB);
                 # keep the trajectory numeric with the model's value
                 emit(f"dispatch/{direction}/lmax{l_max}-K{K}", pred * 1e6,
-                     f"{chosen} (model-fallback, unmeasured corner)")
+                     f"{chosen} (model, unmeasured)")
         emit(f"dispatch/make_plan-cold/lmax{l_max}-K{K}", t_cold * 1e6,
              f"warm x{t_cold / max(t_warm, 1e-9):.0f} faster")
 

@@ -1,10 +1,9 @@
 """Paper Fig. 15: relative speed-up across problem sizes -- via make_plan.
 
 The paper reports MPI/CUDA vs MPI/OpenMP speed-up per process count.  Our
-measurable analogue on this container: each plan backend vs the float64
+measurable analogue on this container: the float32 plan vs the float64
 jnp baseline for the full transform (both directions), plus the batched-K
-amortisation (the MXU story at the algorithmic level).  Every engine is
-reached through the unified Plan API -- no hand-wired kernels.
+amortisation.  Every engine is reached through the unified Plan API.
 
 Columns: name, us_per_call (optimised path), derived = speedup vs baseline.
 Every ratio comes from ONE paired interleaved loop (`common.time_pair`):
@@ -33,17 +32,16 @@ def main():
 
         alm32 = alm64.astype(jnp.complex64)
         maps32 = jnp.asarray(maps64, jnp.float32)
-        for mode in ("jnp", "pallas_vpu", "pallas_mxu"):
-            p = repro.make_plan("gl", l_max=l_max, K=1, dtype="float32",
-                                mode=mode)
-            tb_s, ts = time_pair(lambda: base.alm2map(alm64),
-                                 lambda: p.alm2map(alm32), iters=2)
-            tb_a, ta = time_pair(lambda: base.map2alm(maps64),
-                                 lambda: p.map2alm(maps32), iters=2)
-            emit(f"speedup/{mode}-f32-synth/lmax{l_max}", ts * 1e6,
-                 f"x{tb_s / ts:.2f} vs f64 jnp")
-            emit(f"speedup/{mode}-f32-anal/lmax{l_max}", ta * 1e6,
-                 f"x{tb_a / ta:.2f} vs f64 jnp")
+        p = repro.make_plan("gl", l_max=l_max, K=1, dtype="float32",
+                            mode="jnp")
+        tb_s, ts = time_pair(lambda: base.alm2map(alm64),
+                             lambda: p.alm2map(alm32), iters=2)
+        tb_a, ta = time_pair(lambda: base.map2alm(maps64),
+                             lambda: p.map2alm(maps32), iters=2)
+        emit(f"speedup/jnp-f32-synth/lmax{l_max}", ts * 1e6,
+             f"x{tb_s / ts:.2f} vs f64 jnp")
+        emit(f"speedup/jnp-f32-anal/lmax{l_max}", ta * 1e6,
+             f"x{tb_a / ta:.2f} vs f64 jnp")
 
         # fold optimisation through the plan layer (synthesis only)
         pf = repro.make_plan("gl", l_max=l_max, K=1, dtype="float64",

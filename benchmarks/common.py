@@ -41,8 +41,8 @@ def time_call(fn, *args, warmup=1, iters=3, **kw):
 def time_pair(fn_a, fn_b, warmup=1, iters=3):
     """Paired interleaved wall times -> (median_a, median_b) seconds.
 
-    Interpret-mode pallas wall times drift 30-40% between runs on a noisy
-    host, which makes two independent `time_call` measurements useless for
+    Host wall times drift 30-40% between runs on a noisy host, which
+    makes two independent `time_call` measurements useless for
     an A/B ratio.  Alternating A and B inside one loop exposes both to the
     same drift; the per-call medians stay comparable.
     """

@@ -72,13 +72,12 @@ def main(argv=None) -> None:
         # corners but never one-shot time missing ones (cost-model
         # fallback instead) -- see repro.roofline.chardb
         os.environ["REPRO_CHARDB_SMOKE"] = "1"
-    from benchmarks import (bench_accuracy, bench_recurrence,
-                            bench_scaling_model, bench_fft, bench_speedup,
-                            bench_dist_overlap, bench_dispatch, bench_spin,
-                            bench_serve)
+    from benchmarks import (bench_accuracy, bench_scaling_model, bench_fft,
+                            bench_speedup, bench_dist_overlap,
+                            bench_dispatch, bench_spin, bench_serve)
     print("name,us_per_call,derived")
     errors = {}
-    for mod in (bench_accuracy, bench_recurrence, bench_scaling_model,
+    for mod in (bench_accuracy, bench_scaling_model,
                 bench_fft, bench_speedup, bench_dist_overlap,
                 bench_dispatch, bench_spin, bench_serve):
         try:

@@ -8,32 +8,27 @@ One process drives the chip through the library's own entry points
 (``repro.make_plan``, ``Plan.alm2map``/``map2alm``, ``repro.serve.ShtEngine``)
 at the paper's widths (``repro.configs.sht_cmb``):
 
-  fused    the fused Legendre+phase Pallas pipeline, VPU and MXU variants
-           forced per plan, at ``synth_4k_k1`` (l_max=4096, K=1; MXU
-           synthesis) and ``anal_4k_k4`` (l_max=4096, K=4; MXU analysis);
+  reference the float32 jnp plan against a float64 numpy transform
+           written here, independent of the library (its own
+           Gauss-Legendre nodes and weights, checked against the plan's
+           grid), at l_max=512.
+  auto     one ``mode="auto"`` plan at ``synth_4k_k1`` (l_max=4096, K=1):
+           on one chip it takes the jnp backend without timing anything;
            the round-trip error D_err (paper eq. 19) of
            map2alm(alm2map(a)) on the exact-quadrature Gauss-Legendre grid
-           is checked for every kernel run.
-  reference the same kernels against a float64 numpy transform written
-           here, independent of the library (its own Gauss-Legendre
-           nodes and weights, checked against the plan's grid), at
-           l_max=512.
-  auto     one ``mode="auto"`` plan at ``synth_4k_k1``: every candidate
-           backend is measured (a slow backend's further layouts are
-           pruned, see ``repro.core.transform.PRUNE_FACTOR``); none may
-           fail.
+           is checked.
   engine   eight float32 alm2map requests at l_max=2048 coalesced into one
-           K=8 batch (``synth_2k_k8``); each result must equal the batch
-           plan's own column bit for bit, and agree with a K=1 plan run of
-           the same request.
+           K=8 batch (``synth_2k_k8``) by jnp plans; each result must
+           equal the batch plan's own column bit for bit, and agree with a
+           K=1 plan run of the same request.
   dist     (``--chips 4`` only) the distributed two-stage transform at
            ``anal_4k_k4`` (l_max=4096, K=4), with the plan's default
            exchange chunk count and, where that is not 1, with one chunk,
            against the one-chip jnp plan.
 
-Each phase prints its backend and layout, whether Pallas ran in interpret
-mode (it must not), the backend compile seconds, one warm call's seconds,
-its errors against their bounds and the device's peak bytes in use.  A
+Each phase prints its backend, the backend compile seconds, the seconds
+of its calls, its errors against their bounds and the device's peak
+bytes in use.  A
 failed phase prints its traceback, the remaining phases still run, and
 the script exits non-zero.  The last line of
 standard output is one JSON object naming the device; it is printed only
@@ -64,7 +59,7 @@ SEED = 0
 #: matmul's default precision) give ~3e-3, and an indexing or
 #: accumulation fault gives D_err of order 1.
 D_ERR_BOUND = 1e-3
-#: Kernels vs the float64 numpy reference at l_max=512, as the largest
+#: The jnp plan vs the float64 numpy reference at l_max=512, as the largest
 #: error over the map (or alm) relative to its largest value.  The float32
 #: transform on a CPU is off by 5.9e-4 to 1.2e-3 over two inputs: x =
 #: cos(theta) rounded to float32 keeps little of 1 - x on the polar rings.
@@ -76,8 +71,8 @@ ENGINE_BOUND = 1e-5
 #: Shape (l_max, K) of the reference phase: the numpy transform's cost
 #: grows as l_max^3, ~10 s here at 512.
 SMALL = (512, 2)
-#: Distributed plan vs the one-chip fused plan (both float32): two
-#: different kernel chains of the same transform, l_max=4096.
+#: Distributed plan vs the one-chip jnp plan (both float32): the same
+#: transform sharded and not, l_max=4096.
 DIST_BOUND = 1e-4
 
 
@@ -142,12 +137,10 @@ def timed(fn, *args):
 
 
 def device_fields() -> dict:
-    """Interpret-mode flag and the first device's peak bytes in use."""
+    """The first device's peak bytes in use."""
     import jax
-    from repro.kernels import ops
     stats = jax.devices()[0].memory_stats() or {}
-    return {"interpret": ops.should_interpret(),
-            "peak_bytes": stats.get("peak_bytes_in_use", "n/a")}
+    return {"peak_bytes": stats.get("peak_bytes_in_use", "n/a")}
 
 
 def release(*plans) -> None:
@@ -268,109 +261,47 @@ def _plan(l_max: int, K: int, mode: str, **kw):
                            mode=mode, **kw)
 
 
-def _fused_plan(tag: str, variant: str, l_max: int, K: int):
-    plan = _plan(l_max, K, f"pallas_{variant}")
-    if plan.layouts != {"synth": "fused", "anal": "fused"}:
-        raise AssertionError(f"{tag}: fused layout not chosen for {variant}: "
-                             f"{plan.layouts}")
-    return plan
-
-
 def _finite(tag: str, *arrays) -> None:
     if not all(np.isfinite(np.asarray(a)).all() for a in arrays):
         raise AssertionError(f"{tag}: non-finite output")
 
 
-def phase_fused(tag: str, l_max: int, K: int, mxu_direction: str, rng,
-                clock: CompileClock) -> None:
-    """Forced fused pipeline, VPU and MXU, round-trip D_err.
-
-    VPU: both directions, each compiled, then timed warm.  MXU: the one
-    direction the shape stands for (``mxu_direction``), one call with its
-    compile.  One MXU call takes ~68 s at l_max=4096 on a v5e, six times
-    the VPU kernel, so its warm calls are left to benchmarks.  The MXU
-    direction is checked by a round trip through the VPU kernel of the
-    other direction."""
-    from repro.core import spectra
-    vpu = _fused_plan(tag, "vpu", l_max, K)
-    mxu = _fused_plan(tag, "mxu", l_max, K)
-    alm = random_alm(rng, l_max, K)
-    clock.lap()
-    _, maps = timed(vpu.alm2map, alm)
-    c_synth = clock.lap()
-    t_synth, maps = timed(vpu.alm2map, alm)
-    _, back = timed(vpu.map2alm, maps)
-    c_anal = clock.lap()
-    t_anal, back = timed(vpu.map2alm, maps)
-    _finite(tag, maps, back)
-    log(f"fused/{tag}/vpu", backend=vpu.backends, layout=vpu.layouts,
-        compile_synth_s=f"{c_synth:.1f}", compile_anal_s=f"{c_anal:.1f}",
-        warm_synth_s=f"{t_synth:.4f}", warm_anal_s=f"{t_anal:.4f}",
-        d_err=check(f"{tag} vpu D_err", spectra.d_err(alm, back),
-                    D_ERR_BOUND),
-        **device_fields())
-    if mxu_direction == "synth":
-        t_first, maps = timed(mxu.alm2map, alm)
-        c_mxu = clock.lap()
-        back = vpu.map2alm(maps)
-        trip = "vpu.map2alm(mxu.alm2map(a))"
-    else:
-        t_first, back = timed(mxu.map2alm, maps)
-        c_mxu = clock.lap()
-        trip = "mxu.map2alm(vpu.alm2map(a))"
-    _finite(tag, maps, back)
-    log(f"fused/{tag}/mxu", backend=mxu.backends, layout=mxu.layouts,
-        direction=mxu_direction, compile_s=f"{c_mxu:.1f}",
-        first_call_s=f"{t_first:.4f}", round_trip=trip,
-        d_err=check(f"{tag} mxu D_err", spectra.d_err(alm, back),
-                    D_ERR_BOUND),
-        **device_fields())
-    release(vpu, mxu)
-
-
 def phase_reference(l_max: int, K: int, rng, clock: CompileClock) -> None:
-    """Fused kernels vs the float64 numpy transform on the same input,
-    on a grid computed here and checked against the plans'."""
+    """The float32 jnp plan vs the float64 numpy transform on the same
+    input, on a grid computed here and checked against the plan's."""
     alm = random_alm(rng, l_max, K)
-    plans = {v: _plan(l_max, K, f"pallas_{v}") for v in ("vpu", "mxu")}
+    plan = _plan(l_max, K, "jnp")
     geo = gl_geometry(l_max)
-    for plan in plans.values():
-        check_grid(plan.grid, geo)
+    check_grid(plan.grid, geo)
     ref_maps = numpy_synth(alm, geo)
     ref_alm = numpy_anal(ref_maps, geo, l_max)
-    maps32 = ref_maps.astype(np.float32)
-    for v, plan in plans.items():
-        clock.lap()
-        maps = np.asarray(plan.alm2map(alm))
-        alm_k = np.asarray(plan.map2alm(maps32))
-        log(f"reference/lmax{l_max}/{v}", layout=plan.layouts,
-            compile_s=f"{clock.lap():.1f}",
-            synth_err=check(f"{v} synth vs float64", rel_err(maps, ref_maps),
-                            REF_BOUND),
-            anal_err=check(f"{v} anal vs float64", rel_err(alm_k, ref_alm),
-                           REF_BOUND),
-            **device_fields())
-    release(*plans.values())
+    clock.lap()
+    maps = np.asarray(plan.alm2map(alm))
+    alm_k = np.asarray(plan.map2alm(ref_maps.astype(np.float32)))
+    log(f"reference/lmax{l_max}/jnp", fold=plan.fold,
+        compile_s=f"{clock.lap():.1f}",
+        synth_err=check("synth vs float64", rel_err(maps, ref_maps),
+                        REF_BOUND),
+        anal_err=check("anal vs float64", rel_err(alm_k, ref_alm),
+                       REF_BOUND),
+        **device_fields())
+    release(plan)
 
 
 def phase_auto(l_max: int, K: int, rng, clock: CompileClock) -> None:
-    """One autotuned plan: every candidate measured, none failing."""
+    """One ``mode="auto"`` plan on one chip: the jnp backend, chosen
+    without a timing call."""
     from repro.core import spectra
     clock.lap()
     t_build, plan = timed(lambda: _plan(l_max, K, "auto"))
     d = plan.describe()
-    table = d["measured_s"]
-    for b, row in table.items():
-        log(f"auto/lmax{l_max}_k{K}/candidate", backend=b,
-            **{k: (f"{v:.4f}" if isinstance(v, float) else v)
-               for k, v in row.items()})
-    errors = {f"{b}.{k}": v for b, row in table.items()
-              for k, v in row.items() if k.endswith("_error")}
-    if errors:
-        raise AssertionError(f"auto: candidates failed: {errors}")
+    if d["backends"] != {"synth": "jnp", "anal": "jnp"} or d["measured_s"]:
+        raise AssertionError(f"auto: expected jnp, untimed: {d['backends']} "
+                             f"{d['measured_s']}")
     alm = random_alm(rng, l_max, K)
     _, back = timed(lambda a: plan.map2alm(plan.alm2map(a)), alm)
-    log(f"auto/lmax{l_max}_k{K}", backend=d["backends"], layout=d["layouts"],
+    log(f"auto/lmax{l_max}_k{K}", backend=d["backends"],
+        decision=d["cache"]["events"].get("decision"),
         skipped=sorted(d["skipped"]), build_s=f"{t_build:.1f}",
         compile_s=f"{clock.lap():.1f}",
         d_err=check("auto D_err", spectra.d_err(alm, back), D_ERR_BOUND),
@@ -404,7 +335,7 @@ def phase_engine(l_max: int, k: int, mode: str, rng,
     err = max(rel_err(r, single.alm2map(a[..., None])[..., 0])
               for r, a in zip(results, alms))
     log(f"engine/lmax{l_max}_k{k}", mode=mode,
-        layout=batch_plan.layouts, batches=batches,
+        backend=batch_plan.backends, batches=batches,
         bit_identical_to_batch_plan=identical,
         compile_s=f"{c_engine:.1f}", serve_s=f"{t_serve:.4f}",
         vs_k1_plans=check("engine vs K=1 plans", err, ENGINE_BOUND),
@@ -424,7 +355,7 @@ def phase_dist(l_max: int, K: int, rng, clock: CompileClock) -> None:
     clock.lap()
     maps_ref = np.asarray(ref.alm2map(alm))
     alm_ref = np.asarray(ref.map2alm(maps_ref))
-    log(f"dist/lmax{l_max}_k{K}/one-chip", layout=ref.layouts,
+    log(f"dist/lmax{l_max}_k{K}/one-chip", fold=ref.fold,
         compile_s=f"{clock.lap():.1f}", **device_fields())
     release(ref)
     default = _plan(l_max, K, "dist").comm_chunks
@@ -443,7 +374,7 @@ def phase_dist(l_max: int, K: int, rng, clock: CompileClock) -> None:
         peaks = [(dv.memory_stats() or {}).get("peak_bytes_in_use")
                  for dv in jax.devices()]
         log(f"dist/lmax{l_max}_k{K}/chunks={chunks}",
-            comm_chunks=plan.comm_chunks, stage1=eng.stage1,
+            comm_chunks=plan.comm_chunks,
             operand_sharding=operand.sharding.spec, operand_shards=shards,
             compile_s=f"{c:.1f}", first_synth_s=f"{t_synth:.4f}",
             first_anal_s=f"{t_anal:.4f}",
@@ -461,19 +392,13 @@ def phase_dist(l_max: int, K: int, rng, clock: CompileClock) -> None:
 def one_chip_phases():
     """(name, thunk(rng, clock)) for every one-chip phase, in run order."""
     from repro.configs.sht_cmb import SHT_SHAPES
-    phases = []
-    for tag, direction in (("synth_4k_k1", "synth"), ("anal_4k_k4", "anal")):
-        cfg = SHT_SHAPES[tag]
-        phases.append((f"fused/{tag}",
-                       lambda r, c, t=tag, d=direction, s=cfg:
-                       phase_fused(t, s.l_max, s.K, d, r, c)))
-    phases.append(("reference", lambda r, c: phase_reference(*SMALL, r, c)))
+    phases = [("reference", lambda r, c: phase_reference(*SMALL, r, c))]
     auto = SHT_SHAPES["synth_4k_k1"]
     phases.append(("auto", lambda r, c: phase_auto(auto.l_max, auto.K,
                                                    r, c)))
     eng = SHT_SHAPES["synth_2k_k8"]
     phases.append(("engine", lambda r, c: phase_engine(
-        eng.l_max, eng.K, "pallas_vpu", r, c)))
+        eng.l_max, eng.K, "jnp", r, c)))
     return phases
 
 

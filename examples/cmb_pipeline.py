@@ -7,7 +7,7 @@ actual workload: spin-weighted polarised transforms):
   pseudo-C_l estimation (TT, EE, BB, TE) against the inputs.
 
 Both plans dispatch through ``repro.make_plan`` -- the spin-2 plan runs the
-same backend menu (jnp | pallas_vpu | pallas_mxu | dist) as the scalar one.
+same backend menu (jnp | dist) as the scalar one.
 Set XLA_FLAGS=--xla_force_host_platform_device_count=8 to exercise the
 distributed two-stage transforms on CPU.
 

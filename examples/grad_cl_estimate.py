@@ -3,8 +3,8 @@
 The workload the adjoint-based custom VJP rules unlock: fit spherical-
 harmonic coefficients to an observed (noisy) map by gradient descent on a
 pixel-space chi^2 -- ``jax.grad`` flows through ``Plan.alm2map`` via the
-adjoint transform (synthesis VJP = weighted analysis), so every backend
-(jnp, pallas_vpu, pallas_mxu, dist) is usable inside the optimizer loop --
+adjoint transform (synthesis VJP = weighted analysis), so either backend
+(jnp, dist) is usable inside the optimizer loop --
 then read the angular power spectrum off the fitted coefficients.
 
 On the exact Gauss-Legendre grid the normal equations are perfectly
@@ -38,7 +38,7 @@ def main():
                     choices=["float64", "float32"],
                     help="default: float64 on a CPU, float32 on a TPU")
     ap.add_argument("--mode", default="auto",
-                    help="auto | model | jnp | pallas_vpu | pallas_mxu | dist")
+                    help="auto | model | jnp | dist")
     a = ap.parse_args()
     if a.dtype is None:
         a.dtype = "float32" if jax.default_backend() == "tpu" else "float64"

@@ -1,6 +1,6 @@
 """Quickstart: the paper's validation experiment through the Plan API.
 
-Builds a transform plan (autotuned kernel dispatch + cached precompute),
+Builds a transform plan (backend dispatch + cached precompute),
 synthesises a map from random a_lm (inverse SHT), analyses it back (direct
 SHT), and reports the round-trip error D_err (paper eq. 19) -- on the
 exact Gauss-Legendre grid this sits at machine precision.
@@ -23,17 +23,16 @@ def main():
     ap.add_argument("--K", type=int, default=2, help="simultaneous maps")
     ap.add_argument("--dtype", default=None,
                     choices=["float64", "float32"],
-                    help="float32 enables the Pallas kernel backends "
-                         "(default: float64 on a CPU, float32 on a TPU)")
+                    help="default: float64 on a CPU, float32 on a TPU")
     ap.add_argument("--mode", default="auto",
-                    help="auto | model | jnp | pallas_vpu | pallas_mxu | dist")
+                    help="auto | model | jnp | dist")
     a = ap.parse_args()
     if a.dtype is None:
         a.dtype = "float32" if jax.default_backend() == "tpu" else "float64"
     if a.dtype == "float64":    # the float64 oracle needs JAX's 64-bit mode
         jax.config.update("jax_enable_x64", True)
 
-    # One entry point: the plan owns precompute, layout and kernel choice.
+    # One entry point: the plan owns precompute and backend choice.
     # A second make_plan with this signature returns the same (cached) plan.
     plan = repro.make_plan(a.grid, l_max=a.lmax,
                            nside=max(a.lmax // 2, 1),

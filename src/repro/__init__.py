@@ -1,7 +1,7 @@
 """repro: Parallel Spherical Harmonic Transforms as a multi-pod JAX framework.
 
 Implements Szydlarski et al. (INRIA RR-7635) -- the two-stage distributed SHT
-with intra-node acceleration -- adapted to TPU (shard_map + Pallas), together
+with intra-node acceleration -- adapted to TPU (shard_map + jnp), together
 with the assigned 10-architecture LM model zoo, training/serving substrate,
 multi-pod dry-run and roofline tooling.  See DESIGN.md.
 
@@ -18,8 +18,7 @@ def __getattr__(name):
     """Lazy top-level API: ``repro.make_plan`` / ``repro.Plan``.
 
     Imported on first use so ``import repro`` stays light (the transform
-    layer pulls in the SHT engine; the Pallas kernels are only imported if
-    a plan actually selects them).
+    layer pulls in the SHT engine).
     """
     if name in ("make_plan", "Plan", "available_backends",
                 "backend_eligibility", "clear_plan_cache"):

@@ -14,8 +14,6 @@ same layer:
   Legendre analysis (w)        w * Legendre synthesis
   phase synthesis              fac_m * phase analysis / weights
   phase analysis               phase synthesis(w * cotangent / fac_m)
-  Pallas kernel synth          Pallas kernel anal (same schedule)
-  Pallas kernel anal           Pallas kernel synth (same schedule)
   ===========================  =======================================
 
 :func:`linear_pair` packages one such (forward, transpose) pair as a
@@ -26,9 +24,8 @@ function that is differentiable in both modes:
 * **VJP** (reverse mode): the tangent-side computation is expressed with
   :func:`jax.custom_derivatives.linear_call`, whose registered transpose
   rule invokes the supplied adjoint -- so ``jax.grad`` calls the
-  opposite-direction transform instead of transposing kernel internals
-  (Pallas kernels are not transposable at all; for the jnp engine this
-  also avoids storing one recurrence panel per multipole).
+  opposite-direction transform instead of transposing loop internals
+  (which also avoids storing one recurrence panel per multipole).
 
 Contract
 --------

@@ -42,8 +42,6 @@ __all__ = [
 
 #: Bump when the payload layout of any cached builder changes; old disk
 #: entries are then simply never matched (keys embed the version).
-#: v2: autotune decisions gained the "fused" layout (PR 7) -- v1 decisions
-#: would pin plans to staged-only choices.
 CACHE_VERSION = 2
 
 _MEMORY: dict[str, dict[str, np.ndarray]] = {}

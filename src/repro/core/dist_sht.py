@@ -22,7 +22,6 @@ Design notes (DESIGN.md §2):
 * `comm_dtype` optionally down-casts the Delta exchange (e.g. bfloat16) --
   the paper explicitly leaves lossy-compressed communication to future work
   (§4.1.2); we implement it and measure the accuracy cost in tests.
-* `stage1` selects the jnp reference path or the Pallas kernel path.
 * `comm_chunks = C > 1` replaces the monolithic exchange with a chunked,
   software-pipelined one: the Delta block is split into C chunks along the
   K map-batch axis (or the local m rows when K is too small, see
@@ -83,7 +82,6 @@ class DistSHT:
     dtype: str = "float64"
     fold: bool = False
     comm_dtype: Optional[str] = None      # e.g. "bfloat16" for compressed Delta
-    stage1: str = "jnp"                    # "jnp" | "pallas"
     comm_chunks: Optional[int] = None      # None -> plan.comm_chunks; C>1 =
                                            # chunked pipelined exchange
 
@@ -134,11 +132,6 @@ class DistSHT:
         """
         p = self.plan
         dt = jnp.dtype(self.dtype)
-        if self.stage1 == "pallas":
-            from repro.kernels import ops as kops
-            return kops.delta_from_alm_auto(
-                a_re, a_im, m_loc, self._geom, self._log_mu,
-                l_max=p.l_max, fold=self.fold, dtype=dt)
         g = self._geom
         if not self.fold:
             return legendre.delta_from_alm(
@@ -159,11 +152,6 @@ class DistSHT:
         p = self.plan
         dt = jnp.dtype(self.dtype)
         g = self._geom
-        if self.stage1 == "pallas":
-            from repro.kernels import ops as kops
-            return kops.alm_from_delta_auto(
-                dw_re, dw_im, m_loc, g, self._log_mu,
-                l_max=p.l_max, fold=self.fold, dtype=dt)
         if not self.fold:
             ones = np.ones(p.r_pad)
             return legendre.alm_from_delta(
@@ -185,11 +173,6 @@ class DistSHT:
         p = self.plan
         dt = jnp.dtype(self.dtype)
         g = self._geom
-        if self.stage1 == "pallas":
-            from repro.kernels import ops as kops
-            return kops.delta_from_alm_spin_auto(
-                e_re, e_im, b_re, b_im, m_loc, g, l_max=p.l_max,
-                m_max=p.m_max, dtype=dt)
         return legendre.delta_from_alm_spin(
             e_re, e_im, b_re, b_im, m_loc, g["cos_theta"], g["sin_theta"],
             l_max=p.l_max, m_max=p.m_max, dtype=dt)
@@ -200,11 +183,6 @@ class DistSHT:
         p = self.plan
         dt = jnp.dtype(self.dtype)
         g = self._geom
-        if self.stage1 == "pallas":
-            from repro.kernels import ops as kops
-            return kops.alm_from_delta_spin_auto(
-                dq_re, dq_im, du_re, du_im, m_loc, g, l_max=p.l_max,
-                m_max=p.m_max, dtype=dt)
         return legendre.alm_from_delta_spin(
             dq_re, dq_im, du_re, du_im, m_loc, g["cos_theta"],
             g["sin_theta"], l_max=p.l_max, m_max=p.m_max, dtype=dt)

@@ -22,7 +22,7 @@ Implements the paper's §2.1 machinery in a vectorised, branch-free form:
   of the paper's scheme (DESIGN.md §2).
 
 The recurrence is pure jnp and dtype-parametric: float64 for the
-reference/validation engine, float32 matching the Pallas kernel numerics.
+reference/validation engine, float32 for the device.
 The seeds are precomputed on the host in numpy float64 and cast at the end,
 so no float64 arithmetic runs on the device.
 """
@@ -1075,8 +1075,8 @@ def alm_from_delta_general(d_re, d_im, m_vals, mprime_vals, grid_x, grid_sin,
 # ---------------------------------------------------------------------------
 # Spin-2 component packing: (E, B) <-> a^+- = -(E +- iB), stacked along the
 # row axis as [m' = -2 rows | m' = +2 rows], and (Delta_Q, Delta_U) <->
-# Delta^+- = Delta_Q +- i Delta_U.  Shared by the f64 engine, the Pallas
-# wrappers and the distributed transform (all dtypes, any trailing dims).
+# Delta^+- = Delta_Q +- i Delta_U.  Shared by the serial engine and the
+# distributed transform (all dtypes, any trailing dims).
 # ---------------------------------------------------------------------------
 
 

@@ -31,13 +31,11 @@ that phase stage, with two device-resident engines:
 
 Both engines are expressed as trace-friendly functions taking the ring
 geometry (phi0, weights, n_phi) and the index maps as *arguments*, so the
-same code serves three callers:
+same code serves two callers:
 
   * the serial engine (`core.sht.SHT`) via the `UniformPhase`/`BucketPhase`
     classes built by :func:`make_phase` (geometry closed over as numpy
     constants -- free under jit);
-  * the Pallas backends (`core.transform`), which reuse the serial plan's
-    phase object after their kernel Legendre stage;
   * the distributed transform (`core.dist_sht`), which passes *sharded*
     geometry/index-map operands inside shard_map (every shard runs the
     same bucket structure by construction -- see SHTPlan.local_fft_layout).
@@ -62,8 +60,7 @@ from repro.tracing import PHASE, scoped
 
 __all__ = [
     "uniform_synth", "uniform_anal", "bucket_synth", "bucket_anal",
-    "bucket_bin_maps", "uniform_bin_maps", "uniform_rotation_tables",
-    "bucket_rotation_tables", "phase_factors",
+    "bucket_bin_maps", "uniform_bin_maps", "phase_factors",
     "PhaseStage", "UniformPhase", "BucketPhase", "make_phase",
 ]
 
@@ -122,82 +119,13 @@ def uniform_bin_maps(m_vals, n):
     Returns ``(bins, hi, nyq)``: the rfft half-spectrum bin each m row
     lands in, whether it wraps onto the conjugate half (``hi``: scatter /
     gather the conjugate), and whether it sits on the Nyquist bin (real
-    part doubles on synthesis).  Shared by the host engine below and by
-    the fused Legendre+phase kernels (kernels/fused.py), which bake the
-    same maps into their per-slot rotation tables."""
+    part doubles on synthesis)."""
     m = np.asarray(m_vals)
     b = np.maximum(m, 0) % n
     hi = b > n // 2                                # conjugate wrap
     bins = np.where(hi, n - b, b)
     nyq = 2 * b == n                               # Nyquist: real part doubles
     return bins, hi, nyq
-
-
-def uniform_rotation_tables(m_vals, phi0, n, direction):
-    """Real 2x2 per-(row, ring) phase-rotation tables, (M, 4, R) f64 numpy.
-
-    Encodes the uniform engine's e^{+-i m phi0(r)} rotation *and* the
-    conjugate-wrap / Nyquist handling of :func:`uniform_bin_maps` as a real
-    linear map so the fused kernels can apply the phase stage in-kernel:
-
-        h_re = t0 * d_re + t1 * d_im
-        h_im = t2 * d_re + t3 * d_im
-
-    ``direction`` is ``"synth"`` (Delta -> half-spectrum row, sign +1,
-    conjugate scattered for hi rows, doubled real part on Nyquist) or
-    ``"anal"`` (gathered half-spectrum row -> Delta, sign -1, conjugate
-    gathered for hi rows; no Nyquist term -- exactly the host engine's
-    math).  Rows with m < 0 are zeroed like :func:`phase_factors`."""
-    m = np.asarray(m_vals)
-    bins, hi, nyq = uniform_bin_maps(m, n)
-    msafe = np.maximum(m, 0).astype(np.float64)
-    ang = msafe[:, None] * np.asarray(phi0, np.float64)[None, :]
-    c, s = np.cos(ang), np.sin(ang)
-    hi_c = hi[:, None]
-    if direction == "synth":
-        ta, tb = c, -s
-        tc = np.where(hi_c, -s, s)
-        td = np.where(hi_c, -c, c)
-        nyq_c = nyq[:, None]
-        ta = np.where(nyq_c, 2.0 * c, ta)
-        tb = np.where(nyq_c, -2.0 * s, tb)
-        tc = np.where(nyq_c, 0.0, tc)
-        td = np.where(nyq_c, 0.0, td)
-    elif direction == "anal":
-        ta = c
-        tb = np.where(hi_c, -s, s)
-        tc = -s
-        td = np.where(hi_c, -c, c)
-    else:
-        raise ValueError(f"unknown direction {direction!r}")
-    t = np.stack([ta, tb, tc, td], axis=1)         # (M, 4, R)
-    return np.where((m >= 0)[:, None, None], t, 0.0)
-
-
-def bucket_rotation_tables(m_vals, phi0, direction):
-    """Real 2x2 per-(row, ring) phase tables for the bucket engine,
-    (M, 4, R) f64 numpy.
-
-    Unlike :func:`uniform_rotation_tables` there is no conjugate-wrap or
-    Nyquist folding here -- the bucket engine's alias fold is a pure index
-    map (:func:`bucket_bin_maps`), applied by the host-side scatter/gather
-    around the fused kernels.  The tables only encode e^{+-i m phi0(r)}:
-
-        synth  h = e^{+i m phi0} d   ->  (c, -s, s, c)
-        anal   d = e^{-i m phi0} f   ->  (c, s, -s, c)
-
-    Rows with m < 0 are zeroed like :func:`phase_factors`."""
-    m = np.asarray(m_vals)
-    msafe = np.maximum(m, 0).astype(np.float64)
-    ang = msafe[:, None] * np.asarray(phi0, np.float64)[None, :]
-    c, s = np.cos(ang), np.sin(ang)
-    if direction == "synth":
-        t = np.stack([c, -s, s, c], axis=1)
-    elif direction == "anal":
-        t = np.stack([c, s, -s, c], axis=1)
-    else:
-        raise ValueError(f"unknown direction {direction!r}")
-    return np.where((m >= 0)[:, None, None], t, 0.0)
 
 
 def _uniform_synth_body(d_re, d_im, phi0, scale_rows, m, n, dtype):
@@ -461,7 +389,7 @@ def bucket_anal(maps, layout: BucketLayout, pos, n_phi, phi0, weights,
 
 
 # ---------------------------------------------------------------------------
-# grid-bound phase-stage objects (the serial/Pallas integration point)
+# grid-bound phase-stage objects (the serial engine's integration point)
 # ---------------------------------------------------------------------------
 
 

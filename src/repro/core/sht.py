@@ -10,14 +10,14 @@ Implements paper Algorithms 1 & 2 for iso-latitude grids:
       Delta^S_m(r) = sum_j w_r s(r, phi_j) e^{-i m phi_j}  (FFT stage)
       a_lm         = sum_r Delta^S_m(r) P_lm(cos theta_r)  (Legendre stage)
 
-This module is the *oracle*: float64 by default, used by every test.  The
-Pallas kernels (repro.kernels) and the distributed transforms
-(repro.core.dist_sht) are validated against it.
+This module is the *oracle*: float64 by default, used by every test, and
+in float32 the one-device backend of every plan.  The distributed
+transforms (repro.core.dist_sht) are validated against it.
 
 The FFT stage is NOT implemented here: it lives in the pluggable phase
 layer (`repro.core.phase`), which picks the batched-uniform engine or the
-ring-bucket engine (true ragged HEALPix) per grid.  The oracle, the Pallas
-backends and the distributed transform all share that one implementation.
+ring-bucket engine (true ragged HEALPix) per grid.  This engine and the
+distributed transform share that one implementation.
 
 Conventions
 -----------
@@ -155,7 +155,7 @@ class SHT:
     def _m_all(self) -> np.ndarray:
         return np.arange(self.m_max + 1)
 
-    # -- FFT/phase stage (pluggable, shared with Pallas and dist paths) -----
+    # -- FFT/phase stage (pluggable, shared with the dist path) ------------
 
     @functools.cached_property
     def phase(self):

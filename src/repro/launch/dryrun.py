@@ -263,7 +263,7 @@ def lower_lm_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def lower_sht_cell(shape_name: str, multi_pod: bool, *, fold=False,
-                   comm_dtype=None, stage1="jnp", variant=None):
+                   comm_dtype=None):
     from repro.configs.sht_cmb import SHT_SHAPES
     from repro.core import grids, plan as planlib, dist_sht
     scfg = SHT_SHAPES[shape_name]
@@ -273,11 +273,8 @@ def lower_sht_cell(shape_name: str, multi_pod: bool, *, fold=False,
     n_dev = mesh.size
     g = grids.make_grid("gl", l_max=scfg.l_max)
     p = planlib.SHTPlan(g, scfg.l_max, scfg.l_max, n_dev)
-    if variant is not None:
-        os.environ["REPRO_LEGENDRE_VARIANT"] = variant
     d = dist_sht.DistSHT(p, mesh, sht_axis_names(mesh), dtype=scfg.dtype,
-                         fold=scfg.fold, comm_dtype=scfg.comm_dtype,
-                         stage1=stage1)
+                         fold=scfg.fold, comm_dtype=scfg.comm_dtype)
     if scfg.direction == "synth":
         lowered, _ = d.lower_synth(scfg.K)
     else:
@@ -325,7 +322,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
     tag = f"{arch}__{shape_name}__{mesh_kind}"
     if sht_kw:
         extras = "_".join(f"{k}-{v}" for k, v in sorted(sht_kw.items())
-                          if v not in (None, False, "jnp"))
+                          if v not in (None, False))
         if extras:
             tag += "__" + extras
     path = os.path.join(out_dir, tag + ".json")
@@ -408,8 +405,6 @@ def main():
     ap.add_argument("--no-account", action="store_true")
     ap.add_argument("--fold", action="store_true")
     ap.add_argument("--comm-dtype", default=None)
-    ap.add_argument("--stage1", default="jnp")
-    ap.add_argument("--variant", default=None)
     ap.add_argument("--profile", default=None,
                     help="override tp_profile (tp|small|dp) for perf iters")
     ap.add_argument("--moe-impl", default=None, choices=[None, "a2a",
@@ -417,8 +412,7 @@ def main():
     a = ap.parse_args()
     kw = {}
     if a.arch == "sht_cmb":
-        kw = dict(fold=a.fold, comm_dtype=a.comm_dtype, stage1=a.stage1,
-                  variant=a.variant)
+        kw = dict(fold=a.fold, comm_dtype=a.comm_dtype)
     else:
         if a.profile:
             kw["profile"] = a.profile

@@ -27,7 +27,7 @@ def main():
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--mode", default="jnp",
                     help="plan dispatch mode for pooled plans "
-                         "(jnp | auto | model | pallas_*)")
+                         "(jnp | auto | model | dist)")
     ap.add_argument("--p99-target-ms", type=float, default=None,
                     help="roofline admission: cap each group's coalesced "
                          "K to fit this tail-latency target")

@@ -38,10 +38,10 @@ __all__ = ["default_model", "k_caps_for_target"]
 
 def default_model() -> tuple:
     """(backend, Hardware) the admission model should price against on
-    this host: the f64 jnp oracle on CPU, the MXU pipeline on devices
-    (peaks from `analysis.PEAKS`; an unlisted device raises)."""
-    hw = hardware_for()
-    return ("jnp" if hw.name == "host-cpu" else "pallas_mxu"), hw
+    this host: the jnp backend, which the engine's plans run on one
+    device, at this host's peaks (`analysis.PEAKS`; an unlisted device
+    raises)."""
+    return "jnp", hardware_for()
 
 
 def k_caps_for_target(*, l_max: int, n_rings: int, n_phi: int, max_k: int,

@@ -25,7 +25,7 @@ import numpy as np
 
 __all__ = ["HW_V5E", "HW_HOST", "PEAKS", "hardware_for", "Roofline",
            "collective_bytes", "analyze_compiled", "parse_hlo_collectives",
-           "sht_work", "legendre_panel_counts", "predict_sht_time",
+           "sht_work", "predict_sht_time",
            "predict_comm_chunks", "BACKEND_MODELS", "BackendModel"]
 
 
@@ -120,31 +120,7 @@ def sht_work(l_max: int, m_max: int, n_rings: int, n_phi: int,
             + 16.0 * (m_max + 1) * n_rings * K * ncomp)    # Delta (complex)
     return {"n_lm": n_lm, "recurrence_flops": rec, "accum_flops": acc,
             "fft_flops": fft, "bytes": byts,
-            "total_flops": rec + acc + fft,
-            # Legendre grid-step accounting (plain vs packed kernel grids);
-            # the dispatch layer uses this to model packed-vs-plain honestly.
-            "panels": legendre_panel_counts(l_max, m_max, spin=spin)}
-
-
-def legendre_panel_counts(l_max: int, m_max: int, *, lp_size: int = 128,
-                          spin: int = 0) -> dict:
-    """Grid-step accounting of the Legendre stage, plain vs packed.
-
-    Delegates to `repro.kernels.pack.panel_counts` on the canonical row
-    set (``m = 0..m_max``; doubled ``m' = -+2`` rows for ``spin=2``) so the
-    cost model and the kernels agree by construction.  Keys:
-    ``plain_launched`` (dense grid steps, all paying launch latency),
-    ``plain_worked`` (steps passing the ``pl.when`` diagonal test),
-    ``packed`` (packed grid steps -- every one works), ``ideal_steps``
-    (the paper's triangular invariant) and the derived ratios.
-    """
-    from repro.kernels import pack
-    m = np.arange(m_max + 1)
-    if spin:
-        m2 = np.concatenate([m, m])
-        mp2 = np.concatenate([np.full(m_max + 1, -2), np.full(m_max + 1, 2)])
-        return pack.panel_counts(m2, l_max, lp_size=lp_size, mp_vals=mp2)
-    return pack.panel_counts(m, l_max, lp_size=lp_size)
+            "total_flops": rec + acc + fft}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,9 +131,8 @@ class BackendModel:
     achieved on vector (VPU/scalar) and matrix (MXU) work; ``matrix_eff = 0``
     means the accumulation runs on the vector unit too.  ``anal_penalty``
     models the paper's direct/inverse dichotomy (§5): the analysis direction
-    pays extra for its ring reduction (the paper's Algorithm 5 atomics; our
-    sequential-grid accumulation), so the same backend may win synthesis and
-    lose analysis.
+    pays extra for its ring reduction (the paper's Algorithm 5 atomics), so
+    the same backend may win synthesis and lose analysis.
     """
 
     name: str
@@ -167,15 +142,14 @@ class BackendModel:
 
 
 BACKEND_MODELS = {
-    # float64 un-fused HLO ops: correct but memory-bound.
+    # the jnp loop: separate HLO ops, memory-bound.
     "jnp": BackendModel("jnp", vector_eff=0.01, anal_penalty=1.0),
-    # broadcast-FMA kernel: good vector efficiency, no MXU use.
-    "pallas_vpu": BackendModel("pallas_vpu", vector_eff=0.08,
-                               anal_penalty=1.3),
-    # panel matmul: accumulation on the MXU, recurrence still vector work.
-    "pallas_mxu": BackendModel("pallas_mxu", vector_eff=0.06, matrix_eff=0.4,
-                               anal_penalty=1.2),
-    # dist = best local kernel / n_devices + one all_to_all on the wire.
+    # dist = one device's share of the work + one all_to_all on the wire.
+    # Its efficiencies are the old guesses for an MXU Legendre stage, not
+    # the jnp loop that dist now runs (which "jnp" prices at 0.01), so
+    # mode="model" ranks the two inconsistently.  They are kept because
+    # predict_comm_chunks' C = 1 pick for the cells depends on them;
+    # ROADMAP C5 replaces them with calibrated ones.
     "dist": BackendModel("dist", vector_eff=0.06, matrix_eff=0.4,
                          anal_penalty=1.2),
 }
@@ -184,8 +158,7 @@ BACKEND_MODELS = {
 def predict_sht_time(backend: str, *, l_max: int, m_max: int, n_rings: int,
                      n_phi: int, K: int, direction: str = "synth",
                      hw: Hardware = HW_V5E, n_devices: int = 1,
-                     fft_lengths=None, spin: int = 0, layout: str = None,
-                     lp_size: int = 128, pipeline: str = "staged",
+                     fft_lengths=None, spin: int = 0,
                      overlap: bool = False, comm_chunks: int = 1) -> float:
     """Predicted seconds for one transform on ``backend`` (3-term model).
 
@@ -196,17 +169,6 @@ def predict_sht_time(backend: str, *, l_max: int, m_max: int, n_rings: int,
     ``direction="anal"``.  ``fft_lengths`` carries a ragged grid's
     per-ring bucket lengths into the FFT term; ``spin=2`` doubles every
     term including the exchanged Delta block (see `sht_work`).
-
-    ``layout`` ("plain" | "packed", pallas backends only) scales the
-    Legendre terms by that grid's executed-step overhead over the ideal
-    triangular count (`legendre_panel_counts`), so the packed-vs-plain
-    dispatch decision is modelled honestly.
-
-    ``pipeline="fused"`` (pallas backends only) models the single-kernel
-    Legendre+phase pipeline (`repro.kernels.fused`): the intermediate
-    Delta block never round-trips HBM, so its bytes term is dropped --
-    the fused pipeline's advantage in this model is purely the removed
-    memory traffic (the flop terms are identical).
 
     ``overlap=True`` with ``comm_chunks=C > 1`` (dist backend only) models
     the chunked software-pipelined exchange (`DistSHT(comm_chunks=C)`):
@@ -224,31 +186,16 @@ def predict_sht_time(backend: str, *, l_max: int, m_max: int, n_rings: int,
     """
     if backend not in BACKEND_MODELS:
         raise ValueError(f"unknown backend {backend!r}")
-    if pipeline not in ("staged", "fused"):
-        raise ValueError(f"unknown pipeline {pipeline!r}")
     m = BACKEND_MODELS[backend]
     w = sht_work(l_max, m_max, n_rings, n_phi, K, fft_lengths=fft_lengths,
                  spin=spin)
-    byts = w["bytes"]
-    if pipeline == "fused" and backend.startswith("pallas"):
-        ncomp = 1 if spin == 0 else 2
-        byts -= 16.0 * (m_max + 1) * n_rings * K * ncomp   # Delta stays on-chip
-    leg_scale = 1.0
-    if layout in ("plain", "packed") and backend.startswith("pallas"):
-        pc = w["panels"] if lp_size == 128 else legendre_panel_counts(
-            l_max, m_max, lp_size=lp_size, spin=spin)
-        steps = (pc["plain_worked"] if layout == "plain" else pc["packed"]) \
-            * pc["lp_size"]
-        if pc["ideal_steps"] > 0:
-            leg_scale = steps / pc["ideal_steps"]
     vec_rate = hw.peak_flops * m.vector_eff
-    t = w["recurrence_flops"] * leg_scale / vec_rate \
-        + w["fft_flops"] / vec_rate
+    t = w["recurrence_flops"] / vec_rate + w["fft_flops"] / vec_rate
     if m.matrix_eff > 0:
-        t += w["accum_flops"] * leg_scale / (hw.peak_flops * m.matrix_eff)
+        t += w["accum_flops"] / (hw.peak_flops * m.matrix_eff)
     else:
-        t += w["accum_flops"] * leg_scale / vec_rate
-    t += byts / hw.hbm_bw
+        t += w["accum_flops"] / vec_rate
+    t += w["bytes"] / hw.hbm_bw
     if backend == "dist" and n_devices > 1:
         t /= n_devices
         # one tiled all_to_all of the (M, R, ncomp*2K) Delta block
@@ -260,8 +207,7 @@ def predict_sht_time(backend: str, *, l_max: int, m_max: int, n_rings: int,
         if overlap and C > 1 and comm > 0.0:
             m_local = -(-(m_max + 2) // (2 * n_devices)) * 2
             if K >= C:
-                t += (C - 1) * w["recurrence_flops"] * leg_scale \
-                    / vec_rate / n_devices
+                t += (C - 1) * w["recurrence_flops"] / vec_rate / n_devices
             else:
                 t += (min(C, m_local) - 1) * (l_max + 1) * hw.loop_step
             comp_c = t / C

@@ -1,10 +1,10 @@
 """Persistent per-hardware autotune characterization DB.
 
-``make_plan(mode="auto")`` used to one-shot time every candidate corner
-(backend x direction x layout) on every cache-cold plan build.  This
-module replaces that with a characterization database: measured corner
-timings are cached under a *hardware fingerprint* (accelerator backend,
-device kind/count, jax version, interpret flag), so
+``make_plan(mode="auto")`` times its candidate corners (backend x
+direction, and the dist backend's exchange chunk counts) when a signature
+has more than one candidate, i.e. on two or more devices.  Measured
+corner timings are cached under a *hardware fingerprint* (accelerator
+backend, device kind/count, jax version), so
 
   * a corner is measured at most once per hardware per schema epoch --
     later plan builds (even after the decision cache is cleared) reuse
@@ -34,8 +34,6 @@ __all__ = [
 ]
 
 #: bump when the timing methodology changes; older corners become stale.
-#: 2: fused corners gained an ``lp_size`` coordinate (block-shape autotune)
-#: and the fused-MXU kernels were restructured, invalidating old timings.
 SCHEMA = 2
 
 _SMOKE_ENV = "REPRO_CHARDB_SMOKE"
@@ -56,21 +54,16 @@ def smoke_mode() -> bool:
     return True
 
 
-def hardware_fingerprint(*, interpret: Optional[bool] = None) -> tuple:
+def hardware_fingerprint() -> tuple:
     """(short-hash, human-readable string) identifying the hardware the
-    timings are valid for.  Interpret-mode pallas timings are a different
-    machine than compiled-TPU timings, so the flag is part of the key."""
+    timings are valid for."""
     import jax
     dev = jax.devices()[0]
-    if interpret is None:
-        from repro.kernels.ops import should_interpret
-        interpret = should_interpret()
     desc = "|".join([
         jax.default_backend(),
         dev.device_kind,
         str(jax.device_count()),
         jax.__version__,
-        f"interpret={int(bool(interpret))}",
     ])
     return hashlib.sha1(desc.encode()).hexdigest()[:16], desc
 
@@ -123,8 +116,8 @@ class CharDB:
     def corner_key(**fields) -> str:
         """Deterministic key over the corner coordinates.  Callers pass
         the *workload* coordinates (grid/l_max/K/dtype/backend/direction/
-        layout/pipeline...) -- never the dispatch mode, so plans built
-        with different modes share corners."""
+        comm_chunks...) -- never the dispatch mode, so plans built with
+        different modes share corners."""
         blob = json.dumps(fields, sort_keys=True, default=str)
         return hashlib.sha1(blob.encode()).hexdigest()[:24]
 
@@ -179,12 +172,11 @@ class CharDB:
                 "path": self.path, **self.counters}
 
 
-def get_db(directory: Optional[str] = None, *,
-           interpret: Optional[bool] = None) -> CharDB:
+def get_db(directory: Optional[str] = None) -> CharDB:
     """The process-wide CharDB for the current hardware (memoized per
     ``(fingerprint, directory)``).  Pass the plan's disk-cache directory
     to persist corners across processes; None keeps them in memory."""
-    fp, desc = hardware_fingerprint(interpret=interpret)
+    fp, desc = hardware_fingerprint()
     key = f"{fp}:{directory or ''}"
     with _lock:
         db = _DBS.get(key)

@@ -137,16 +137,6 @@ class PlanPool:
         from repro.roofline import chardb
         with self._lock:
             total = self.hits + self.misses
-            fusion = {"eligible": 0, "active": 0, "staged": 0}
-            for plan in list(self._lru._data.values()):
-                ok, _ = plan._fusion_eligibility()
-                if not ok:
-                    fusion["staged"] += 1
-                    continue
-                fusion["eligible"] += 1
-                if any(plan.layouts.get(d) == "fused"
-                       for d in ("synth", "anal")):
-                    fusion["active"] += 1
             return {
                 "size": len(self._lru),
                 "capacity": self.capacity,
@@ -155,9 +145,6 @@ class PlanPool:
                 "evictions": self.evictions,
                 "warmups": self.warmups,
                 "hit_rate": (self.hits / total) if total else float("nan"),
-                # fused-pipeline coverage of the warm set: how many pooled
-                # plans could fuse and how many actually dispatch fused
-                "fusion": fusion,
                 # autotune corners behind the pooled plans: a warm pool
                 # should show reuse, not re-measurement
                 "chardb": chardb.stats(),

@@ -10,9 +10,9 @@ names from here rather than copying the strings.
 Device scopes (``op_name`` segments):
 
 * :data:`PHASE` -- ring FFTs, phase rotation, quadrature weights;
-* :data:`LEGENDRE` -- the Legendre stage of every backend (the jnp scan,
-  the staged and fused Pallas kernels), with the sub-scopes
-  :data:`RECURRENCE` and :data:`ACCUMULATE` inside the jnp scan step;
+* :data:`LEGENDRE` -- the Legendre stage of both backends (the jnp loop,
+  and the dist backend's sharded stage 1), with the sub-scopes
+  :data:`RECURRENCE` and :data:`ACCUMULATE` inside the loop step;
 * :data:`FOLD` -- hemisphere sums and mirrors, complex assembly and the
   layout copies between the two stages;
 * :data:`EXCHANGE` -- the distributed transform's ``all_to_all`` of the

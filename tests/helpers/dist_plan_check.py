@@ -48,10 +48,9 @@ def report(name, good, **fields):
 d = p.describe()["dist"]
 blocks = {k: v["blocks"] for k, v in d["row_blocks"].items()}
 want = 2 if case == "blocks" else 1
-report("layout", d["stage1"] == "jnp" and d["shards"] == 4
-       and set(blocks.values()) == {want}
+report("layout", d["shards"] == 4 and set(blocks.values()) == {want}
        and p.comm_chunks == {"synth": 1, "anal": 1},
-       stage1=d["stage1"], blocks=blocks, chunks=p.comm_chunks)
+       blocks=blocks, chunks=p.comm_chunks)
 maps = np.asarray(p.alm2map(alm.astype(cdt)))
 err_s = np.max(np.abs(maps - maps_ref)) / np.max(np.abs(maps_ref))
 report("synth", err_s < tol, err=f"{err_s:.2e}")

@@ -20,9 +20,9 @@ mesh = jax.make_mesh((4, 2), ("data", "model"),
                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
 p = planlib.SHTPlan(g, lmax, lmax, 8)
 
-def check(name, fold, comm_dtype, stage1, dtype, tol_s, tol_a):
+def check(name, fold, comm_dtype, dtype, tol_s, tol_a):
     d = dist_sht.DistSHT(p, mesh, ("data", "model"), dtype=dtype, fold=fold,
-                         comm_dtype=comm_dtype, stage1=stage1)
+                         comm_dtype=comm_dtype)
     packed = np.asarray(p.pack_alm(np.asarray(alm)))
     if dtype == "float32":
         packed = packed.astype(np.complex64)
@@ -37,11 +37,11 @@ def check(name, fold, comm_dtype, stage1, dtype, tol_s, tol_a):
     return ok
 
 ok = True
-ok &= check("f64", False, None, "jnp", "float64", 1e-12, 1e-12)
-ok &= check("f64+fold", True, None, "jnp", "float64", 1e-12, 1e-12)
-ok &= check("f64+bf16comm", False, "bfloat16", "jnp", "float64", 2e-2, 2e-2)
-ok &= check("f32+pallas", False, None, "pallas", "float32", 5e-4, 5e-4)
-ok &= check("f32+pallas+fold", True, None, "pallas", "float32", 5e-4, 5e-4)
+ok &= check("f64", False, None, "float64", 1e-12, 1e-12)
+ok &= check("f64+fold", True, None, "float64", 1e-12, 1e-12)
+ok &= check("f64+bf16comm", False, "bfloat16", "float64", 2e-2, 2e-2)
+ok &= check("f32", False, None, "float32", 5e-4, 5e-4)
+ok &= check("f32+fold", True, None, "float32", 5e-4, 5e-4)
 
 # ragged true-HEALPix: bucket-aware ring sharding + bucket phase stage
 gh = grids.make_grid("healpix", nside=8)
@@ -70,9 +70,8 @@ maps_qu_ref = np.asarray(t.alm2map_spin(alm_eb))
 alm_eb_ref = np.asarray(t.map2alm_spin(jnp.asarray(maps_qu_ref)))
 
 
-def check_spin(name, stage1, dtype, tol_s, tol_a):
-    d = dist_sht.DistSHT(p, mesh, ("data", "model"), dtype=dtype,
-                         stage1=stage1)
+def check_spin(name, dtype, tol_s, tol_a):
+    d = dist_sht.DistSHT(p, mesh, ("data", "model"), dtype=dtype)
     packed = np.stack([np.asarray(p.pack_alm(np.asarray(alm_eb[i])))
                        for i in range(2)])
     if dtype == "float32":
@@ -91,8 +90,8 @@ def check_spin(name, stage1, dtype, tol_s, tol_a):
     return s_ok
 
 
-ok &= check_spin("f64+spin2", "jnp", "float64", 1e-12, 1e-12)
-ok &= check_spin("f32+pallas+spin2", "pallas", "float32", 5e-4, 5e-4)
+ok &= check_spin("f64+spin2", "float64", 1e-12, 1e-12)
+ok &= check_spin("f32+spin2", "float32", 5e-4, 5e-4)
 
 # -- adjoint-based VJP through shard_map: jax.grad of a scalar loss through
 #    the distributed transform matches central finite differences (the
@@ -100,9 +99,8 @@ ok &= check_spin("f32+pallas+spin2", "pallas", "float32", 5e-4, 5e-4)
 rng = np.random.default_rng(7)
 
 
-def check_grad(name, stage1, dtype, tol):
-    d = dist_sht.DistSHT(p, mesh, ("data", "model"), dtype=dtype,
-                         stage1=stage1)
+def check_grad(name, dtype, tol):
+    d = dist_sht.DistSHT(p, mesh, ("data", "model"), dtype=dtype)
     packed = jnp.asarray(p.pack_alm(np.asarray(alm))).astype(
         jnp.complex64 if dtype == "float32" else jnp.complex128)
     t = jnp.asarray(rng.normal(size=(p.r_pad, g.max_n_phi, 2)),
@@ -135,8 +133,8 @@ def check_grad(name, stage1, dtype, tol):
     return g_ok
 
 
-ok &= check_grad("grad+f64+jnp", "jnp", "float64", 1e-7)
-ok &= check_grad("grad+f32+pallas", "pallas", "float32", 3e-2)
+ok &= check_grad("grad+f64", "float64", 1e-7)
+ok &= check_grad("grad+f32", "float32", 3e-2)
 
 # -- spin-2 ragged healpix through the full plan dispatch (mode="dist")
 ps = repro.make_plan("healpix", nside=8, l_max=lmax_h, K=2,

@@ -11,15 +11,14 @@ checks it at every layer and through every backend:
       <alm2map(a), t>_pix  ==  sum_{m,l} fac_m Re(a_lm conj(ahat_lm)),
       ahat = map2alm(t / w),  fac_m = 1 (m = 0) | 2 (m > 0)
 
-* the kernel-level transpose (ops.synth vs ops.anal, plain and packed,
-  scalar and spin rows, fold on/off);
-
 * JVP-vs-VJP consistency of the custom rules (the transpose is checked
   against the forward linearisation, which the forward tests pin down);
 
 * finite-difference gradient checks of ``jax.grad`` through
-  ``Plan.alm2map`` and ``Plan.map2alm`` on every eligible backend, both
-  Legendre layouts, spin 0 and 2.
+  ``Plan.alm2map`` and ``Plan.map2alm`` on the jnp backend, float64 and
+  float32 (the dtype the benchmark cells run), spin 0 and 2, folded and
+  not.  The dist backend's checks run on simulated devices
+  (tests/helpers/dist_sht_check.py).
 
 Hypothesis runs through the `_hypothesis_compat` fallback runner, so the
 property tests execute (seeded + boundary examples) even without the real
@@ -44,13 +43,13 @@ from repro.core.sht import random_alm, random_alm_spin
 GRIDS = ("gl", "ecp", "healpix")
 
 
-def _make_plan(grid_kind, l_max, dtype, mode, spin=0, K=1):
+def _make_plan(grid_kind, l_max, dtype, mode, spin=0, K=1, fold=None):
     nside = None
     if grid_kind == "healpix":
         nside = max(4, (l_max + 1) // 2)
         l_max = min(l_max, 2 * nside)
     return repro.make_plan(grid_kind, l_max=l_max, nside=nside, K=K,
-                           dtype=dtype, mode=mode, spin=spin)
+                           dtype=dtype, mode=mode, spin=spin, fold=fold)
 
 
 def _rand_alm(plan, seed):
@@ -79,18 +78,14 @@ def _harmonic_dot(plan, a, ahat):
     return float(jnp.sum(fac * p))
 
 
-def _adjoint_identity_err(plan, seed, layout=None):
-    """Relative error of the plan-level adjointness identity.
-
-    ``layout`` pins the Legendre layout on both directions (the compiled-
-    callable cache is keyed by layout, so pinning is jit-cache friendly).
-    """
+def _adjoint_identity_err(plan, seed):
+    """Relative error of the plan-level adjointness identity."""
     a = _rand_alm(plan, seed)
     t = _rand_maps(plan, seed + 1)
     w = jnp.asarray(plan.grid.weights, plan.dtype)[:, None, None]
     t_over_w = t / (w if plan.spin == 0 else w[None])
-    synth = plan._synth_fn(plan.backends["synth"], layout)
-    anal = plan._anal_fn(plan.backends["anal"], layout)
+    synth = plan._synth_fn(plan.backends["synth"])
+    anal = plan._anal_fn(plan.backends["anal"])
     lhs = float(jnp.sum(synth(a) * t))
     ahat = anal(t_over_w)
     rhs = _harmonic_dot(plan, a, ahat)
@@ -112,16 +107,6 @@ def test_adjointness_jnp_f64(grid, l_max, k, seed):
 
 
 @settings(max_examples=40, deadline=None)
-@given(grid=st.sampled_from(GRIDS), backend=st.sampled_from(
-           ["pallas_vpu", "pallas_mxu"]),
-       layout=st.sampled_from(["plain", "packed"]),
-       seed=st.integers(0, 10**6))
-def test_adjointness_pallas_f32(grid, backend, layout, seed):
-    plan = _make_plan(grid, 8, "float32", backend)
-    assert _adjoint_identity_err(plan, seed, layout=layout) < 2e-3
-
-
-@settings(max_examples=40, deadline=None)
 @given(grid=st.sampled_from(GRIDS), l_max=st.integers(4, 10),
        seed=st.integers(0, 10**6))
 def test_adjointness_spin2_jnp(grid, l_max, seed):
@@ -129,63 +114,20 @@ def test_adjointness_spin2_jnp(grid, l_max, seed):
     assert _adjoint_identity_err(plan, seed) < 1e-11
 
 
-@settings(max_examples=20, deadline=None)
-@given(backend=st.sampled_from(["pallas_vpu", "pallas_mxu"]),
-       layout=st.sampled_from(["plain", "packed"]),
-       seed=st.integers(0, 10**6))
-def test_adjointness_spin2_pallas(backend, layout, seed):
-    plan = _make_plan("gl", 8, "float32", backend, spin=2)
-    assert _adjoint_identity_err(plan, seed, layout=layout) < 2e-3
+#: (grid, spin, fold) of the float32 jnp plans: every grid at spin 0, folded
+#: and not (HEALPix allows the fold, though it is not its default), and at
+#: spin 2, which does not fold
+F32_CASES = [(g, 0, f) for g in GRIDS for f in (False, True)] + \
+    [(g, 2, False) for g in GRIDS]
+F32_IDS = [f"{g}-spin{s}-{'fold' if f else 'nofold'}" for g, s, f in F32_CASES]
 
 
-# ---------------------------------------------------------------------------
-# kernel-level transpose: <synth(a), y> == <a, anal(y)> (no weights at
-# this layer, so the pairing is the plain elementwise dot product)
-# ---------------------------------------------------------------------------
-
-
-def _kernel_operands(l_max, fold, spin, K2=2, seed=0):
-    from repro.kernels import ref as kref
-    g = gridlib.make_grid("gl", l_max=l_max)
-    rng = np.random.default_rng(seed)
-    if spin:
-        m2, mp2 = legendre._spin_rows(np.arange(l_max + 1))
-        pmm, pms = kref.prepare_seeds_spin(m2, mp2, g.cos_theta, g.sin_theta,
-                                           m_max=l_max)
-        m_vals, mp_vals, x = m2, mp2, g.cos_theta
-    else:
-        lm = legendre.log_mu(l_max)
-        m_vals = np.arange(l_max + 1)
-        sin = g.sin_theta[0::2] if fold else g.sin_theta
-        x = g.cos_theta[0::2] if fold else g.cos_theta
-        pmm, pms = kref.prepare_seeds(m_vals, sin, lm)
-        mp_vals = None
-    Mp = m_vals.shape[0]
-    R = x.shape[0]
-    P = 2 if fold else 1
-    a = jnp.asarray(rng.normal(size=(Mp, l_max + 1, K2)), jnp.float32)
-    y = jnp.asarray(rng.normal(size=(Mp, P, R, K2)), jnp.float32)
-    x32 = jnp.asarray(x, jnp.float32)
-    return a, y, m_vals, mp_vals, x32, pmm, pms
-
-
-@settings(max_examples=24, deadline=None)
-@given(variant=st.sampled_from(["vpu", "mxu"]),
-       layout=st.sampled_from(["plain", "packed"]),
-       fold=st.sampled_from([False, True]),
-       spin=st.sampled_from([False, True]))
-def test_kernel_transpose(variant, layout, fold, spin):
-    from repro.kernels import ops as kops
-    if spin and fold:
-        return  # spin rows never fold
-    l_max = 8
-    a, y, m_vals, mp_vals, x32, pmm, pms = _kernel_operands(l_max, fold, spin)
-    kw = dict(l_max=l_max, fold=fold, variant=variant, mp_vals=mp_vals,
-              layout=layout)
-    lhs = float(jnp.sum(kops.synth(a, m_vals, x32, pmm, pms, **kw) * y))
-    rhs = float(jnp.sum(kops.anal(y, m_vals, x32, pmm, pms, **kw) * a))
-    assert abs(lhs - rhs) <= 2e-4 * max(abs(lhs), abs(rhs), 1e-30), \
-        (lhs, rhs)
+@pytest.mark.parametrize("grid,spin,fold", F32_CASES, ids=F32_IDS)
+def test_adjointness_jnp_f32(grid, spin, fold):
+    plan = _make_plan(grid, 8, "float32", "jnp", spin=spin, K=2, fold=fold)
+    assert plan.fold is fold
+    for seed in (0, 1):
+        assert _adjoint_identity_err(plan, seed) < 1e-5
 
 
 # ---------------------------------------------------------------------------
@@ -239,11 +181,11 @@ def _grad_dir(g, v):
     return float(jnp.real(jnp.sum(g * v)))
 
 
-def _check_grad_synth(plan, seed, rtol, layout=None):
+def _check_grad_synth(plan, seed, rtol):
     a = _rand_alm(plan, seed)
     t = _rand_maps(plan, seed + 1)
     v = _rand_alm(plan, seed + 2)
-    synth = plan._synth_fn(plan.backends["synth"], layout)
+    synth = plan._synth_fn(plan.backends["synth"])
 
     def loss(x):
         return jnp.sum(synth(x) * t)
@@ -255,11 +197,11 @@ def _check_grad_synth(plan, seed, rtol, layout=None):
                                atol=rtol * max(abs(fd), 1.0))
 
 
-def _check_grad_anal(plan, seed, rtol, iters=0, layout=None):
+def _check_grad_anal(plan, seed, rtol, iters=0):
     a = _rand_alm(plan, seed)
     maps0 = plan.alm2map(a)
     vm = _rand_maps(plan, seed + 3)
-    anal = plan._anal_fn(plan.backends["anal"], layout)
+    anal = plan._anal_fn(plan.backends["anal"])
 
     def loss(mp):
         alm = anal(mp)
@@ -283,14 +225,11 @@ def test_gradcheck_jnp_f64(grid, spin, seed):
     _check_grad_anal(plan, seed, rtol=1e-6)
 
 
-@settings(max_examples=16, deadline=None)
-@given(backend=st.sampled_from(["pallas_vpu", "pallas_mxu"]),
-       layout=st.sampled_from(["plain", "packed"]),
-       spin=st.sampled_from([0, 2]))
-def test_gradcheck_pallas_f32(backend, layout, spin):
-    plan = _make_plan("gl", 8, "float32", backend, spin=spin)
-    _check_grad_synth(plan, 7, rtol=1e-3, layout=layout)
-    _check_grad_anal(plan, 11, rtol=1e-3, layout=layout)
+@pytest.mark.parametrize("grid,spin,fold", F32_CASES, ids=F32_IDS)
+def test_gradcheck_jnp_f32(grid, spin, fold):
+    plan = _make_plan(grid, 8, "float32", "jnp", spin=spin, K=2, fold=fold)
+    _check_grad_synth(plan, 7, rtol=1e-3)
+    _check_grad_anal(plan, 11, rtol=1e-3)
 
 
 def test_gradcheck_through_jacobi_iters():

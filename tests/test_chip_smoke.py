@@ -1,5 +1,5 @@
-"""chip_smoke.py between chip runs: its phases at a tiny size on the CPU
-(Pallas in interpret mode), its float64 numpy reference against the
+"""chip_smoke.py between chip runs: its phases at a tiny size on the CPU,
+its float64 numpy reference against the
 library's oracle, its refusal to run without a TPU, and the compile-cache
 helper its entry points share."""
 
@@ -68,28 +68,20 @@ def test_check_grid_refuses_a_different_grid(field):
         cs.check_grid(grid, geo)
 
 
-@pytest.mark.parametrize("direction", ["synth", "anal"])
-def test_fused_phase_tiny(direction, rng, clock, capsys):
-    """Both variants run; the MXU kernel in the one given direction."""
-    cs.phase_fused("tiny", 16, 2, direction, rng, clock)
-    out = capsys.readouterr().out
-    assert "[fused/tiny/vpu]" in out and "[fused/tiny/mxu]" in out
-    assert f"direction={direction}" in out and "'fused'" in out
-
-
 def test_reference_phase_tiny(rng, clock, capsys):
     cs.phase_reference(12, 1, rng, clock)
     out = capsys.readouterr().out
-    assert "[reference/lmax12/vpu]" in out and "[reference/lmax12/mxu]" in out
+    assert "[reference/lmax12/jnp]" in out
 
 
 def test_auto_phase_tiny(rng, clock, capsys):
     cs.phase_auto(8, 1, rng, clock)
-    assert "[auto/lmax8_k1]" in capsys.readouterr().out
+    out = capsys.readouterr().out
+    assert "[auto/lmax8_k1]" in out and "decision=only-candidate" in out
 
 
 def test_engine_phase_tiny(rng, clock, capsys):
-    cs.phase_engine(8, 4, "pallas_mxu", rng, clock)
+    cs.phase_engine(8, 4, "jnp", rng, clock)
     out = capsys.readouterr().out
     assert "bit_identical_to_batch_plan=True" in out
 
@@ -109,7 +101,6 @@ def test_dist_phase_on_host_devices():
     # the plan's default C (1 here, so C = 1 is not run a second time)
     assert "/chunks=auto]" in r.stdout and "/chunks=1]" not in r.stdout
     assert "comm_chunks={'synth': 1, 'anal': 1}" in r.stdout
-    assert "stage1=jnp" in r.stdout
     assert "operand_shards=[(0," in r.stdout
 
 

@@ -360,3 +360,81 @@ def test_folded_synthesis_carry_is_k_major():
     carry = [shape for shapes in loops for shape in shapes]
     assert carry.count((2, K * M, R)) == 2            # re, im
     assert (M, R, K) not in carry
+
+
+# -- the loops transpose each other ---------------------------------------------
+
+#: forced rows per block for 24 rows: one loop, 2 blocks (16 + 8), 3 of 8
+_TRANSPOSE_BLOCKS = {1: 0, 2: 16, 3: 8}
+
+
+def _dot(xs, ys):
+    return sum(float(jnp.sum(jnp.asarray(x) * jnp.asarray(y)))
+               for x, y in zip(xs, ys))
+
+
+def _assert_transposed(fwd, adj, a, y):
+    """<fwd(a), y> == <a, adj(y)> over every real output plane."""
+    lhs, rhs = _dot(fwd(a), y), _dot(a, adj(y))
+    assert abs(lhs - rhs) <= 1e-12 * max(abs(lhs), abs(rhs)), (lhs, rhs)
+
+
+@pytest.mark.parametrize("l_max", [23, 24])
+@pytest.mark.parametrize("rows", ["concrete", "traced"])
+@pytest.mark.parametrize("C", sorted(_TRANSPOSE_BLOCKS))
+@pytest.mark.parametrize("loop", ["plain", "folded"])
+def test_loops_transpose_each_other(loop, C, rows, l_max, monkeypatch):
+    """The synthesis loop and the unit-weight analysis loop are each
+    other's transpose, plain and folded, in one loop or in row blocks, on
+    concrete rows and on traced ones (the dist stage 1): the identity the
+    adjoint-based gradients rest on."""
+    monkeypatch.setattr(legendre, "_blocks_for",
+                        lambda *a, **k: _TRANSPOSE_BLOCKS[C])
+    g = grids.make_grid("gl", l_max=l_max)
+    lm = legendre.log_mu(l_max)
+    m_vals = np.arange(24)                # m_max = 23: < l_max at 24
+    nh = (g.n_rings + 1) // 2
+    x, s = ((g.cos_theta[:nh], g.sin_theta[:nh]) if loop == "folded"
+            else (g.cos_theta, g.sin_theta))
+    rng = np.random.default_rng(C + 3 * l_max)
+    M, K, R = len(m_vals), 2, len(x)
+    a = [rng.normal(size=(M, l_max + 1, K)) for _ in range(2)]
+    y = [rng.normal(size=(M, R, K)) for _ in range(4 if loop == "folded"
+                                                   else 2)]
+    if loop == "folded":
+        synth = lambda mv, a: legendre.delta_from_alm_folded(
+            *a, mv, x, s, lm, l_max=l_max)
+        anal = lambda mv, y: legendre.alm_from_delta_folded(
+            *y, mv, x, s, lm, l_max=l_max)
+    else:
+        synth = lambda mv, a: legendre.delta_from_alm(
+            *a, mv, x, s, lm, l_max=l_max)
+        anal = lambda mv, y: legendre.alm_from_delta(
+            *y, mv, x, s, np.ones(R), lm, l_max=l_max)
+    if rows == "traced":
+        synth, anal = jax.jit(synth), jax.jit(anal)
+    _assert_transposed(lambda a: synth(m_vals, a), lambda y: anal(m_vals, y),
+                       a, y)
+
+
+@pytest.mark.parametrize("rows", ["concrete", "traced"])
+@pytest.mark.parametrize("mprime", [-2, 2])
+def test_general_loops_transpose_each_other(mprime, rows):
+    """As above for the spin-weighted (Wigner-d) loops of one m' slice."""
+    l_max = 20
+    g = grids.make_grid("gl", l_max=l_max)
+    m_vals = np.arange(l_max + 1)
+    mp = np.full(len(m_vals), mprime)
+    rng = np.random.default_rng(mprime + 5)
+    M, K = len(m_vals), 3
+    a = [rng.normal(size=(M, l_max + 1, K)) for _ in range(2)]
+    y = [rng.normal(size=(M, g.n_rings, K)) for _ in range(2)]
+    kw = dict(l_max=l_max, m_max=l_max)
+    synth = lambda mv, a: legendre.delta_from_alm_general(
+        *a, mv, mp, g.cos_theta, g.sin_theta, **kw)
+    anal = lambda mv, y: legendre.alm_from_delta_general(
+        *y, mv, mp, g.cos_theta, g.sin_theta, **kw)
+    if rows == "traced":
+        synth, anal = jax.jit(synth), jax.jit(anal)
+    _assert_transposed(lambda a: synth(m_vals, a), lambda y: anal(m_vals, y),
+                       a, y)

@@ -272,10 +272,7 @@ def test_pool_hits_and_warmup():
     # prewarm built the (sig, max_k=2) plan; both batches then hit it
     assert p["misses"] == 1 and p["hits"] == 2
     assert eng.stats()["pool"]["hit_rate"] == pytest.approx(2 / 3)
-    # fused-pipeline coverage of the warm set: the gl plan is eligible
-    f = p["fusion"]
-    assert f["eligible"] == 1 and f["staged"] == 0
-    assert f["active"] in (0, 1)        # autotune decides the dispatch
+    assert p["size"] == 1 and p["evictions"] == 0
 
 
 def test_pool_lru_eviction_releases_plans():
@@ -553,6 +550,24 @@ def test_admission_generous_target_keeps_full_bucket_and_calibrates():
     assert cal["count"] == 1
     assert np.isfinite(cal["ratio"]) and cal["ratio"] > 0.0
     assert "admission" in eng.report()
+
+
+@pytest.mark.parametrize("device_kind", ["cpu", "TPU v5 lite"])
+def test_admission_prices_the_jnp_backend(device_kind, monkeypatch):
+    """Admission prices a batch as the backend the engine's one-device
+    plans run, jnp, on every host, at that host's peaks."""
+    from repro.roofline import admission, analysis
+    if device_kind != "cpu":
+        monkeypatch.setattr(admission, "hardware_for",
+                            lambda: analysis.PEAKS[device_kind])
+    backend, hw = admission.default_model()
+    assert backend == "jnp"
+    assert hw is (analysis.HW_HOST if device_kind == "cpu"
+                  else analysis.HW_V5E)
+    caps = admission.k_caps_for_target(l_max=LMAX, n_rings=LMAX + 1,
+                                       n_phi=2 * LMAX + 2, max_k=4,
+                                       p99_target_s=60.0)
+    assert caps["backend"] == "jnp" and caps["k_cap"] == 4
 
 
 def test_engine_describe():

@@ -1,20 +1,14 @@
 """Compiles for a described TPU v5e chip: no chip needed, nothing runs.
 
-Every kernel chain ``make_plan`` dispatches to on a TPU is lowered through
-Mosaic (not interpret mode) and compiled by the TPU compiler installed
-here, for a chip that is described, not attached.  This catches what
-interpret mode cannot: block shapes off the (8, 128) tiling, programs
-that do not fit HBM, and float64 reaching the device (the 64-bit mode the
+The programs ``make_plan`` runs on a TPU are compiled by the TPU compiler
+installed here, for a chip that is described, not attached.  This catches
+what a CPU run cannot: programs that do not fit HBM, minor dimensions
+padded to 128 lanes, and float64 reaching the device (the 64-bit mode the
 package once switched on globally made the compiler abort even for a
 float32 plan).
 
-Width: the kernels are compiled at ``l_max=1024``, not the paper's 4096.
-At 4096 one fused analysis compile takes ~35 s; 1024 keeps the ring axis
-several blocks deep in both variants -- 9 MXU ring blocks (1025 rings in
-blocks of 128: more than one, not a multiple of 8, like the 33 blocks at
-4096) and 2 VPU ring blocks (rings padded to 2048 in blocks of 1024) --
-which is what the compile failures at 4096 depended on.  A chip run
-(``chip_smoke.py``) covers the full width.
+Width: the plans are compiled at ``l_max=1024`` (one at the paper's
+4096); a chip run (``chip_smoke.py``) covers the full width.
 
 The topology is described inside a module fixture, so only the worker
 that runs this file loads the TPU library.
@@ -28,7 +22,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import repro
-from repro.kernels import ops
 
 L_MAX = 1024
 
@@ -58,63 +51,55 @@ def four_chip_mesh(one_chip):
 
 
 @pytest.fixture()
-def tpu_like(monkeypatch):
-    """Trace as on a TPU backend: Pallas kernels for Mosaic, and JAX's
-    64-bit mode off (the test session turns it on for the float64
-    oracle; the package itself never does)."""
-    monkeypatch.setattr(ops, "should_interpret", lambda: False)
+def tpu_like():
+    """Trace as on a TPU backend: JAX's 64-bit mode off (the test session
+    turns it on for the float64 oracle; the package itself never does)."""
     with jax.enable_x64(False):
         yield
 
 
-def _compile(plan, direction, backend, layout, device):
+def _compile(plan, direction, backend, device):
     """Lower + compile one plan direction for ``device``; returns the
-    compiled executable.  The plan's precomputed tables are arguments of
-    the jitted function (`transform._bind`), given here as shapes."""
-    spec = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=device)
+    compiled executable."""
     if direction == "synth":
-        fn = plan._synth_fn(backend, layout)
+        fn = plan._synth_fn(backend)
         arg = jax.ShapeDtypeStruct(plan._alm_shape, jnp.complex64,
                                    sharding=device)
     else:
-        fn = plan._anal_fn(backend, layout)
+        fn = plan._anal_fn(backend)
         arg = jax.ShapeDtypeStruct(plan._maps_shape, jnp.float32,
                                    sharding=device)
-    if hasattr(fn, "func"):                 # bound pallas plan function
-        consts = jax.tree.map(spec, fn.keywords["consts"])
-        lowered = fn.func.lower(arg, consts=consts)
-    else:
-        lowered = fn.lower(arg)
-    return lowered.compile()
+    return fn.lower(arg).compile()
 
 
-def _plan(K, mode, l_max=L_MAX):
+def _plan(K, mode, l_max=L_MAX, **kw):
     # cache="off": a plan memoised by another test under 64-bit mode
     # would carry float64 tables
     return repro.make_plan("gl", l_max=l_max, K=K, dtype="float32",
-                           mode=mode, cache="off")
+                           mode=mode, cache="off", **kw)
+
+
+#: (spin, fold, K) of the probes below: the K=1 plans of both folds (the
+#: K=4 ones are the lane-density tests) and the spin-2 plans, which do not
+#: fold, at both K
+PROBES = [(0, f, 1) for f in (True, False)] + [(2, False, k) for k in (1, 4)]
 
 
 @pytest.mark.parametrize("direction", ["synth", "anal"])
-@pytest.mark.parametrize("variant", ["vpu", "mxu"])
-@pytest.mark.parametrize("K", [1, 4])
-def test_fused_kernels_compile(K, variant, direction, one_chip, tpu_like):
-    plan = _plan(K, f"pallas_{variant}")
-    compiled = _compile(plan, direction, f"pallas_{variant}", "fused",
-                        one_chip)
-    assert "tpu_custom_call" in compiled.as_text()
-    # lane-dense operands: no K-minor tile padding blows the temp buffers
-    # up (it was 64x at K=1: 13 GB at l_max=4096)
+@pytest.mark.parametrize(
+    "spin,fold,K", PROBES,
+    ids=[f"spin{s}-{'fold' if f else 'nofold'}-K{k}" for s, f, k in PROBES])
+def test_jnp_plan_compiles_for_v5e(spin, fold, K, direction, one_chip,
+                                   tpu_like):
+    """The jnp plan at l_max 1024 compiles for the chip: no kernel, no
+    float64, and temps within 8x the arguments (no K-minor padding)."""
+    plan = _plan(K, "jnp", fold=fold, spin=spin)
+    assert plan.fold is fold
+    compiled = _compile(plan, direction, "jnp", one_chip)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" not in hlo and "f64" not in hlo
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 8 * mem.argument_size_in_bytes
-
-
-@pytest.mark.parametrize("direction", ["synth", "anal"])
-@pytest.mark.parametrize("layout", ["packed", "plain"])
-def test_staged_mxu_kernels_compile(layout, direction, one_chip, tpu_like):
-    plan = _plan(1, "pallas_mxu")
-    compiled = _compile(plan, direction, "pallas_mxu", layout, one_chip)
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("direction", ["synth", "anal"])
@@ -122,7 +107,7 @@ def test_float32_jnp_plan_compiles(direction, one_chip, tpu_like):
     """The x64 regression: with 64-bit mode on, even this plan made the
     TPU compiler abort (f64 -> c128 conversion)."""
     plan = _plan(2, "jnp", l_max=64)
-    compiled = _compile(plan, direction, "jnp", None, one_chip)
+    compiled = _compile(plan, direction, "jnp", one_chip)
     assert "f64" not in compiled.as_text()
 
 
@@ -132,7 +117,7 @@ def test_jnp_plan_is_lane_dense_at_k4(direction, one_chip, tpu_like):
     stacks and (M, R, K) accumulators the TPU pads K=4 to 128 lanes (the
     analysis needed 16.7 GB of the chip's 15.75 GB at l_max=4096)."""
     plan = _plan(4, "jnp")
-    mem = _compile(plan, direction, "jnp", None, one_chip).memory_analysis()
+    mem = _compile(plan, direction, "jnp", one_chip).memory_analysis()
     assert mem.temp_size_in_bytes < 8 * mem.argument_size_in_bytes
 
 
@@ -142,7 +127,7 @@ def test_unfolded_jnp_plan_is_lane_dense_at_k4(direction, one_chip,
     """As above for the unfolded loops (``fold=False``, the dist stage 1)."""
     plan = repro.make_plan("gl", l_max=L_MAX, K=4, dtype="float32",
                            mode="jnp", fold=False, cache="off")
-    mem = _compile(plan, direction, "jnp", None, one_chip).memory_analysis()
+    mem = _compile(plan, direction, "jnp", one_chip).memory_analysis()
     assert mem.temp_size_in_bytes < 8 * mem.argument_size_in_bytes
 
 
@@ -152,7 +137,7 @@ def test_folded_jnp_synthesis_fits_at_4096(one_chip, tpu_like):
     accumulators are K-major (K*M, R) (K-minor ones were ~17 GB)."""
     plan = _plan(1, "jnp", l_max=4096)
     assert plan.fold
-    mem = _compile(plan, "synth", "jnp", None, one_chip).memory_analysis()
+    mem = _compile(plan, "synth", "jnp", one_chip).memory_analysis()
     assert mem.temp_size_in_bytes < 1e9
 
 
@@ -161,7 +146,7 @@ def test_blocked_jnp_analysis_compiles_at_k4(one_chip, tpu_like, monkeypatch):
     compiles for the chip with no more temp memory than the single full
     loop: its carries shrink to one block's rows."""
     from repro.core import legendre
-    full = _compile(_plan(4, "jnp"), "anal", "jnp", None,
+    full = _compile(_plan(4, "jnp"), "anal", "jnp",
                     one_chip).memory_analysis()
     row_bytes = legendre.row_step_bytes("anal", fold=True,
                                         n_rings=(L_MAX + 2) // 2, K=4,
@@ -170,21 +155,21 @@ def test_blocked_jnp_analysis_compiles_at_k4(one_chip, tpu_like, monkeypatch):
                         (L_MAX + 1) * row_bytes // 4)
     plan = _plan(4, "jnp")
     assert plan.describe()["legendre"]["jnp_blocks"]["anal"]["blocks"] == 4
-    blocked = _compile(plan, "anal", "jnp", None, one_chip).memory_analysis()
+    blocked = _compile(plan, "anal", "jnp", one_chip).memory_analysis()
     assert blocked.temp_size_in_bytes <= full.temp_size_in_bytes
 
 
 def test_dist_analysis_compiles_for_four_chips(four_chip_mesh, tpu_like):
     """The dist plan's sharded analysis (jnp stage 1, one chunk) compiles
     for four described chips: one all_to_all under the exchange scope, no
-    Pallas kernel, no float64, and its temps within the chip."""
+    kernel, no float64, and its temps within the chip."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro import tracing
     from repro.core import grids
     from repro.core.dist_sht import DistSHT
     from repro.core.plan import SHTPlan
     sp = SHTPlan(grids.make_grid("gl", l_max=L_MAX), L_MAX, L_MAX, 4)
-    d = DistSHT(sp, four_chip_mesh, ("sht",), dtype="float32", stage1="jnp")
+    d = DistSHT(sp, four_chip_mesh, ("sht",), dtype="float32")
     _, anal, c = d._build(4)
     sh = NamedSharding(four_chip_mesh, P("sht"))
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)

@@ -1,10 +1,9 @@
 """The program's profiler names (`repro.tracing`): stage scopes in the
-compiled programs of every CPU-eligible backend, the serving engine's
-per-batch spans and timing, and the compile counter."""
+compiled programs of the jnp loops, the serving engine's per-batch spans
+and timing, and the compile counter."""
 
 from __future__ import annotations
 
-import functools
 import glob
 import os
 import re
@@ -20,20 +19,14 @@ from repro.serve import ShtEngine
 
 L = 8
 
-#: (backend, layout, fold): every backend the CPU runs, each layout
-PATHS = [("jnp", None, False), ("jnp", None, True),
-         ("pallas_vpu", "plain", False), ("pallas_vpu", "packed", False),
-         ("pallas_vpu", "plain", True), ("pallas_vpu", "fused", False),
-         ("pallas_mxu", "plain", False), ("pallas_mxu", "packed", False),
-         ("pallas_mxu", "fused", True)]
+#: (backend, fold) of the one-device backend
+PATHS = [("jnp", False), ("jnp", True)]
 
 
 def _texts(fn, arg) -> tuple:
-    """(lowered StableHLO with locations, compiled HLO) of a plan
-    function (``jax.jit`` or a ``transform._bind`` partial)."""
-    jitted, kw = ((fn.func, fn.keywords)
-                  if isinstance(fn, functools.partial) else (fn, {}))
-    lowered = jitted.lower(arg, **kw)
+    """(lowered StableHLO with locations, compiled HLO) of a jitted plan
+    function."""
+    lowered = fn.lower(arg)
     return lowered.as_text(debug_info=True), lowered.compile().as_text()
 
 
@@ -42,27 +35,60 @@ def _op_names(compiled: str) -> set:
 
 
 @pytest.mark.parametrize("direction", ["synth", "anal"])
-@pytest.mark.parametrize("backend,layout,fold", PATHS)
-def test_stage_scopes_in_every_backend(backend, layout, fold, direction):
-    plan = repro.make_plan("gl", l_max=L, K=2, dtype="float32", mode="jnp",
+@pytest.mark.parametrize("backend,fold", PATHS)
+def test_stage_scopes_in_every_backend(backend, fold, direction):
+    plan = repro.make_plan("gl", l_max=L, K=2, dtype="float32", mode=backend,
                            fold=fold, cache="off")
+    _assert_scopes(plan, direction, fold)
+
+
+def _assert_scopes(plan, direction, fold):
+    """Every stage scope (the fold's when folded) and the loop's
+    sub-scopes are in the plan direction's lowered and compiled text."""
     if direction == "synth":
-        fn = plan._synth_fn(backend, layout)
-        arg = np.asarray(sht.random_alm(seed=0, l_max=L, m_max=L, K=2),
-                         np.complex64)
+        fn = plan._synth_fn("jnp")
+        f = sht.random_alm_spin if plan.spin else sht.random_alm
+        arg = np.asarray(f(seed=0, l_max=plan.l_max, m_max=plan.m_max,
+                           K=plan.K), np.complex64)
     else:
-        fn = plan._anal_fn(backend, layout)
+        fn = plan._anal_fn("jnp")
         arg = np.zeros(plan._maps_shape, np.float32)
     lowered, compiled = _texts(fn, arg)
     names = _op_names(compiled)
-    want = [tracing.PHASE, tracing.LEGENDRE]
+    want = [tracing.PHASE, tracing.LEGENDRE, tracing.RECURRENCE,
+            tracing.ACCUMULATE]
     if fold:
         want.append(tracing.FOLD)
-    if backend == "jnp":
-        want += [tracing.RECURRENCE, tracing.ACCUMULATE]
     for s in want:
         assert any(s in n.split("/") for n in names), (s, sorted(names))
         assert s in lowered
+
+
+#: (grid, spin, fold, row blocks) of the jnp loops beyond PATHS: blocked
+#: loops (C > 1), spin 2 (one loop over every row, no fold), the ragged
+#: bucket phase stage
+LOOPS = [("gl", 0, False, 2), ("gl", 0, True, 2), ("gl", 2, False, 1)] + \
+    [("healpix", s, f, c) for s, f, c in ((0, False, 1), (0, False, 2),
+                                          (0, True, 1), (0, True, 2),
+                                          (2, False, 1))]
+
+
+@pytest.mark.parametrize("direction", ["synth", "anal"])
+@pytest.mark.parametrize(
+    "grid,spin,fold,blocks", LOOPS,
+    ids=[f"{g}-spin{s}-{'fold' if f else 'nofold'}-C{c}"
+         for g, s, f, c in LOOPS])
+def test_stage_scopes_in_jnp_loops(grid, spin, fold, blocks, direction,
+                                   monkeypatch):
+    from repro.core import legendre
+    if blocks > 1:               # 8-row blocks: 2 of them for 9 rows
+        monkeypatch.setattr(legendre, "BLOCK_STEP_BYTES", 1)
+    kw = dict(l_max=L) if grid == "gl" else dict(nside=4, l_max=L)
+    plan = repro.make_plan(grid, K=2, dtype="float32", mode="jnp",
+                           fold=fold, spin=spin, cache="off", **kw)
+    got = plan.describe()["legendre"]["jnp_blocks"][direction]["blocks"]
+    assert got == blocks
+    _assert_scopes(plan, direction, fold)
 
 
 def _spans(trace_dir: str) -> list:

@@ -64,8 +64,49 @@ def test_disk_cache_skips_recompute(tmp_path):
     assert p2 is not p1                              # new object...
     assert plancache.stats().builds == builds        # ...zero rebuilt payloads
     assert plancache.stats().disk_hits > 0
-    assert p2.backends == p1.backends                # autotune decision reused
-    assert p2.cache_events.get("decision") == "hit"
+    assert p2.backends == p1.backends == {"synth": "jnp", "anal": "jnp"}
+    # one device, one candidate: there is no decision to time or reuse
+    assert p2.cache_events.get("decision") == "only-candidate"
+
+
+def test_disk_cache_reloads_auto_decision(tmp_path):
+    """With two candidates (jnp and dist on two host devices, in a
+    subprocess) a cold rebuild from the disk tier reloads the autotune
+    decision, comm chunks included, and times nothing."""
+    import json
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import json, repro\n"
+        "from repro.core import transform\n"
+        f"kw = dict(l_max=8, K=2, dtype='float32', mode='auto', "
+        f"cache='disk', cache_dir={str(tmp_path)!r})\n"
+        "p1 = repro.make_plan('gl', **kw)\n"
+        "transform.clear_plan_cache()\n"
+        "def untimed(*a, **k):\n"
+        "    raise AssertionError('timed a candidate on a cached decision')\n"
+        "transform._time_call_us = untimed\n"
+        "p2 = repro.make_plan('gl', **kw)\n"
+        "print(json.dumps({'same': p2 is p1, 'cand': p2.candidates,"
+        " 'decisions': [p1.cache_events['decision'],"
+        " p2.cache_events['decision']],"
+        " 'backends': [p1.backends, p2.backends],"
+        " 'chunks': [p1.comm_chunks, p2.comm_chunks],"
+        " 'measured': [p1.measured_s, p2.measured_s]}))\n")
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300, env=env)
+    assert r.returncode == 0, r.stderr[-2000:]
+    d = json.loads(r.stdout.strip().splitlines()[-1])
+    assert not d["same"] and d["cand"] == ["jnp", "dist"]
+    assert d["decisions"] == ["autotuned", "hit"]
+    assert d["backends"][0] == d["backends"][1]
+    assert d["chunks"][0] == d["chunks"][1]
+    assert d["measured"][0] == d["measured"][1]
 
 
 def test_clear_plan_cache_disk_tier(tmp_path):
@@ -101,38 +142,35 @@ def test_clear_plan_cache_disk_tier(tmp_path):
 
 
 def test_disk_cache_keys_distinguish_layout_and_spin(tmp_path):
-    """Signature keys must not collide across spin / layout variants.
+    """Signature keys must not collide across spin / fold variants.
 
-    A spin-2 plan's seed tables have different shapes than the scalar
-    ones; a key collision would resurrect the wrong payload from disk and
-    crash (or worse, silently corrupt) the kernel stage.
+    A spin-2 or unfolded plan runs another program on other shapes than
+    the folded scalar one; a key collision would hand one signature the
+    other's cached plan or decision.  After a cold reload from disk each
+    signature transforms exactly as before.
     """
     d = str(tmp_path)
-    p0 = repro.make_plan("gl", l_max=LMAX, K=1, dtype="float32",
-                         mode="pallas_vpu", cache="disk", cache_dir=d)
-    p2 = repro.make_plan("gl", l_max=LMAX, K=1, dtype="float32",
-                         mode="pallas_vpu", spin=2, cache="disk", cache_dir=d)
-    s0 = p0._seeds()
-    s2 = p2._seeds_spin()
-    assert p0.cache_events["seeds"] != p2.cache_events["seeds_spin"]
-    # fold changes the seed table layout -> its own key (p0 folds by
-    # default: a symmetric spin-0 plan)
-    pf = repro.make_plan("gl", l_max=LMAX, K=1, dtype="float32",
-                         mode="pallas_vpu", fold=False, cache="disk",
-                         cache_dir=d)
-    sf = pf._seeds()
-    assert pf.cache_events["seeds"] != p0.cache_events["seeds"]
-    assert sf[0].shape != s0[0].shape
-    # cold reload from disk returns the right payload for each signature
+    kw = dict(l_max=LMAX, K=1, dtype="float32", mode="jnp", cache="disk",
+              cache_dir=d)
+    variants = [dict(), dict(spin=2), dict(fold=False)]
+    plans = [repro.make_plan("gl", **kw, **v) for v in variants]
+    assert plans[0].fold and not plans[2].fold   # spin 0 folds by default
+    keys = {p._signature_key for p in plans}
+    assert len(keys) == 3
+
+    def outputs(plan):
+        f = sht.random_alm_spin if plan.spin else sht.random_alm
+        alm = f(seed=0, l_max=LMAX, m_max=LMAX).astype(jnp.complex64)
+        return np.asarray(plan.alm2map(alm))
+
+    want = [outputs(p) for p in plans]
+    assert want[1].shape != want[0].shape
+    # cold reload from disk returns the right plan for each signature
     transform.clear_plan_cache()
-    q0 = repro.make_plan("gl", l_max=LMAX, K=1, dtype="float32",
-                         mode="pallas_vpu", cache="disk", cache_dir=d)
-    q2 = repro.make_plan("gl", l_max=LMAX, K=1, dtype="float32",
-                         mode="pallas_vpu", spin=2, cache="disk", cache_dir=d)
-    np.testing.assert_array_equal(np.asarray(q0._seeds()[0]),
-                                  np.asarray(s0[0]))
-    np.testing.assert_array_equal(np.asarray(q2._seeds_spin()[0]),
-                                  np.asarray(s2[0]))
+    again = [repro.make_plan("gl", **kw, **v) for v in variants]
+    assert {p._signature_key for p in again} == keys
+    for p, w in zip(again, want):
+        np.testing.assert_array_equal(outputs(p), w)
 
 
 def test_geometry_payload_roundtrip(tmp_path):
@@ -152,19 +190,16 @@ def test_geometry_payload_roundtrip(tmp_path):
 # -- backend agreement -------------------------------------------------------
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas_vpu", "pallas_mxu"])
+@pytest.mark.parametrize("backend", ["jnp"])
 @pytest.mark.parametrize("fold", [False, True])
 def test_backends_agree_with_f64_oracle(backend, fold):
     alm, maps_ref, alm_ref = _oracle_pair()
-    dtype = "float64" if backend == "jnp" else "float32"
-    p = repro.make_plan("gl", l_max=LMAX, K=K, dtype=dtype, mode=backend,
+    p = repro.make_plan("gl", l_max=LMAX, K=K, dtype="float64", mode=backend,
                         fold=fold)
-    tol = 1e-12 if dtype == "float64" else 1e-4
-    m = np.asarray(p.alm2map(alm.astype(jnp.complex64)
-                             if dtype == "float32" else alm))
-    assert np.max(np.abs(m - maps_ref)) / np.max(np.abs(maps_ref)) < tol
-    a = np.asarray(p.map2alm(jnp.asarray(maps_ref, p.dtype)))
-    assert np.max(np.abs(a - alm_ref)) / np.max(np.abs(alm_ref)) < tol
+    m = np.asarray(p.alm2map(alm))
+    assert np.max(np.abs(m - maps_ref)) / np.max(np.abs(maps_ref)) < 1e-12
+    a = np.asarray(p.map2alm(jnp.asarray(maps_ref)))
+    assert np.max(np.abs(a - alm_ref)) / np.max(np.abs(alm_ref)) < 1e-12
 
 
 def test_auto_and_model_modes_roundtrip():
@@ -179,7 +214,7 @@ def test_auto_and_model_modes_roundtrip():
 
 def test_float64_restricted_to_oracle():
     p = repro.make_plan("gl", l_max=LMAX, K=K, dtype="float64", mode="auto")
-    assert p.candidates == ["jnp"] or "pallas_vpu" not in p.candidates
+    assert p.candidates == ["jnp"]
     assert p.backends == {"synth": "jnp", "anal": "jnp"}
 
 
@@ -224,12 +259,10 @@ def test_describe_well_formed():
         assert {"synth", "anal"} <= set(d["predicted_s"][b])
         assert all(d["predicted_s"][b][direction] > 0
                    for direction in ("synth", "anal"))
-        if b.startswith("pallas"):
-            # pallas candidates carry the packed/plain/fused layout decision
-            assert d["predicted_s"][b]["synth_layout"] in (
-                "packed", "plain", "fused")
-        for direction in ("synth", "anal"):
-            assert direction in d["measured_s"][b]
+    # one candidate: auto times nothing, and no backend has a layout
+    assert d["candidates"] == ["jnp"] and d["measured_s"] == {}
+    assert d["layouts"] == {"synth": None, "anal": None}
+    assert d["cache"]["events"]["decision"] == "only-candidate"
     assert d["memory"]["total_bytes"] > 0
     assert d["work"]["n_lm"] == (LMAX + 1) * (LMAX + 2) // 2
     # report() renders every section without blowing up
@@ -238,11 +271,38 @@ def test_describe_well_formed():
 
 
 def test_describe_predicted_vs_measured_present_in_auto():
-    p = repro.make_plan("gl", l_max=LMAX, K=K, dtype="float32", mode="auto")
-    d = p.describe()
-    chosen = d["backends"]["synth"]
-    assert np.isfinite(d["measured_s"][chosen]["synth"])
-    assert d["measured_s"][chosen]["synth"] > 0
+    """With two candidates (jnp and dist on two host devices, in a
+    subprocess) auto measures both in both directions, and describe()
+    gives each measurement beside its prediction."""
+    import json
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import json, jax, repro\n"
+        "p = repro.make_plan('gl', l_max=8, K=2, dtype='float32', "
+        "mode='auto', cache='off')\n"
+        "d = p.describe()\n"
+        "print(json.dumps({'cand': d['candidates'], 'pred': d['predicted_s'],"
+        " 'meas': d['measured_s'], 'backends': d['backends'],"
+        " 'decision': d['cache']['events']['decision']}))\n")
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300, env=env)
+    assert r.returncode == 0, r.stderr[-2000:]
+    d = json.loads(r.stdout.strip().splitlines()[-1])
+    assert d["cand"] == ["jnp", "dist"] and d["decision"] == "autotuned"
+    for b in d["cand"]:
+        for direction in ("synth", "anal"):
+            assert d["pred"][b][direction] > 0
+            assert 0 < d["meas"][b][direction] < float("inf")
+    assert d["meas"]["dist"]["synth_chunks"] >= 1
+    for direction, b in d["backends"].items():
+        assert d["meas"][b][direction] == min(
+            d["meas"][c][direction] for c in d["cand"])
 
 
 def test_plan_shape_validation():
@@ -254,35 +314,29 @@ def test_plan_shape_validation():
 
 
 def test_available_backends_policy():
-    g = grids.make_grid("gl", l_max=16)
-    assert repro.available_backends(g, "float64", 1) == ["jnp"]
-    f32 = repro.available_backends(g, "float32", 1)
-    assert "pallas_vpu" in f32 and "pallas_mxu" in f32
-    # raggedness is no longer a restriction: the bucket phase stage serves
-    # every backend
-    ragged = grids.make_grid("healpix", nside=4)
-    assert repro.available_backends(ragged, "float32", 1) == f32
-    assert repro.available_backends(ragged, "float32", 4) == f32 + ["dist"]
+    # neither the dtype nor the grid restricts a backend: the bucket phase
+    # stage serves both on every grid; only the device count does
+    assert repro.available_backends(1) == ["jnp"]
+    assert repro.available_backends(4) == ["jnp", "dist"]
 
 
 def test_backend_eligibility_reasons():
-    g = grids.make_grid("gl", l_max=16)
-    elig = transform.backend_eligibility(g, "float64", 1)
+    elig = transform.backend_eligibility(1)
+    assert set(elig) == {"jnp", "dist"}
     assert elig["jnp"] is None
-    assert "float32" in elig["pallas_vpu"]
     assert "devices" in elig["dist"]
-    assert transform.backend_eligibility(g, "float32", 2)["dist"] is None
+    assert transform.backend_eligibility(2)["dist"] is None
 
 
 def test_describe_reports_skip_reasons():
     p = repro.make_plan("healpix", nside=4, K=1, dtype="float64", mode="jnp")
     d = p.describe()
-    assert "float32" in d["skipped"]["pallas_vpu"]
+    assert "devices" in d["skipped"]["dist"]
     assert all(b not in d["candidates"] for b in d["skipped"])
     assert d["phase"]["kind"] == "bucket"
     assert d["phase"]["n_buckets"] >= 2
     r = p.report()
-    assert "skipped pallas_vpu" in r and "phase: bucket" in r
+    assert "skipped dist" in r and "phase: bucket" in r
 
 
 # -- ragged (true HEALPix) grids through the full dispatch stack -------------
@@ -296,17 +350,15 @@ def _healpix_oracle_pair(nside=4):
     return p, alm, maps, np.asarray(p.map2alm(jnp.asarray(maps)))
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas_vpu", "pallas_mxu"])
+@pytest.mark.parametrize("backend", ["jnp"])
 def test_healpix_backends_agree_with_f64_oracle(backend):
     _, alm, maps_ref, alm_ref = _healpix_oracle_pair()
-    dtype = "float64" if backend == "jnp" else "float32"
-    p = repro.make_plan("healpix", nside=4, K=K, dtype=dtype, mode=backend)
-    tol = 1e-12 if dtype == "float64" else 1e-4
-    m = np.asarray(p.alm2map(alm.astype(jnp.complex64)
-                             if dtype == "float32" else alm))
-    assert np.max(np.abs(m - maps_ref)) / np.max(np.abs(maps_ref)) < tol
-    a = np.asarray(p.map2alm(jnp.asarray(maps_ref, p.dtype)))
-    assert np.max(np.abs(a - alm_ref)) / np.max(np.abs(alm_ref)) < tol
+    p = repro.make_plan("healpix", nside=4, K=K, dtype="float64",
+                        mode=backend)
+    m = np.asarray(p.alm2map(alm))
+    assert np.max(np.abs(m - maps_ref)) / np.max(np.abs(maps_ref)) < 1e-12
+    a = np.asarray(p.map2alm(jnp.asarray(maps_ref)))
+    assert np.max(np.abs(a - alm_ref)) / np.max(np.abs(alm_ref)) < 1e-12
 
 
 def test_healpix_auto_mode_roundtrips():
@@ -331,7 +383,7 @@ def test_map2alm_iters_monotone_on_approximate_grids(kind):
 
 
 # ---------------------------------------------------------------------------
-# device policy: float64, peaks table, staged VPU eligibility on a TPU
+# device policy: float64 and the peaks table on a TPU
 # ---------------------------------------------------------------------------
 
 
@@ -355,17 +407,6 @@ def test_float64_needs_x64_mode():
             repro.make_plan("gl", l_max=8, dtype="float64", mode="jnp")
         assert transform.default_dtype() == "float32"
     assert transform.default_dtype() == "float64"
-
-
-def test_staged_vpu_ineligible_on_tpu(monkeypatch):
-    """On a TPU the staged VPU kernels are skipped with Mosaic's reason;
-    the VPU backend keeps its fused layout."""
-    monkeypatch.setattr(transform, "_on_tpu", lambda: True)
-    elig = transform.backend_eligibility(grids.make_grid("gl", l_max=8),
-                                         "float32")
-    for lay in ("plain", "packed"):
-        assert "do not compile for TPU" in elig[f"pallas_vpu[{lay}]"]
-    assert elig["pallas_vpu"] is None
 
 
 def test_peaks_table_rejects_unknown_device():
@@ -397,8 +438,8 @@ def test_peaks_table_rejects_unknown_device():
 ])
 def test_fold_default_is_derived(case, want):
     """``fold=None`` folds a uniform symmetric spin-0 plan outside the
-    dist backend (a ragged HEALPix grid keeps its fused layout unfolded);
-    an explicit value is honoured."""
+    dist backend (a ragged HEALPix grid stays unfolded); an explicit value
+    is honoured."""
     kw = dict(l_max=None if "nside" in case else 8, dtype="float64",
               mode="jnp", cache="off")
     kw.update(case)
@@ -443,26 +484,53 @@ def test_folded_row_blocks_at_4096(direction, K, arrays, blocks, rows):
     assert legendre.planes_per_step(direction, fold=True) == 1
 
 
-@pytest.mark.parametrize("fold", [True, False])
-def test_failed_folded_pallas_layout_is_skipped_on_tpu(fold, monkeypatch):
-    """On a TPU a folded plan's pallas layout that fails to build is
-    skipped with its reason, as the staged VPU kernels are, and the auto
-    build ends; an unfolded plan's failure is still an error."""
-    def refuse(self, *a, **k):
-        raise RuntimeError("Mosaic refused the kernel")
+# ---------------------------------------------------------------------------
+# the two backends: float32 jnp plans against the float64 oracle, auto on
+# one device, and the backend names that went
+# ---------------------------------------------------------------------------
 
-    monkeypatch.setattr(transform, "_on_tpu", lambda: True)
-    monkeypatch.setattr(transform.Plan, "_make_fused_anal", refuse)
-    monkeypatch.setattr(transform.Plan, "_make_fused_synth", refuse)
-    build = lambda: repro.make_plan("gl", l_max=10, K=3, dtype="float32",
-                                    mode="auto", fold=fold, cache="off")
-    if not fold:
-        with pytest.raises(RuntimeError, match="Mosaic refused"):
-            build()
-        return
-    plan = build()
-    skipped = plan.describe()["skipped"]
-    assert "Mosaic refused" in skipped["pallas_vpu[fused]"]
-    assert set(plan.backends) == {"synth", "anal"}
-    assert all(np.isfinite(plan.measured_s[b][d])
-               for d, b in plan.backends.items())
+_GRID_KW = {"gl": dict(l_max=LMAX), "ecp": dict(l_max=LMAX),
+            "healpix": dict(nside=4), "healpix_ring": dict(nside=4)}
+
+
+@pytest.mark.parametrize("direction", ["synth", "anal"])
+@pytest.mark.parametrize("spin", [0, 2])
+@pytest.mark.parametrize("grid", sorted(_GRID_KW))
+def test_jnp_float32_agrees_with_f64_oracle(grid, spin, direction):
+    """The float32 jnp plan (the dtype every cell runs, with its default
+    fold) against the float64 plan of the same signature."""
+    kw = dict(K=K, mode="jnp", spin=spin, cache="off", **_GRID_KW[grid])
+    oracle = repro.make_plan(grid, dtype="float64", **kw)
+    p = repro.make_plan(grid, dtype="float32", **kw)
+    f = sht.random_alm_spin if spin else sht.random_alm
+    alm = f(KEY, p.l_max, p.m_max, K=K)
+    maps = oracle.alm2map(alm)
+    if direction == "synth":
+        got = p.alm2map(alm.astype(jnp.complex64))
+        want = maps
+    else:
+        got = p.map2alm(jnp.asarray(maps, jnp.float32))
+        want = oracle.map2alm(maps)
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype in (np.float32, np.complex64)
+    assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 2e-5
+
+
+def test_auto_on_one_device_times_nothing(monkeypatch):
+    """One device leaves one candidate: ``mode="auto"`` takes jnp without
+    compiling or timing a call."""
+    def refuse(*a, **k):
+        raise AssertionError("auto timed a call")
+
+    monkeypatch.setattr(transform, "_time_call_us", refuse)
+    p = repro.make_plan("gl", l_max=LMAX, K=K, dtype="float32", mode="auto",
+                        cache="off")
+    assert p.backends == {"synth": "jnp", "anal": "jnp"}
+    assert p.measured_s == {} and not p._compiled
+    assert p.cache_events["decision"] == "only-candidate"
+
+
+@pytest.mark.parametrize("mode", ["pallas_vpu", "pallas_mxu"])
+def test_removed_backends_raise(mode):
+    with pytest.raises(ValueError, match=r"\('jnp', 'dist'\)"):
+        repro.make_plan("gl", l_max=LMAX, K=K, dtype="float32", mode=mode)
